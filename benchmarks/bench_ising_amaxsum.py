@@ -29,9 +29,6 @@ THREAD_AGENTS = 8
 
 
 def main():
-    from pydcop_tpu.utils.cleanenv import ensure_live_backend
-
-    ensure_live_backend(tag="bench_ising_amaxsum")
     rows = int(sys.argv[1]) if len(sys.argv) > 1 else ROWS
     from pydcop_tpu.algorithms import AlgorithmDef, load_algorithm_module
     from pydcop_tpu.algorithms.maxsum import build_engine

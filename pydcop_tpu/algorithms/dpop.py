@@ -124,11 +124,12 @@ def solve_on_device(dcop: DCOP, algo_def: AlgorithmDef,
                 compile_time_s=res.compile_time_s,
                 metrics=stats,
             )
-        except (ImportError, UtilTooLargeError) as e:
+        except UtilTooLargeError as e:
             if requested == "jit":
                 raise
-            # No jax, or a UTIL table beyond the device cap (the host
-            # sweep can still stream it): fall back, audibly.
+            # A UTIL table beyond the device cap (the host sweep can
+            # still stream it): fall back, audibly — the log line and
+            # stats["engine"] say what ran.
             import logging
 
             logging.getLogger("pydcop.algo.dpop").warning(

@@ -564,6 +564,18 @@ def serve(port: int = 8080, host: str = "127.0.0.1",
         )
 
         enable_persistent_compile_cache(compile_cache_dir)
+    import sys
+
+    import jax
+
+    # Take the device NOW: a service that cannot have its accelerator
+    # (a chip belongs to one process) fails at start — before it
+    # binds, so a fleet router sees its worker die with the reason in
+    # the log — not at its first request.
+    devices = jax.devices()
+    print(f"pydcop serve: backend {devices[0].platform} "
+          f"({len(devices)} x {devices[0].device_kind})",
+          file=sys.stderr)
     from pydcop_tpu.serving.admission import AdmissionPolicy
     from pydcop_tpu.serving.http import ServeFrontEnd
     from pydcop_tpu.serving.service import SolveService
@@ -599,8 +611,6 @@ def serve(port: int = 8080, host: str = "127.0.0.1",
         service.stop(drain=False)
         raise
     handle = ServeHandle(service, front_end)
-    import sys
-
     print(f"pydcop serve: listening on {handle.url} "
           "(POST /solve, GET /result/<id>, /metrics, /healthz)",
           file=sys.stderr)

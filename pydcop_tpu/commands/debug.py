@@ -3,11 +3,11 @@
 ``pydcop debug bundle`` cuts a postmortem bundle on demand — the same
 document the always-on flight recorder (observability/flight.py)
 dumps automatically on anomaly triggers: the trace-event ring tail,
-a metrics-registry snapshot, the ``/healthz`` payload, env +
-accelerator-probe diagnostics, the device-efficiency rollup
-(backend-honest attainment + the where-the-time-went ledger — what
-backend was the anomalous run actually executing on, and was it doing
-useful work), the ``BENCH_TPU_PROBELOG.jsonl`` history tail, and the
+a metrics-registry snapshot, the ``/healthz`` payload, the
+``PYDCOP_*`` / ``JAX_*`` / ``XLA_*`` environment, the
+device-efficiency rollup (backend-labeled attainment + the
+where-the-time-went ledger — what backend was the anomalous run
+actually executing on, and was it doing useful work), and the
 pending-journal summary when a serve journal is active.
 
 Two modes:

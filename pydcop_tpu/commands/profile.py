@@ -249,11 +249,9 @@ def _pct(part: float, whole: float) -> str:
 
 def render_live(doc: Dict[str, Any], out) -> None:
     backend = doc.get("backend") or {}
-    probe = ("ok" if backend.get("probe_ok")
-             else f"{backend.get('probe_failures', '?')} failure(s)")
     print(f"backend: {backend.get('backend', '?')} "
-          f"({backend.get('n_devices', '?')} device(s), "
-          f"accelerator probe {probe})", file=out)
+          f"({backend.get('n_devices', '?')} x "
+          f"{backend.get('device_kind', '?')})", file=out)
     ledger = doc.get("ledger") or {}
     components = ledger.get("components_s") or {}
     total = ledger.get("total_s") or 0.0

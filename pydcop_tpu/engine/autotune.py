@@ -1,11 +1,10 @@
 """Aggregation autotuner: measure, don't guess.
 
-The variable-side aggregation is the op that dominates the superstep
-past the ~100k-var scale cliff (BENCH_TPU.md), and the best strategy
-is backend- and shape-dependent: scatter wins everywhere on CPU,
-while on TPU the scatter-add serializes row updates and the dense
-ell gather is the candidate (docs/performance.md, round-5 on-chip
-A/B).  A manual ``aggregation=`` flag nobody tunes leaves that
+The variable-side aggregation is the suspect op of the superstep
+past ~100k variables, and the best strategy is backend- and
+shape-dependent: scatter wins everywhere on CPU, while on TPU the
+scatter-add serializes row updates and the dense ell gather is the
+candidate (docs/performance.md).  A manual ``aggregation=`` flag nobody tunes leaves that
 performance on the table; ``aggregation='auto'`` replaces it with a
 per-graph measurement: micro-time the candidate strategies on the
 *actual* compiled graph (same bucket shapes, same edge distribution,
@@ -30,9 +29,11 @@ worked around):
 
 Decisions persist in a JSON cache keyed by (backend, graph shape):
 re-serving a same-shaped problem skips the micro-benchmark entirely.
-Default location ``~/.cache/pydcop_tpu/agg_autotune.json``
-(``PYDCOP_AGG_AUTOTUNE_CACHE`` overrides; an unwritable path degrades
-to measuring every time, never to failing the solve).
+Default location ``<checkout>/.cache/pydcop_tpu/agg_autotune.json``
+— beside the compile cache (engine/aotcache.DEFAULT_DIR), inside the
+checkout and git-ignored: nothing around the checkout is read or
+written (``PYDCOP_AGG_AUTOTUNE_CACHE`` overrides; an unwritable path
+degrades to measuring every time, never to failing the solve).
 """
 
 import json
@@ -44,6 +45,7 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
+from pydcop_tpu.engine.aotcache import DEFAULT_DIR
 from pydcop_tpu.engine.compile import (
     AGGREGATIONS,
     CompiledFactorGraph,
@@ -64,7 +66,7 @@ def cache_path() -> str:
     if env:
         return env
     return os.path.join(
-        os.path.expanduser("~"), ".cache", "pydcop_tpu",
+        os.path.dirname(DEFAULT_DIR), "pydcop_tpu",
         "agg_autotune.json",
     )
 

@@ -39,7 +39,6 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from pydcop_tpu.engine.compile import (
@@ -686,12 +685,12 @@ class ShardOps:
             values = _select_local(lgraph, aux, st, v_loc)
             return _reblock_state(st), values[None]
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             local_run, mesh=self.mesh,
             in_specs=(self._graph_specs(graph),
                       self._state_specs(graph)),
             out_specs=(self._state_specs(graph), P(SHARD_AXIS)),
-            check_rep=False,
+            check_vma=False,
         )
         state, values_sh = mapped(graph, state)
         return state, self._assemble_values(graph, values_sh)
@@ -793,11 +792,11 @@ class ShardOps:
             _, values = cost_of(st)
             return _reblock_state(st), values[None], costs
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             local_run, mesh=self.mesh,
             in_specs=(self._graph_specs(graph), P(SHARD_AXIS)),
             out_specs=(self._state_specs(graph), P(SHARD_AXIS), P()),
-            check_rep=False,
+            check_vma=False,
         )
         state, values_sh, costs = mapped(graph, base_local)
         return state, self._assemble_values(graph, values_sh), costs
@@ -848,12 +847,12 @@ class ShardOps:
             return _exchange_halo(
                 tuple(m[0] for m in msgs), aux, n_bnd)
 
-        return shard_map(
+        return jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(self._graph_specs(graph),
                       (P(SHARD_AXIS),) * nb),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(graph, tuple(f2v))
 
     def assignment_constraint_cost(self, graph: ShardedGraph,
@@ -873,11 +872,11 @@ class ShardOps:
                 maxsum_ops.assignment_constraint_cost(lgraph, vl[0]),
                 SHARD_AXIS)
 
-        return shard_map(
+        return jax.shard_map(
             local_cost, mesh=self.mesh,
             in_specs=(self._graph_specs(graph), P(SHARD_AXIS)),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(graph, vals_local)
 
     def _assemble_values(self, graph: ShardedGraph, values_sh

@@ -18,12 +18,14 @@ This module is that surface, three interlocking parts:
   ``flops * cycles / execute_s``.  Attainment is roofline-style: the
   MAX of flop attainment and bandwidth attainment (a memory-bound
   program at 80% of peak bandwidth is an efficiently used machine even
-  at 1% of peak flops); both components are reported.  Peaks come
-  from a per-backend table (:data:`BACKEND_PEAKS`, deliberately
-  coarse) overridable with ``PYDCOP_PEAK_FLOPS`` /
-  ``PYDCOP_PEAK_BYTES_PER_S`` — the rollup says which source it used,
-  so a number computed against a default peak can never masquerade as
-  calibrated.
+  at 1% of peak flops); both components are reported.  On a TPU the
+  peaks come from ``engine.roofline.TPU_PEAKS`` by ``device_kind``
+  (the one table of chip peaks), on the CPU from the coarse
+  :data:`HOST_PEAK`; ``PYDCOP_PEAK_FLOPS`` /
+  ``PYDCOP_PEAK_BYTES_PER_S`` override either.  A device whose peak
+  is not known (a TPU kind missing from the table, any other backend)
+  gets NO attainment figure — never a default.  The rollup says which
+  source it used.
 
 - **Useful-work fraction.**  Attainment says how hard the device
   worked; the honest waste accounting the dispatch paths already emit
@@ -49,13 +51,10 @@ This module is that surface, three interlocking parts:
   where-the-time-went breakdown ``/profile``, ``/stats`` and
   ``pydcop profile report`` serve.
 
-**Backend honesty**: every rollup and exported metric is labeled with
+**Backend labels**: every rollup and exported metric is labeled with
 the RESOLVED backend (:func:`resolved_backend` — ``jax``'s actual
-default backend plus the accelerator-probe outcome from
-``utils.cleanenv.diag_events``), so a CPU-fallback number can never
-masquerade as a TPU number — the same discipline bench.py's
-``leg_backends`` applies per leg and ``tools/bench_sentinel.py``
-enforces across rounds.
+default backend, device kind and device count), so a CPU number can
+never be read as a TPU number.
 
 Overhead: recording is a dict update under one lock per DISPATCH
 (milliseconds of device work), never per cycle; ``make perf-smoke``
@@ -75,19 +74,13 @@ from pydcop_tpu.observability.metrics import registry as metrics_registry
 LEDGER_COMPONENTS = ("submit", "queue", "plan", "prep", "compile",
                      "execute", "decode")
 
-# Per-backend peak (flops/s, bytes/s) used for attainment when no env
-# override is given.  Deliberately coarse, order-of-magnitude honest:
-# tpu = v5e bf16 peak (197 TFLOP/s, 819 GB/s HBM); gpu = a mid-range
-# datacenter part; cpu = a few vector cores' worth.  The rollup
-# reports ``peak_source`` so consumers know whether the denominator
-# was calibrated (env) or a default — calibrate with
-# PYDCOP_PEAK_FLOPS / PYDCOP_PEAK_BYTES_PER_S for real MFU numbers.
-BACKEND_PEAKS: Dict[str, Any] = {
-    "tpu": (1.97e14, 8.19e11),
-    "gpu": (1.0e13, 9.0e11),
-    "cpu": (1.0e11, 5.0e10),
-}
-DEFAULT_PEAK = (1.0e11, 5.0e10)
+# Coarse (flops/s, bytes/s) denominator for the CPU backend — a few
+# vector cores' worth, so the CPU test plane has an attainment to
+# account with.  Chip peaks are NOT here: they live in
+# engine.roofline.TPU_PEAKS, keyed by device_kind.  The rollup reports
+# ``peak_source`` so consumers know whether the denominator was
+# calibrated (env) or a table value.
+HOST_PEAK = (1.0e11, 5.0e10)
 
 PEAK_FLOPS_ENV = "PYDCOP_PEAK_FLOPS"
 PEAK_BYTES_ENV = "PYDCOP_PEAK_BYTES_PER_S"
@@ -105,21 +98,39 @@ def _env_float(name: str) -> Optional[float]:
     return value if value > 0 else None
 
 
-def backend_peaks(backend: str) -> Dict[str, Any]:
-    """``{flops_per_s, bytes_per_s, source}`` for one backend —
-    env-calibrated when ``PYDCOP_PEAK_FLOPS``/``PYDCOP_PEAK_BYTES_PER_S``
-    are set, the coarse :data:`BACKEND_PEAKS` default otherwise.
-    ``source`` is ``env`` only when BOTH peaks are calibrated;
-    calibrating one resource reports ``mixed`` — an attainment whose
-    binding resource was judged against a default peak must never
-    read as calibrated."""
-    flops, bw = BACKEND_PEAKS.get(backend, DEFAULT_PEAK)
+def backend_peaks(backend: str, device_kind: Optional[str] = None
+                  ) -> Optional[Dict[str, Any]]:
+    """``{flops_per_s, bytes_per_s, source}`` for one backend, or None
+    when a peak is not known — no attainment is better than one
+    against a made-up denominator.
+
+    ``tpu`` looks ``device_kind`` up in ``engine.roofline.TPU_PEAKS``
+    (default: this process's resolved kind when it runs on that
+    backend); ``cpu`` uses :data:`HOST_PEAK`; any other backend has no
+    table.  ``PYDCOP_PEAK_FLOPS``/``PYDCOP_PEAK_BYTES_PER_S`` override
+    per resource.  ``source`` is ``env`` only when BOTH peaks are
+    calibrated; calibrating one resource reports ``mixed`` — an
+    attainment whose binding resource was judged against a table peak
+    must never read as calibrated."""
+    flops = bw = None
+    if backend == "tpu":
+        from pydcop_tpu.engine.roofline import TPU_PEAKS
+
+        if device_kind is None:
+            resolved = resolved_backend()
+            if resolved["backend"] == backend:
+                device_kind = resolved.get("device_kind")
+        flops, bw = TPU_PEAKS.get(device_kind, (None, None))
+    elif backend == "cpu":
+        flops, bw = HOST_PEAK
     env_flops = _env_float(PEAK_FLOPS_ENV)
     env_bw = _env_float(PEAK_BYTES_ENV)
     if env_flops is not None:
         flops = env_flops
     if env_bw is not None:
         bw = env_bw
+    if flops is None or bw is None:
+        return None
     calibrated = sum(1 for v in (env_flops, env_bw) if v is not None)
     source = ("env" if calibrated == 2
               else "mixed" if calibrated == 1 else "default")
@@ -131,21 +142,21 @@ _backend_lock = threading.Lock()
 
 
 def resolved_backend(refresh: bool = False) -> Dict[str, Any]:
-    """The backend this process ACTUALLY runs on, plus the
-    accelerator-probe outcome at resolution time — the label every
-    efficiency metric carries (backend honesty: a CPU fallback must
-    say so).  The jax resolution is memoized (the default backend
-    cannot change once initialized); the probe summary is re-read per
-    call — failures can accumulate while a process runs."""
+    """The backend this process ACTUALLY runs on (``backend``,
+    ``device_kind``, ``n_devices``) — the label every efficiency
+    metric carries.  Memoized: the default backend cannot change once
+    initialized."""
     with _backend_lock:
         base = dict(_backend_cache)
     if refresh or not base:
         try:
             import jax
 
+            devices = jax.devices()
             base = {
                 "backend": jax.default_backend(),
-                "n_devices": len(jax.devices()),
+                "device_kind": devices[0].device_kind,
+                "n_devices": len(devices),
             }
         except Exception as exc:  # noqa: BLE001 — the accounting
             # plane must answer even before/without a live backend.
@@ -154,30 +165,12 @@ def resolved_backend(refresh: bool = False) -> Dict[str, Any]:
         with _backend_lock:
             _backend_cache.clear()
             _backend_cache.update(base)
-    out = dict(base)
-    try:
-        from pydcop_tpu.utils.cleanenv import (
-            diag_events,
-            is_probe_failure,
-        )
-
-        failures = [e for e in diag_events() if is_probe_failure(e)]
-        out["probe_failures"] = len(failures)
-        out["probe_ok"] = not failures
-        if failures:
-            out["last_probe_error"] = failures[-1].get("error")
-    except Exception:  # noqa: BLE001
-        out["probe_failures"] = 0
-        out["probe_ok"] = None
-    return out
+    return dict(base)
 
 
 def backend_name() -> str:
     """The memoized resolved-backend STRING — the per-dispatch hot
-    form.  :func:`resolved_backend` additionally re-reads the
-    accelerator-probe diagnostics (a JSON env parse) on every call;
-    dispatch recording only needs the label, so it must not pay that
-    per dispatch."""
+    form (no dict copy)."""
     with _backend_lock:
         cached = _backend_cache.get("backend")
     if cached is not None:
@@ -252,13 +245,16 @@ def attainment_from_cost(cost_entry: Optional[Dict[str, Any]],
     per loop iteration — XLA counts the while body once); ``cycles``
     scales it to the whole dispatch; ``execute_s`` is the measured
     device-execute wall.  Returns None when the entry is missing /
-    unavailable or nothing was measured — "not profiled" must stay
+    unavailable, nothing was measured, or the device's peak is not
+    known (:func:`backend_peaks`) — "not profiled" must stay
     distinguishable from "0% attained"."""
     if not cost_entry or not cost_entry.get("available"):
         return None
     if execute_s <= 0 or cycles <= 0:
         return None
     peaks = backend_peaks(backend)
+    if peaks is None:
+        return None
     out: Dict[str, Any] = {"peak_source": peaks["source"]}
     flop_att = bw_att = None
     flops = cost_entry.get("flops")
@@ -548,8 +544,8 @@ class EfficiencyTracker:
             "pad_waste_s": round(pad_s, 6),
             "envelope_waste_s": round(env_s, 6),
         }
-        if execute_s > 0:
-            peaks = backend_peaks(backend)
+        peaks = backend_peaks(backend) if execute_s > 0 else None
+        if peaks is not None:
             flop_att = (flops / execute_s / peaks["flops_per_s"]
                         if flops else None)
             bw_att = (byts / execute_s / peaks["bytes_per_s"]
@@ -644,7 +640,7 @@ class EfficiencyTracker:
         agg = roll["backends"].get(backend, {})
         return {
             "backend": backend,
-            "probe_ok": roll["backend"].get("probe_ok"),
+            "device_kind": roll["backend"].get("device_kind"),
             "attainment": agg.get("attainment"),
             "useful_work_fraction": agg.get("useful_work_fraction"),
             "device_execute_s": agg.get("execute_s", 0.0),

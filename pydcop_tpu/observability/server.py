@@ -64,44 +64,11 @@ def get_health_provider() -> Optional[Callable[[], Dict[str, Any]]]:
         return _health_provider
 
 
-def _probe_diagnostics() -> Optional[Dict[str, Any]]:
-    """Accelerator-probe failure root cause for the /healthz body.
-
-    The bench/CLI backend guards record every probe outcome via
-    ``utils.cleanenv.record_diag`` — until now that evidence was
-    bench-log-only, so an operator watching a CPU-fallback service
-    had no way to see WHY the accelerator was skipped.  Returns None
-    when no probe ever failed (the common healthy case keeps the
-    body small); failures never flip the health status — a CPU
-    fallback still serves correctly, the body just says what
-    happened."""
-    try:
-        from pydcop_tpu.utils.cleanenv import (
-            diag_events,
-            is_probe_failure,
-        )
-    except Exception:  # noqa: BLE001 — probe must answer
-        return None
-    failures = [e for e in diag_events() if is_probe_failure(e)]
-    if not failures:
-        return None
-    last = failures[-1]
-    return {
-        "failures": len(failures),
-        "last_event": last.get("event"),
-        "last_error": last.get("error"),
-        "last_unix": last.get("unix"),
-        "recent": failures[-5:],
-    }
-
-
 def health_verdict() -> Dict[str, Any]:
     """The /healthz body: provider data + an overall ``status`` rolled
     up from per-agent statuses (any dead -> ``failing``, any suspect
-    -> ``degraded``, else ``ok``), plus the accelerator-probe failure
-    root cause when any probe failed (``accelerator_probe`` key —
-    informational, never changes the status).  Provider failures
-    report ``unknown`` rather than crashing the probe."""
+    -> ``degraded``, else ``ok``).  Provider failures report
+    ``unknown`` rather than crashing the probe."""
     provider = get_health_provider()
     if provider is None:
         data = {"status": "ok", "detail": "no health monitor active"}
@@ -120,9 +87,6 @@ def health_verdict() -> Dict[str, Any]:
             else:
                 status = "ok"
             data.setdefault("status", status)
-    probe = _probe_diagnostics()
-    if probe is not None:
-        data.setdefault("accelerator_probe", probe)
     return data
 
 

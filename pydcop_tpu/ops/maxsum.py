@@ -151,17 +151,33 @@ _PALLAS_FLAG = _read_pallas_flag()
 
 
 def _use_pallas() -> bool:
-    """Opt-in Pallas path for the binary-factor update (TPU only;
-    PYDCOP_PALLAS_MAXSUM=1 must be set before this module is imported).
-    Default off: measured at parity with XLA's fusion on v5e — see
-    ops/pallas_maxsum.py for the full status."""
-    return (
-        _PALLAS_FLAG
-        and jax.default_backend() == "tpu"
-        # Sharded buckets (mesh runs) cannot feed pallas_call without
-        # gathering the whole bucket per superstep — single chip only.
-        and jax.device_count() == 1
-    )
+    """Opt-in Pallas path for the binary-factor update
+    (PYDCOP_PALLAS_MAXSUM=1 must be set before this module is
+    imported; default off — see ops/pallas_maxsum.py for the status).
+    The kernel is a TPU kernel for whole buckets on one device: asked
+    for where it cannot run, it raises — it never gives way silently
+    to the jnp expression (engines that cannot feed it refuse the
+    flag through :func:`refuse_pallas`)."""
+    if not _PALLAS_FLAG:
+        return False
+    if jax.default_backend() != "tpu":
+        raise RuntimeError(
+            "PYDCOP_PALLAS_MAXSUM=1 asks for the Pallas TPU kernel but "
+            f"the backend is {jax.default_backend()!r}; unset it or "
+            "run on a TPU")
+    return True
+
+
+def refuse_pallas(where: str) -> None:
+    """Called by an engine whose buckets cannot feed the kernel: a
+    bucket sharded over a mesh would have to be gathered whole per
+    superstep, and the lane-major layout has its own update.  What
+    matters is where the bucket lives, not how many chips exist — an
+    unsharded solve on a multi-chip host keeps the kernel."""
+    if _PALLAS_FLAG:
+        raise RuntimeError(
+            "PYDCOP_PALLAS_MAXSUM=1 runs whole edge-major buckets on "
+            f"one device; unset it for {where}")
 
 
 class PruneTable(NamedTuple):
@@ -392,7 +408,7 @@ def aggregate_beliefs(graph: CompiledFactorGraph, f2v: Msgs
 
     Returns (beliefs [V+1, D] = own costs + sums, sums [V+1, D]).
     This aggregation is the single cross-shard op per superstep, and
-    the op that dominates past the 100k-var scale cliff (BENCH_TPU.md).
+    the suspect past the size that fits fast memory (~100k vars).
     Strategy is chosen at compile time via the graph's ``agg_*`` arrays
     (engine/compile.build_aggregation_arrays; A/B harness
     benchmarks/exp_aggregation.py):

@@ -3,11 +3,10 @@
 The default kernels (ops/maxsum.py) keep messages as ``[F, arity, D]``
 — domain values on the minor axis.  DCOP domains are tiny (D=3..8) so
 that layout leaves 120+ of the 128 TPU lanes idle in every vector op,
-and past VMEM residency (~100k vars, the BENCH_TPU.md scale cliff) the
-scatter/gather traffic is issued in D-element slivers.  An on-chip
-prototype of the transposed layout measured 1.7x (10k vars) / 1.3x
-(100k) on the raw message math (BENCH_TPU.md round 3); this module is
-the full-superstep version of that layout, A/B-able against edge-major
+and past the size that fits fast memory (~100k vars) the
+scatter/gather traffic is issued in D-element slivers.  This module
+is the full-superstep version of the transposed layout (not yet
+measured against edge-major on the chip — PERF.md), A/B-able
 via benchmarks/exp_layout.py and selectable with the maxsum
 ``layout="lane"`` algo param (engine/runner.MaxSumEngine).
 

@@ -310,6 +310,19 @@ class Replica:
         }
 
 
+def _log_tail(path: str, max_bytes: int = 2000) -> str:
+    """The end of a worker's log, for the error that reports its
+    death: the reason (e.g. an accelerator another process holds) is
+    in the message, not only in a file on the router's host."""
+    try:
+        with open(path, "rb") as f:
+            f.seek(0, os.SEEK_END)
+            f.seek(max(f.tell() - max_bytes, 0))
+            return f.read().decode("utf-8", "replace").strip()
+    except OSError as exc:
+        return f"(log unreadable: {exc})"
+
+
 def _rendezvous_score(digest: str, index: int) -> int:
     """Highest-random-weight score of one (structure, replica) pair —
     deterministic across processes and restarts (hash() is seeded per
@@ -633,8 +646,9 @@ class FleetRouter:
             if replica.proc.poll() is not None:
                 raise RuntimeError(
                     f"fleet worker {replica.index} died on startup "
-                    f"(exit {replica.proc.returncode}); log: "
-                    f"{replica.log_path}")
+                    f"(exit {replica.proc.returncode}); log "
+                    f"{replica.log_path} ends:\n"
+                    f"{_log_tail(replica.log_path)}")
             try:
                 with open(port_file, encoding="utf-8") as f:
                     replica.port = int(f.read().strip())
@@ -671,7 +685,8 @@ class FleetRouter:
             time.sleep(0.05)
         raise RuntimeError(
             f"fleet worker {replica.index} never became ready; "
-            f"log: {replica.log_path}")
+            f"log {replica.log_path} ends:\n"
+            f"{_log_tail(replica.log_path)}")
 
     # -- health & restarts --------------------------------------------- #
 

@@ -484,7 +484,7 @@ class SolveService:
         self._scheduler.shutdown(timeout=timeout)
         self._scheduler = None
         if self._speculator is not None:
-            self._speculator.stop()
+            self._speculator.stop(timeout=timeout)
             self._speculator = None
         self._started = False
         metrics_registry.active = self._was_active

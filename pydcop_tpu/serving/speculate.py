@@ -148,7 +148,12 @@ class SpeculativeCompiler:
             daemon=True)
         self._thread.start()
 
-    def stop(self, timeout: float = 2.0) -> None:
+    def stop(self, timeout: float = 30.0) -> None:
+        """Stop the worker and wait for it: it ends after the compile
+        in flight, which cannot be cancelled and takes seconds on a
+        TPU.  A worker abandoned mid-compile is still calling into
+        JAX when the interpreter tears down, and that segfaults (seen
+        at the end of a ``chip_smoke.py`` run on the chip, PR 22)."""
         if self._thread is None:
             return
         self._stop.set()
@@ -157,6 +162,9 @@ class SpeculativeCompiler:
         except queue.Full:
             pass
         self._thread.join(timeout=timeout)
+        if self._thread.is_alive():
+            log.warning("speculative compile thread did not stop in "
+                        "%.1fs", timeout)
         self._thread = None
 
     # ----------------------------------------------------------- #

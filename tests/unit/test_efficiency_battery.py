@@ -8,7 +8,7 @@ backend-label propagation into the metrics exposition; the sentinel's
 cross-backend refusal; the dynamic engine's deferred-edit batching
 (behavior-identical to per-action application, incl. mid-batch
 recompile and the failed-batch partial-apply contract); and the
-probelog tail + postmortem-bundle sections."""
+postmortem bundle's efficiency section."""
 
 import json
 import os
@@ -129,6 +129,37 @@ class TestAttainment:
         assert att["attainment"] == pytest.approx(0.1)
         assert att["peak_source"] == "env"
 
+    @pytest.mark.parametrize("backend,kind,known", [
+        ("tpu", "TPU v5 lite", True),
+        ("tpu", "TPU v99 imaginary", False),
+        ("tpu", None, False),       # this process runs on the CPU
+        ("gpu", None, False),
+        ("cpu", None, True),
+    ])
+    def test_backend_peaks_known_or_none(self, monkeypatch, backend,
+                                         kind, known):
+        """Chip peaks come from engine.roofline.TPU_PEAKS by
+        device_kind; a device that is not in the table gets None —
+        no attainment — never a default."""
+        from pydcop_tpu.engine.roofline import TPU_PEAKS
+        from pydcop_tpu.observability.efficiency import backend_peaks
+
+        monkeypatch.delenv("PYDCOP_PEAK_FLOPS", raising=False)
+        monkeypatch.delenv("PYDCOP_PEAK_BYTES_PER_S", raising=False)
+        peaks = backend_peaks(backend, kind)
+        if not known:
+            assert peaks is None
+            entry = {"available": True, "flops": 1e5,
+                     "bytes_accessed": 2e5}
+            if kind is None:
+                assert attainment_from_cost(
+                    entry, 100, 0.1, backend) is None
+            return
+        assert peaks["source"] == "default"
+        if backend == "tpu":
+            assert (peaks["flops_per_s"],
+                    peaks["bytes_per_s"]) == TPU_PEAKS[kind]
+
     def test_unavailable_entry_is_none_not_zero(self):
         assert attainment_from_cost(
             {"available": False}, 10, 0.1, "cpu") is None
@@ -187,10 +218,11 @@ class TestRollup:
         assert set(roll["backends"]) == {"cpu", "tpu"}
         assert roll["backends"]["cpu"]["dispatches"] == 3
         assert roll["backends"]["tpu"]["dispatches"] == 1
-        # The tpu cell ran the same program 20x faster: attainment
-        # must be proportionally higher relative to ITS peak scale.
-        assert (roll["backends"]["tpu"]["attainment"]
-                != roll["backends"]["cpu"]["attainment"])
+        # The tpu cell was recorded in a process that does not run
+        # on a TPU, so its chip kind — hence its peak — is not known:
+        # no attainment figure, never one borrowed from a default.
+        assert roll["backends"]["cpu"]["attainment"] is not None
+        assert "attainment" not in roll["backends"]["tpu"]
 
     def test_structures_ranked_by_device_time(self):
         roll = self._tracker().rollup()
@@ -664,51 +696,23 @@ class TestBatchEdits:
 
 
 # ------------------------------------------------------------------ #
-# probelog tail + bundle sections
+# bundle sections
 # ------------------------------------------------------------------ #
 
 class TestBundleSections:
-    def test_probelog_tail_reads_record_diag_format(self, tmp_path,
-                                                    monkeypatch):
-        from pydcop_tpu.utils.cleanenv import probelog_tail
-
-        path = tmp_path / "probelog.jsonl"
-        rows = [{"unix": 1.0 + i, "event": "probe", "ok": i % 2 == 0}
-                for i in range(30)]
-        with open(path, "w") as f:
-            for row in rows:
-                f.write(json.dumps(row) + "\n")
-            f.write("not json\n")
-        monkeypatch.setenv("PYDCOP_PROBELOG", str(path))
-        tail = probelog_tail(5)
-        assert len(tail) == 5
-        assert tail[-1]["unix"] == 30.0
-
-    def test_probelog_tail_missing_file_is_empty(self, monkeypatch):
-        monkeypatch.setenv("PYDCOP_PROBELOG", "/nonexistent/x.jsonl")
-        from pydcop_tpu.utils.cleanenv import probelog_tail
-
-        assert probelog_tail() == []
-
-    def test_bundle_carries_efficiency_and_probe_tail(self, tmp_path,
-                                                      monkeypatch):
+    def test_bundle_carries_efficiency_section(self, tmp_path):
         from pydcop_tpu.observability.flight import FlightRecorder
 
-        path = tmp_path / "probelog.jsonl"
-        with open(path, "w") as f:
-            f.write(json.dumps({"unix": 1.0, "event": "probe",
-                                "ok": False,
-                                "error": "timeout after 20s"}) + "\n")
-        monkeypatch.setenv("PYDCOP_PROBELOG", str(path))
         efficiency.tracker.record_dispatch(
             key="k", structure="s", backend="cpu", time_s=0.1,
             compile_s=0.0, cycles=10, n_real=1, batch_size=1)
         doc = FlightRecorder(bundle_dir=str(tmp_path)).make_bundle(
             "test", {})
-        assert doc["probe_log_tail"][0]["error"] == \
-            "timeout after 20s"
-        assert doc["efficiency"]["backend"]["backend"] == \
-            resolved_backend()["backend"]
+        assert "probe_log_tail" not in doc
+        assert "probe_diagnostics" not in doc
+        backend = doc["efficiency"]["backend"]
+        assert backend == resolved_backend()
+        assert set(backend) == {"backend", "device_kind", "n_devices"}
         assert doc["efficiency"]["structures"]
 
 
@@ -864,8 +868,8 @@ class TestReviewRegressions:
 
     def test_sentinel_newest_is_the_newest_numbered_round(
             self, tmp_path):
-        """BENCH_TPU_LAST.json (appended last by load_history) must
-        not define which backend the newest ROUND resolved."""
+        """The newest numbered round defines which backend the latest
+        round resolved; nothing else in the directory does."""
         import bench_sentinel
 
         root = str(tmp_path)
@@ -874,13 +878,7 @@ class TestReviewRegressions:
                 "value": v, "backend": "cpu",
                 "leg_backends": {"headline": {"backend": "cpu"}}}},
                 open(os.path.join(root, f"BENCH_r0{i}.json"), "w"))
-        json.dump({"value": 1083.0, "backend": "tpu"},
-                  open(os.path.join(root, "BENCH_TPU_LAST.json"),
-                       "w"))
         report = bench_sentinel.run_check(root)
-        # The newest numbered round resolved cpu: the cpu series is
-        # judged normally and NO cpu round is SKIPPED against the
-        # stale tpu reference artifact.
         assert report["series"]["cpu"]["verdict"] == "ok"
         assert not any("SKIPPED" in line for line in report["lines"])
 

@@ -688,10 +688,9 @@ class TestSentinelNewFamily:
         (tmp_path / "BENCH_r2.json").write_text(
             '{"parsed": "not a dict"}')
         (tmp_path / "BENCH_r3.json").write_text("not json at all")
-        (tmp_path / "BENCH_TPU_LAST.json").write_text("[]")
         report = sentinel.run_check(str(tmp_path))
         assert report["failed"] is False
-        assert len(report["skipped"]) == 4
+        assert len(report["skipped"]) == 3
 
     def test_new_family_with_short_history_is_insufficient(
             self, tmp_path):

@@ -287,9 +287,76 @@ class TestAotCache:
             assert aotcache.split_cold_call(
                 1.0, before, pure_hit) is None  # disabled → None
 
-    def test_enable_resolves_env(self, tmp_path, monkeypatch):
+    @pytest.mark.parametrize("case", [
+        "jax_env_wins", "explicit_arg", "pydcop_env", "default"])
+    def test_cache_dir_resolution_order(self, case, tmp_path,
+                                        monkeypatch):
+        """JAX's own env setting stands; else the explicit directory
+        (argument, or the env var workers inherit); else ONE fixed
+        path inside the checkout — never a temp-, pid- or
+        time-derived one (the path is part of every entry's key)."""
+        monkeypatch.delenv(aotcache.JAX_ENV_DIR, raising=False)
         monkeypatch.delenv(aotcache.ENV_DIR, raising=False)
-        assert aotcache.maybe_enable_from_env() is None
+        arg = None
+        if case == "jax_env_wins":
+            monkeypatch.setenv(aotcache.JAX_ENV_DIR,
+                               str(tmp_path / "jax_own"))
+            monkeypatch.setenv(aotcache.ENV_DIR,
+                               str(tmp_path / "inherited"))
+            arg = str(tmp_path / "arg")
+            want = (str(tmp_path / "jax_own"), "jax_env")
+        elif case == "explicit_arg":
+            monkeypatch.setenv(aotcache.ENV_DIR,
+                               str(tmp_path / "inherited"))
+            arg = str(tmp_path / "arg")
+            want = (arg, "explicit")
+        elif case == "pydcop_env":
+            monkeypatch.setenv(aotcache.ENV_DIR,
+                               str(tmp_path / "inherited"))
+            want = (str(tmp_path / "inherited"), "explicit")
+        else:
+            want = (os.path.join(REPO, ".cache", "jax"), "default")
+        assert aotcache.resolve_cache_dir(arg) == want
+        # Asked twice, the same answer: nothing in it moves.
+        assert aotcache.resolve_cache_dir(arg) == want
+
+    @pytest.mark.parametrize("jax_env_set", [True, False])
+    def test_enable_sets_the_directory_only_without_jax_env(
+            self, jax_env_set, tmp_path, monkeypatch):
+        """With JAX_COMPILATION_CACHE_DIR set no code path calls
+        ``jax.config.update("jax_compilation_cache_dir", ...)``;
+        without it, exactly the resolved directory is set."""
+        import jax
+        from jax.experimental.compilation_cache import (
+            compilation_cache,
+        )
+        from pydcop_tpu.engine.aotcache import _lock, _state
+
+        monkeypatch.delenv(aotcache.ENV_DIR, raising=False)
+        if jax_env_set:
+            monkeypatch.setenv(aotcache.JAX_ENV_DIR,
+                               str(tmp_path / "jax_own"))
+        else:
+            monkeypatch.delenv(aotcache.JAX_ENV_DIR, raising=False)
+        updates = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: updates.append((k, v)))
+        monkeypatch.setattr(compilation_cache, "reset_cache",
+                            lambda: None)
+        with _lock:
+            prior = dict(_state)
+        try:
+            got = aotcache.enable_persistent_compile_cache(
+                str(tmp_path / "arg"))
+        finally:
+            with _lock:
+                _state.update(prior)
+        dirs = [v for k, v in updates
+                if k == "jax_compilation_cache_dir"]
+        if jax_env_set:
+            assert got == str(tmp_path / "jax_own") and dirs == []
+        else:
+            assert got == str(tmp_path / "arg") and dirs == [got]
 
     def test_fresh_process_serves_without_recompiling(self, tmp_path):
         """THE acceptance mechanism: process A compiles a structure
@@ -533,3 +600,31 @@ class TestServeCli:
         serve_cmd.set_parser(sub)
         with pytest.raises(SystemExit):
             parser.parse_args(["serve", "--affinity", "sticky"])
+
+    def test_worker_without_its_device_fails_the_start_at_once(
+            self, tmp_path):
+        """One process per chip: a worker takes its device before it
+        binds, so one that cannot have it (here: a platform that does
+        not exist; on a one-chip machine: the chip another worker
+        holds) dies at start and the router reports the death with
+        the worker's log tail — at once, not at the ready deadline.
+        The router parent runs under the same setting and gets as far
+        as spawning: it never initialises a backend itself."""
+        env = dict(os.environ, JAX_PLATFORMS="no_such_platform",
+                   PYTHONPATH=REPO,
+                   PYDCOP_COMPILE_CACHE_DIR=str(tmp_path / "cache"))
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        t0 = time.monotonic()
+        proc = subprocess.run(
+            [sys.executable, "-m", "pydcop_tpu.dcop_cli", "serve",
+             "--port", "0", "--replicas", "2",
+             "--port_file", str(tmp_path / "port")],
+            env=env, cwd=REPO, capture_output=True, text=True,
+            timeout=110)
+        elapsed = time.monotonic() - t0
+        assert proc.returncode != 0
+        assert elapsed < 60, elapsed
+        assert "died on startup" in proc.stderr, proc.stderr[-800:]
+        # The reason travels in the message, not only in a log file.
+        assert "no_such_platform" in proc.stderr
+        assert not (tmp_path / "port").exists()

@@ -56,3 +56,45 @@ def test_zero_messages_give_row_minima():
         out[:, 0, :], costs.min(axis=2), rtol=1e-6)
     np.testing.assert_allclose(
         out[:, 1, :], costs.min(axis=1), rtol=1e-6)
+
+
+class TestFlagAskedForAndUnavailable:
+    """PYDCOP_PALLAS_MAXSUM=1 where the kernel cannot run raises; it
+    never gives way silently to the jnp expression."""
+
+    def test_non_tpu_backend_raises(self, monkeypatch):
+        monkeypatch.setattr(ops, "_PALLAS_FLAG", True)
+        graph, msgs = _bucket(7, 3, 0)
+        with pytest.raises(RuntimeError, match="PYDCOP_PALLAS_MAXSUM"):
+            ops.factor_to_var(graph, (msgs,))
+
+    @pytest.mark.parametrize("kwargs", [
+        {"n_devices": 2}, {"shards": 2}, {"layout": "lane"}])
+    def test_engines_that_cannot_feed_it_refuse_the_flag(
+            self, monkeypatch, kwargs):
+        from pydcop_tpu.engine.runner import (
+            MaxSumEngine,
+            ShardedMaxSumEngine,
+        )
+
+        monkeypatch.setattr(ops, "_PALLAS_FLAG", True)
+        graph, _ = _bucket(40, 3, 1)
+        meta = None  # refused before the meta is ever read
+        with pytest.raises(RuntimeError, match="one device"):
+            if "shards" in kwargs:
+                ShardedMaxSumEngine(graph, meta, n_shards=2)
+            else:
+                MaxSumEngine(graph, meta, **kwargs)
+
+    def test_unsharded_engine_keeps_the_flag_on_a_multi_device_host(
+            self, monkeypatch):
+        """What matters is where the bucket lives, not how many
+        devices exist (8 here): an unsharded engine is not refused."""
+        import jax
+
+        from pydcop_tpu.engine.runner import MaxSumEngine
+
+        assert jax.device_count() > 1
+        monkeypatch.setattr(ops, "_PALLAS_FLAG", True)
+        graph, _ = _bucket(40, 3, 1)
+        MaxSumEngine(graph, None)

@@ -964,15 +964,14 @@ def _write_history(path, values, backend="cpu", start=1):
 class TestBenchSentinel:
     STEADY = [900.0, 860.0, 910.0, 880.0, 895.0, 905.0]
 
-    def test_passes_on_repo_history(self):
+    def test_passes_on_the_repo_root_without_history(self):
+        """The tree keeps no bench rounds (the driver's record is
+        PERF_LEDGER.jsonl): an empty history is a pass, not a
+        crash."""
         report = bench_sentinel.run_check(REPO)
         assert report["failed"] is False
-        assert "cpu" in report["series"]
-        assert report["series"]["cpu"]["verdict"] == "ok"
-        # TPU: one artifact point only — tracked separately, judged
-        # insufficient rather than crashed or merged into CPU.
-        assert report["series"]["tpu"]["verdict"] == "insufficient"
-        assert any("bench[cpu]" in line for line in report["lines"])
+        assert report["series"] == {}
+        assert bench_sentinel.main(["--root", REPO]) == 0
 
     def test_fails_on_synthetic_30pct_regression(self, tmp_path):
         """The acceptance fixture: steady history, newest 30% down."""
@@ -1020,33 +1019,22 @@ class TestBenchSentinel:
         assert report["skipped"] == ["BENCH_r99.json"]
         assert report["failed"] is False
 
-    def test_stale_tpu_artifact_ignored_once_tpu_rounds_exist(
-            self, tmp_path):
-        """BENCH_TPU_LAST.json has no position in the round
-        chronology: with real TPU rounds present, a stale artifact
-        must not be judged as 'the newest run' (spurious REGRESSED
-        or masked real regression)."""
-        _write_history(str(tmp_path),
-                       [1000.0, 1050.0, 990.0, 1020.0],
-                       backend="tpu")
+    @pytest.mark.parametrize("backend", ["tpu", "cpu"])
+    def test_only_numbered_rounds_form_the_series(self, tmp_path,
+                                                  backend):
+        """The history is the numbered rounds and nothing else: a
+        stray artifact beside them (the last-known-TPU file earlier
+        trees kept) is never read, so it can neither seed a series
+        nor be judged as 'the newest run'."""
+        rounds = [1000.0, 1050.0, 990.0, 1020.0]
+        _write_history(str(tmp_path), rounds, backend=backend)
         with open(os.path.join(str(tmp_path), "BENCH_TPU_LAST.json"),
                   "w", encoding="utf-8") as f:
-            json.dump({"value": 500.0, "backend": "tpu",
-                       "recorded_unix": 1.0}, f)
+            json.dump({"value": 500.0, "backend": "tpu"}, f)
         report = bench_sentinel.run_check(str(tmp_path))
-        assert report["series"]["tpu"]["values"] == \
-            [1000.0, 1050.0, 990.0, 1020.0]
+        assert list(report["series"]) == [backend]
+        assert report["series"][backend]["values"] == rounds
         assert report["failed"] is False
-
-    def test_tpu_artifact_seeds_series_without_tpu_rounds(
-            self, tmp_path):
-        _write_history(str(tmp_path), self.STEADY, backend="cpu")
-        with open(os.path.join(str(tmp_path), "BENCH_TPU_LAST.json"),
-                  "w", encoding="utf-8") as f:
-            json.dump({"value": 50_000.0, "backend": "tpu"}, f)
-        report = bench_sentinel.run_check(str(tmp_path))
-        assert report["series"]["tpu"]["values"] == [50_000.0]
-        assert report["series"]["tpu"]["verdict"] == "insufficient"
 
     def test_device_fn_profile_label_is_stable(self):
         from functools import partial
@@ -1175,59 +1163,3 @@ class TestHostShiftGuard:
         assert report["failed"] is False
         assert (report["series"]["serve_mixed_baseline:cpu"]["gating"]
                 is False)
-
-
-# ------------------------------------------------------------------ #
-# bench probe observability satellites
-
-
-class TestProbeObservability:
-    def test_probe_timeout_env(self, monkeypatch):
-        from pydcop_tpu.utils.cleanenv import default_probe_timeout
-
-        monkeypatch.delenv("PYDCOP_BENCH_PROBE_TIMEOUT",
-                           raising=False)
-        assert default_probe_timeout() == 120.0
-        assert default_probe_timeout(60) == 60
-        monkeypatch.setenv("PYDCOP_BENCH_PROBE_TIMEOUT", "7.5")
-        assert default_probe_timeout() == 7.5
-        assert default_probe_timeout(60) == 7.5
-        monkeypatch.setenv("PYDCOP_BENCH_PROBE_TIMEOUT", "bogus")
-        assert default_probe_timeout(60) == 60
-        monkeypatch.setenv("PYDCOP_BENCH_PROBE_TIMEOUT", "-3")
-        assert default_probe_timeout(60) == 60
-
-    def test_record_diag_counts_failures_by_reason(self, monkeypatch):
-        from pydcop_tpu.utils.cleanenv import DIAG_ENV, record_diag
-
-        monkeypatch.setenv(DIAG_ENV, "[]")
-        counter = global_registry.counter(
-            "pydcop_bench_probe_failures_total")
-        t0 = counter.value(reason="timeout")
-        e0 = counter.value(reason="init_error")
-        f0 = counter.value(reason="cpu_fallback")
-        record_diag("probe", tag="t", ok=False,
-                    error="timeout after 120s")
-        record_diag("probe", tag="t", ok=False,
-                    error="exit 1: ImportError")
-        record_diag("probe", tag="t", ok=True, error=None)
-        record_diag("cpu_fallback", tag="t")
-        record_diag("revival_probe", ok=False,
-                    error="timeout after 60s")
-        assert counter.value(reason="timeout") == t0 + 2
-        assert counter.value(reason="init_error") == e0 + 1
-        assert counter.value(reason="cpu_fallback") == f0 + 1
-
-    def test_record_diag_emits_trace_instant(self, monkeypatch):
-        from pydcop_tpu.utils.cleanenv import DIAG_ENV, record_diag
-
-        monkeypatch.setenv(DIAG_ENV, "[]")
-        tracer.enable()
-        record_diag("probe", tag="t", ok=False,
-                    error="timeout after 9s")
-        tracer.disable()
-        instants = [e for e in tracer.events()
-                    if e["name"] == "bench_probe"]
-        assert len(instants) == 1
-        assert instants[0]["args"]["kind"] == "probe"
-        assert instants[0]["args"]["ok"] is False

@@ -526,7 +526,7 @@ class TestFlightRecorder:
             "pre-anomaly context missing from the ring tail"
         # Diagnostics sections all present.
         for section in ("metrics", "healthz", "env",
-                        "probe_diagnostics"):
+                        "efficiency"):
             assert section in doc, f"bundle missing {section}"
         assert doc["pid"] == os.getpid()
 

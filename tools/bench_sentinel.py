@@ -1,20 +1,17 @@
 """Bench regression sentinel: run-over-run guard on the BENCH_r*.json
 trajectory.
 
-Every PR's driver appends a ``BENCH_r<N>.json`` (the supervised
-``bench.py`` line, wrapped with attempt metadata) and TPU runs persist
-``BENCH_TPU_LAST.json`` — but until now nothing ever COMPARED them, so
-a perf regression only surfaced when a human eyeballed the numbers.
-This tool parses the whole history, builds a noise-aware baseline per
-backend (CPU-fallback and TPU rates differ by orders of magnitude and
-must never share a baseline — and CPU baselines are further keyed on
+A driver round appends a ``BENCH_r<N>.json`` (the ``bench.py`` line,
+wrapped with attempt metadata).  This tool parses the whole history,
+builds a noise-aware baseline per backend (CPU and TPU rates differ
+by orders of magnitude and must never share a baseline — and CPU baselines are further keyed on
 the host's core count once a round records ``host_cpus``, because a
 1-core bench box measures the same code ~3x slower than an 8-core
 one), and fails when the newest run regresses beyond threshold.
 
 Noise model: the baseline is the MEDIAN of the trailing window with a
-MAD (median absolute deviation) spread — both robust to the single
-wild outlier a wedged-tunnel run produces.  The newest value regresses
+MAD (median absolute deviation) spread — both robust to a single
+wild outlier.  The newest value regresses
 when it falls below ``median - max(rel_tol * median, mad_mult * MAD)``:
 the relative term guards stable series (MAD ~ 0 would otherwise flag
 every wiggle), the MAD term widens tolerance on genuinely noisy
@@ -79,13 +76,8 @@ def _opt_float(value) -> Optional[float]:
 
 
 def load_history(root: str) -> List[Dict[str, Any]]:
-    """All bench runs in chronological order: ``BENCH_r*.json`` (by
-    round number), plus ``BENCH_TPU_LAST.json`` ONLY when no round
-    ever ran on TPU — the artifact has no position in the round
-    chronology, so once real TPU rounds exist it must not masquerade
-    as "the newest run" (a stale artifact would be judged instead of
-    the actual latest round); with zero TPU rounds it is the only
-    TPU evidence and seeds the series instead.
+    """All bench runs in chronological order: ``BENCH_r*.json`` by
+    round number.
 
     Unreadable or value-less files are skipped with a note in the
     returned rows (``"skipped"`` entries), never a crash — the history
@@ -133,7 +125,7 @@ def load_history(root: str) -> List[Dict[str, Any]]:
             # Rounds 1-5 all fell back to CPU; the earliest line
             # predates the backend key, so absent means cpu.
             "backend": parsed.get("backend") or "cpu",
-            # Host hardware class (ISSUE 17): CPU-fallback rates scale
+            # Host hardware class (ISSUE 17): CPU rates scale
             # with the bench box's core count, so CPU baselines are
             # keyed on it (``cpu@<n>``) once a round records it —
             # rounds that predate the key stay plain ``cpu``.
@@ -240,25 +232,6 @@ def load_history(root: str) -> List[Dict[str, Any]]:
                 if isinstance(info, dict)
             },
         })
-    last_path = os.path.join(root, "BENCH_TPU_LAST.json")
-    have_tpu_round = any(r.get("backend") == "tpu" for r in runs)
-    if os.path.exists(last_path) and not have_tpu_round:
-        try:
-            with open(last_path, encoding="utf-8") as f:
-                doc = json.load(f)
-            if not isinstance(doc, dict):
-                raise ValueError("not a JSON object")
-            value = doc.get("value")
-            if value is not None:
-                runs.append({
-                    "source": "BENCH_TPU_LAST.json",
-                    "n": None,
-                    "value": float(value),
-                    "backend": doc.get("backend") or "tpu",
-                })
-        except (OSError, ValueError) as exc:
-            runs.append({"source": "BENCH_TPU_LAST.json",
-                         "skipped": str(exc)})
     return runs
 
 
@@ -485,20 +458,11 @@ def run_check(root: str, rel_tol: float = DEFAULT_REL_TOL,
             by_backend.setdefault(leg_backend(r), []).append(r)
         # Cross-backend refusal (ISSUE 14): the newest run's leg is
         # judged ONLY against history rows whose recorded leg backend
-        # matches its own resolved backend — a CPU-fallback round
-        # must neither regress nor pad a TPU baseline.  Rows with an
+        # matches its own resolved backend — a CPU round must
+        # neither regress nor pad a TPU baseline.  Rows with an
         # explicit mismatching leg record are named as SKIPPED so the
-        # exclusion is visible, not silent.  "Newest" means the
-        # newest NUMBERED round: load_history appends the stale
-        # BENCH_TPU_LAST reference row last, and a reference artifact
-        # with no position in the chronology must not define which
-        # backend the latest round "resolved".
-        numbered_rows = [
-            r for r in rows_f
-            if re.fullmatch(r"BENCH_r\d+\.json", r.get("source", ""))
-        ]
-        newest_row = (numbered_rows[-1] if numbered_rows
-                      else rows_f[-1] if rows_f else None)
+        # exclusion is visible, not silent.
+        newest_row = rows_f[-1] if rows_f else None
         newest_backend = (leg_backend(newest_row)
                           if newest_row is not None else None)
         skipped_rows = [

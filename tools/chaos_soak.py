@@ -1425,7 +1425,7 @@ def scenario_anomaly_postmortem(seed, trace):
     assert "engine_segment" in tail_names, \
         "pre-anomaly engine context missing from the ring tail"
     for section in ("metrics", "healthz", "env",
-                    "probe_diagnostics"):
+                    "efficiency"):
         assert section in doc, f"bundle missing {section} section"
     return {"bundle": bundles[0],
             "tail_events": len(doc["events"])}
