@@ -112,7 +112,11 @@ def solve(dcop: DCOP, algo_def: Union[str, AlgorithmDef],
     the whole solve on the process tracer and writes a Chrome
     ``trace_event`` JSON (``trace_format="chrome"``, open in
     chrome://tracing / Perfetto) or line-delimited JSON
-    (``"jsonl"``) to that path.  ``metrics_file`` activates the
+    (``"jsonl"``) to that path; it does not change the program that
+    runs (a traced device solve is the untraced one's whole-solve
+    program, with ``engine_call`` in its trace).  ``metrics_file``
+    and ``serve_metrics`` do: their per-chunk snapshots need the
+    segmented loop.  ``metrics_file`` activates the
     metrics registry, appends JSONL snapshots — in device mode one per
     ``metrics_every``-cycle engine chunk (honest per-chunk timings +
     a cost-vs-cycle curve, returned in ``metrics['cost_curve']``),
@@ -123,8 +127,9 @@ def solve(dcop: DCOP, algo_def: Union[str, AlgorithmDef],
     duration of the solve — ``/metrics`` (Prometheus text),
     ``/healthz`` (health verdicts) and ``/events`` (SSE cycle/cost
     stream) — so a long run is scrapeable while it runs
-    (observability/server.py).  An observed device solve also records
-    XLA cost attribution: measured flops/bytes/peak memory per
+    (observability/server.py).  A device solve with ``metrics_file``
+    or ``serve_metrics`` also records XLA cost attribution:
+    measured flops/bytes/peak memory per
     compiled segment land in ``metrics['xla_cost']`` keyed by jit
     cache key (explicit ``available: False`` markers on backends that
     return nothing).  All default off and cost nothing while off.
@@ -239,7 +244,7 @@ def solve(dcop: DCOP, algo_def: Union[str, AlgorithmDef],
                 checkpoint_async=checkpoint_async,
                 checkpoint_keep=checkpoint_keep, resume=resume,
                 fault_plan=fault_plan, recovery=recovery,
-                health=health, observing=session is not None,
+                health=health,
                 metrics_file=metrics_file, metrics_every=metrics_every,
                 serving=serve_metrics is not None,
             )
@@ -835,7 +840,7 @@ def _solve(dcop, algo_def, module, *, distribution, backend, timeout,
            collector,
            collect_moment, collect_period, delay, checkpoint_dir,
            checkpoint_every, checkpoint_async, checkpoint_keep,
-           resume, fault_plan, recovery, health, observing,
+           resume, fault_plan, recovery, health,
            metrics_file, metrics_every, serving=False) -> SolveResult:
     if backend == "device":
         if not hasattr(module, "solve_on_device"):
@@ -849,9 +854,13 @@ def _solve(dcop, algo_def, module, *, distribution, backend, timeout,
 
         initialize_multihost()
         t0 = time.perf_counter()
-        # The engine probe needs chunk boundaries, so an observed solve
-        # routes through the same segmented loop checkpointing uses —
-        # and decimation IS a segmented mode now (clamping happens at
+        # The engine probe needs chunk boundaries, so a solve that
+        # writes metrics snapshots (``metrics_file``) or serves them
+        # (``serve_metrics``) routes through the same segmented loop
+        # checkpointing uses.  ``trace=`` alone does not: a traced
+        # solve runs the program an untraced one runs (its trace
+        # shows ``engine_call``, not ``engine_segment``).
+        # Decimation IS a segmented mode now (clamping happens at
         # those same boundaries), so decimated solves checkpoint,
         # recover and probe like any other.  Excluded: warmup=True
         # (the segmented loop has no discarded warm-up call, and
@@ -865,7 +874,7 @@ def _solve(dcop, algo_def, module, *, distribution, backend, timeout,
             decim_plan = module.decimation_plan_from_params(
                 algo_def.params)
         probed = (
-            observing
+            (metrics_file is not None or serving)
             and not warmup
             and hasattr(module, "build_engine")
         )

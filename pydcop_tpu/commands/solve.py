@@ -299,11 +299,22 @@ def run_cmd(args) -> int:
     if args.mode == "device":
         import contextlib
 
-        profile_ctx = contextlib.nullcontext()
+        profile_ctx = contextlib.ExitStack()
         if args.profile:
             import jax
 
-            profile_ctx = jax.profiler.trace(args.profile)
+            from pydcop_tpu.observability.trace import tracer
+
+            profile_ctx.enter_context(jax.profiler.trace(args.profile))
+            if trace_file is None:
+                # A file session with no file: while it is on, every
+                # span of the program is also a ``pydcop:<name>``
+                # annotation in the profiler's trace, so the raw
+                # trace shows the program's spans beside the device's
+                # operations, on one clock.  (With --trace, solve()
+                # starts the session itself, inside the profile.)
+                tracer.enable()
+                profile_ctx.callback(tracer.disable)
         with profile_ctx:
             res = solve(
                 dcop, algo_def, backend="device",

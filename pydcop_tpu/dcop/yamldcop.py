@@ -32,6 +32,7 @@ from pydcop_tpu.dcop.relations import (
 )
 from pydcop_tpu.dcop.scenario import DcopEvent, EventAction, Scenario
 from pydcop_tpu.distribution.objects import Distribution, DistributionHints
+from pydcop_tpu.observability.trace import tracer
 
 _RANGE_RE = re.compile(r"^\s*(-?\d+)\s*\.\.\s*(-?\d+)\s*$")
 
@@ -104,7 +105,25 @@ def _parse_domain_values(raw_values) -> List:
 
 
 def load_dcop(yaml_str: str, main_dir: str = ".") -> DCOP:
-    data = yaml.safe_load(yaml_str)
+    """Parse the YAML text, then build the DCOP's objects from it.
+    The two halves are spans on ``tracer.active``, so they reach the
+    flight ring with tracing off: the load is the largest host cost
+    of ``pydcop solve`` and of a served request, and a slow request's
+    parse time is what a postmortem wants."""
+    if not tracer.active:
+        return _build_dcop(yaml.safe_load(yaml_str), main_dir)
+    # ``bytes`` counts characters: the same number for ASCII YAML,
+    # without encoding the text a second time.
+    with tracer.span("yaml_parse", "dcop", bytes=len(yaml_str)):
+        data = yaml.safe_load(yaml_str)
+    with tracer.span("yaml_build", "dcop") as span:
+        dcop = _build_dcop(data, main_dir)
+        span.args["n_variables"] = len(dcop.variables)
+        span.args["n_constraints"] = len(dcop.constraints)
+    return dcop
+
+
+def _build_dcop(data, main_dir: str) -> DCOP:
     if not data or "name" not in data:
         raise DcopInvalidFormatError("Missing DCOP name")
     objective = data.get("objective", "min")

@@ -49,11 +49,11 @@ jit cache saves both within one.
 **Hit accounting.**  JAX announces cache activity on its monitoring
 bus; we subscribe once and keep process-wide counters so (a) tests and
 the bench can assert a fresh process genuinely skipped compilation and
-(b) ``timed_jit_call`` can split a cold dispatch honestly: a cold call
-whose executables ALL came off the disk cache did not compile — its
-ledger ``compile`` component is the measured cache-retrieval wall
-(milliseconds), not the whole first-call interval
-(:func:`split_cold_call`).  The serve_cold_start bench leg and the
+(b) ``timed_jit_call`` can say what a first dispatch did: its ``compile``
+seconds are those XLA spent compiling plus those spent reading programs
+off the disk (milliseconds for a call whose executables ALL came off
+the disk cache), never the whole first-call interval
+(:func:`dispatch_compile`).  The serve_cold_start bench leg and the
 fleet docs (docs/serving.md "Persistent compile cache") build on
 exactly this accounting.
 """
@@ -61,7 +61,10 @@ exactly this accounting.
 import logging
 import os
 import threading
+import time
 from typing import Any, Dict, Optional, Tuple
+
+from pydcop_tpu.observability.trace import tracer
 
 logger = logging.getLogger("pydcop.engine.aotcache")
 
@@ -77,6 +80,22 @@ _EVT_HIT = "/jax/compilation_cache/cache_hits"
 _EVT_MISS = "/jax/compilation_cache/cache_misses"
 _DUR_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
 _DUR_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
+# The stages of one jit dispatch that is not in the process's own jit
+# cache (jax/_src/dispatch.py).  JAX reports each as it ends, on the
+# dispatching thread.  The last one wraps ``compile_or_get_cached``,
+# so it also fires after a load from the disk cache.
+_DUR_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_DUR_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_DUR_BACKEND = "/jax/core/compile/backend_compile_duration"
+# Stage -> span name, recorded under a file session (``jax_trace`` /
+# ``jax_lower`` / ``xla_compile`` / ``xla_cache_load``, cat engine).
+_STAGE_SPANS = {_DUR_TRACE: "jax_trace", _DUR_LOWER: "jax_lower",
+                _DUR_BACKEND: "xla_compile",
+                _DUR_RETRIEVAL: "xla_cache_load"}
+# While it traces a program JAX also reports every inner ``jit`` it
+# meets (about a hundred per MaxSum program, microseconds each, all
+# inside the outer stage's interval): no span for those.
+_MIN_STAGE_SPAN_S = 1e-4
 
 _lock = threading.Lock()
 _state: Dict[str, Any] = {
@@ -86,29 +105,74 @@ _state: Dict[str, Any] = {
     "misses": 0,
     "retrieval_s": 0.0,
     "saved_s": 0.0,
+    "compiles": 0,
+    "compile_s": 0.0,
     "listeners_installed": False,
 }
+# Per thread: its own tally of the counters, and when a disk-cache
+# load last ended on it (the backend stage that contains that moment
+# compiled nothing).
+_thread = threading.local()
+
+
+_COUNTERS = ("hits", "misses", "retrieval_s", "saved_s", "compiles",
+             "compile_s")
+
+
+def _bump(key: str, amount) -> None:
+    """Add to the process-wide counter and to the calling thread's
+    own.  JAX compiles (and reports) on the dispatching thread, so
+    the thread's tally is what ONE dispatch did, whatever the
+    service's background compiler does meanwhile on its thread."""
+    with _lock:
+        _state[key] += amount
+    tally = getattr(_thread, "tally", None)
+    if tally is None:
+        tally = _thread.tally = dict.fromkeys(_COUNTERS, 0)
+    tally[key] += amount
 
 
 def _on_event(event: str, **kwargs) -> None:
     if event == _EVT_HIT:
-        with _lock:
-            _state["hits"] += 1
+        _bump("hits", 1)
     elif event == _EVT_MISS:
-        with _lock:
-            _state["misses"] += 1
+        _bump("misses", 1)
 
 
 def _on_duration(event: str, duration: float, **kwargs) -> None:
+    if event == _DUR_SAVED:
+        _bump("saved_s", float(duration))
+        return
+    name = _STAGE_SPANS.get(event)
+    if name is None:
+        return
+    duration = float(duration)
+    # JAX reports a stage as it ends, so it began ``duration`` ago.
+    now = time.perf_counter()
     if event == _DUR_RETRIEVAL:
-        with _lock:
-            _state["retrieval_s"] += float(duration)
-    elif event == _DUR_SAVED:
-        with _lock:
-            _state["saved_s"] += float(duration)
+        _thread.loaded_at = now
+        _bump("retrieval_s", duration)
+    elif event == _DUR_BACKEND:
+        loaded_at = getattr(_thread, "loaded_at", None)
+        _thread.loaded_at = None
+        if loaded_at is not None and loaded_at >= now - duration:
+            # The program came off the disk inside this stage, and
+            # the load was counted and recorded when it ended.
+            return
+        _bump("compiles", 1)
+        _bump("compile_s", duration)
+    if tracer.enabled and duration >= _MIN_STAGE_SPAN_S:
+        # Back-dated, under the calling thread's open span (the
+        # dispatch's ``engine_call``).
+        tracer.complete(name, "engine", t0=now - duration, t1=now,
+                        parent=tracer.current_span_id(),
+                        fun=kwargs.get("fun_name"))
 
 
-def _install_listeners() -> None:
+def install_listeners() -> None:
+    """Subscribe to JAX's monitoring bus (once per process).  The
+    persistent cache need not be on: ``timed_jit_call`` counts the
+    compiles of a process that has none the same way."""
     with _lock:
         if _state["listeners_installed"]:
             return
@@ -170,7 +234,7 @@ def enable_persistent_compile_cache(
     # the process-wide cache object must be rebuilt to pick the
     # directory up.  Safe (idempotent) before the first jit.
     compilation_cache.reset_cache()
-    _install_listeners()
+    install_listeners()
     with _lock:
         _state["enabled"] = True
         _state["dir"] = cache_dir
@@ -184,44 +248,39 @@ def enabled() -> bool:
         return bool(_state["enabled"])
 
 
-def counters() -> Dict[str, float]:
-    """Monotone counter snapshot (hits/misses/retrieval_s/saved_s) —
-    delta two snapshots around a dispatch to attribute ITS cache
-    activity (:func:`split_cold_call`)."""
+def counters(thread: bool = False) -> Dict[str, float]:
+    """Monotone counter snapshot, of the process or (``thread``) of
+    the calling thread alone: delta two of the thread's around a
+    dispatch to attribute ITS activity (:func:`dispatch_compile`).
+    ``hits`` / ``misses`` / ``retrieval_s`` / ``saved_s`` are the
+    disk cache's (a miss is a program compiled AND written);
+    ``compiles`` / ``compile_s`` count every program XLA compiled,
+    written or not, cache on or off."""
+    if thread:
+        return dict(getattr(_thread, "tally", None)
+                    or dict.fromkeys(_COUNTERS, 0))
     with _lock:
-        return {
-            "hits": _state["hits"],
-            "misses": _state["misses"],
-            "retrieval_s": _state["retrieval_s"],
-            "saved_s": _state["saved_s"],
-        }
+        return {key: _state[key] for key in _COUNTERS}
 
 
-def split_cold_call(elapsed_s: float, before: Dict[str, float],
-                    after: Dict[str, float]) -> Optional[float]:
-    """Honest ``compile`` seconds for one COLD jit dispatch given the
-    counter snapshots around it.
-
-    Returns the compile component to report, or None to keep the
-    caller's default convention (cold interval == compile):
-
-    - every executable the dispatch needed came off the disk cache
-      (hits advanced, misses did not) → the dispatch did not compile;
-      its compile component is the measured retrieval wall, clamped
-      into ``[0, elapsed]`` — the serve_cold_start acceptance
-      ("compile ≈ 0 with a warm cache") is THIS number;
-    - any miss, or no cache activity at all (cache disabled,
-      measurement unavailable) → None: the conservative whole-interval
-      convention stands.
-    """
-    if not enabled():
-        return None
-    d_hits = after["hits"] - before["hits"]
-    d_misses = after["misses"] - before["misses"]
-    if d_hits <= 0 or d_misses > 0:
-        return None
-    retrieval = max(after["retrieval_s"] - before["retrieval_s"], 0.0)
-    return min(retrieval, max(elapsed_s, 0.0))
+def dispatch_compile(elapsed_s: float, before: Dict[str, float],
+                     after: Dict[str, float]) -> Dict[str, float]:
+    """What one jit dispatch did besides run, from the calling
+    thread's counter snapshots around it (``counters(thread=True)``,
+    listeners installed): ``xla_compiles`` (programs XLA compiled),
+    ``cache_loads`` (programs read from the disk cache) and
+    ``compile_s``, the seconds those took, clamped into ``[0,
+    elapsed]``: 0 when neither happened, the retrieval wall when the
+    programs all came off the disk (the serve_cold_start acceptance,
+    "compile ≈ 0 with a warm cache", is THIS number).  Tracing,
+    lowering and the run itself are never in it."""
+    spent = ((after["compile_s"] - before["compile_s"])
+             + (after["retrieval_s"] - before["retrieval_s"]))
+    return {
+        "xla_compiles": int(after["compiles"] - before["compiles"]),
+        "cache_loads": int(after["hits"] - before["hits"]),
+        "compile_s": min(max(spent, 0.0), max(elapsed_s, 0.0)),
+    }
 
 
 def disk_stats(directory: Optional[str]) -> Dict[str, int]:

@@ -270,7 +270,7 @@ def stack_to_envelope(
         "stability", "prune",
     ),
 )
-def _batched_solve(stacked, *, max_cycles, damping, damp_vars,
+def _batched_maxsum_solve(stacked, *, max_cycles, damping, damp_vars,
                    damp_factors, stability, prune=False):
     """One jitted program per solver-parameter combination (jit's own
     cache keys on the static args), reused across calls — a fresh
@@ -480,7 +480,7 @@ def launch_stacked(
     t0 = time.perf_counter()
     raw = launch_jit_call(
         _warm, prep.key,
-        functools.partial(_batched_solve, **prep.statics),
+        functools.partial(_batched_maxsum_solve, **prep.statics),
         prep.stacked)
     return PendingDispatch("stacked", raw, prep, prep.key, t0)
 
@@ -556,7 +556,7 @@ def run_stacked(
     with (span if span is not None else contextlib.nullcontext()):
         (values, cycles, stable), compile_s, run_s = timed_jit_call(
             _warm, prep.key,
-            functools.partial(_batched_solve, **prep.statics),
+            functools.partial(_batched_maxsum_solve, **prep.statics),
             prep.stacked,
         )
     elapsed = time.perf_counter() - t0
@@ -571,7 +571,7 @@ def run_stacked(
         "stability",
     ),
 )
-def _lane_packed_solve(lane, *, max_cycles, damping, damp_vars,
+def _lane_packed_maxsum_solve(lane, *, max_cycles, damping, damp_vars,
                        damp_factors, stability):
     """One jitted lane-major solve of a packed union (see
     ``run_lane_packed``); the suppression counters ride out so the
@@ -627,7 +627,7 @@ def run_lane_packed(
         (values, cycle, v2f_count, f2v_count), compile_s, run_s = \
             timed_jit_call(
                 _warm, prep.key,
-                functools.partial(_lane_packed_solve, **prep.statics),
+                functools.partial(_lane_packed_maxsum_solve, **prep.statics),
                 prep.lane,
             )
     elapsed = time.perf_counter() - t0
@@ -774,7 +774,7 @@ def launch_lane_packed(
     t0 = time.perf_counter()
     raw = launch_jit_call(
         _warm, prep.key,
-        functools.partial(_lane_packed_solve, **prep.statics),
+        functools.partial(_lane_packed_maxsum_solve, **prep.statics),
         prep.lane)
     return PendingDispatch("lane", raw, prep, prep.key, t0)
 

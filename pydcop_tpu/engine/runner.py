@@ -30,7 +30,7 @@ from pydcop_tpu.observability.efficiency import (
 )
 from pydcop_tpu.observability.metrics import registry as metrics_registry
 from pydcop_tpu.observability.profiler import key_str, profiler
-from pydcop_tpu.observability.trace import tracer
+from pydcop_tpu.observability.trace import NOOP_SPAN, tracer
 from pydcop_tpu.ops import maxsum as maxsum_ops
 from pydcop_tpu.ops import maxsum_lane as lane_ops
 
@@ -80,14 +80,19 @@ class DecimationState(NamedTuple):
 class DeviceRunResult:
     """Result of an on-device solve.
 
-    Timing convention (uniform across all engines): ``time_s`` is the
-    total wall time of the engine call, INCLUDING any jit compile that
-    happened inside it; ``compile_time_s`` is the compile portion when
-    it was separately measurable, else it EQUALS ``time_s`` (the two
-    fields overlap — never sum them) and ``metrics['cold_start']`` is
-    True.  Callers that need steady-state execution time (benchmarks)
-    warm the engine up with an identical call first; the warm call has
-    ``compile_time_s == 0``."""
+    Timing convention: ``time_s`` is the total wall time of the
+    engine call, INCLUDING any tracing and jit compile that happened
+    inside it.  On the whole-solve path (``MaxSumEngine.run``)
+    ``compile_time_s`` is the part of it XLA spent compiling, or
+    loading executables from the disk cache, from JAX's own counters
+    (``timed_jit_call``'s ``report``): 0 when neither happened.  The
+    other engines still report a first call's compile portion when
+    it was separately measurable, else ``time_s`` itself.  Either
+    way the two fields overlap — never sum them — and
+    ``metrics['cold_start']`` is True on the first call of a
+    program.  Callers that need steady-state execution time
+    (benchmarks) warm the engine up with an identical call first; the
+    warm call has ``compile_time_s == 0``."""
 
     assignment: Dict[str, Any]
     cycles: int
@@ -97,7 +102,13 @@ class DeviceRunResult:
     metrics: Dict[str, Any] = field(default_factory=dict)
 
 
-def timed_jit_call(warm: set, key, fn, *args):
+# What a warm dispatch did besides run (``timed_jit_call``'s report).
+_WARM_DISPATCH = {"first": False, "xla_compiles": 0, "cache_loads": 0,
+                  "compile_s": 0.0}
+
+
+def timed_jit_call(warm: set, key, fn, *args,
+                   report: Optional[Dict[str, Any]] = None):
     """Execute a cached-jit function, splitting compile from run time.
 
     Plain jit dispatch, NOT ``fn.lower(...).compile()``: the AOT
@@ -106,8 +117,19 @@ def timed_jit_call(warm: set, key, fn, *args):
     device-resident state back in on mesh runs.  The first call per
     ``key`` includes trace+compile and reports the whole elapsed
     interval as BOTH compile and run time (the DeviceRunResult
-    overlapping-fields convention; compile dominates); warm calls
-    report (0, elapsed).
+    overlapping-fields convention; compile dominates) — unless its
+    executables all came off the disk cache, when compile is the
+    retrieval wall; warm calls report (0, elapsed).
+
+    What the first call really did comes from JAX's own counters
+    (engine/aotcache.dispatch_compile) and names the span:
+    ``jit_compile`` only when XLA compiled during the call, else
+    ``engine_call`` (a new engine whose program came from the disk
+    cache did not compile), with ``first`` / ``cache_loads`` /
+    ``xla_compiles`` in the args of a first call.  ``report``, when
+    given, is filled with the same and with ``compile_s``, the
+    seconds XLA compiled or loaded (0 when neither happened): the
+    whole-solve path reports that as its compile time.
 
     Completion is forced with engine.timing.sync (a host fetch of
     the smallest output — see the timing module docstring).
@@ -121,64 +143,61 @@ def timed_jit_call(warm: set, key, fn, *args):
     # buffers (the profiler only reads avals, but they come from the
     # live arrays).
     entry = None
-    if first and profiler.enabled:
-        entry = profiler.capture(key, fn, args)
-    # Persistent-cache attribution (engine/aotcache.py): snapshot the
-    # disk-cache counters around a cold dispatch so a first call whose
-    # executables all deserialized from disk reports the retrieval
-    # wall — not the whole interval — as its compile component.
-    aot_before = aotcache.counters() if first and aotcache.enabled() \
-        else None
+    before = None
+    if first:
+        if profiler.enabled:
+            entry = profiler.capture(key, fn, args)
+        aotcache.install_listeners()
+        before = aotcache.counters(thread=True)
+    did = _WARM_DISPATCH
     t0 = time.perf_counter()
-    span = None
-    # Cold dispatches record on ``tracer.active`` (a recompile storm
+    # First dispatches record on ``tracer.active`` (a recompile storm
     # is exactly the signal a flight-recorder postmortem needs); warm
     # dispatches only under a file session — in flight-only mode the
     # enclosing engine_segment span already marks every segment, and
     # the redundant per-segment event would eat the ring AND the ≤5%
     # overhead budget gated in make perf-smoke.
+    span = NOOP_SPAN
     if tracer.enabled or (first and tracer.active):
-        span = tracer.span("jit_compile" if first else "engine_call",
-                           "engine", key=str(key))
-        with span:
-            out = sync(fn(*args))
-    else:
+        span = tracer.span("engine_call", "engine", key=str(key))
+    with span:
         out = sync(fn(*args))
-    elapsed = time.perf_counter() - t0
-    if entry is not None and span is not None:
-        # The recorded event holds this args dict BY REFERENCE until
-        # export, so measured cost lands in the jit_compile span
-        # without widening the timed window.
-        span.args["xla_cost"] = {
-            k: v for k, v in entry.items() if k != "capture_s"
-        }
+        elapsed = time.perf_counter() - t0
+        if first:
+            did = {"first": True, **aotcache.dispatch_compile(
+                elapsed, before, aotcache.counters(thread=True))}
+            if did["xla_compiles"]:
+                span.name = "jit_compile"
+            span.args.update(first=True,
+                             cache_loads=did["cache_loads"],
+                             xla_compiles=did["xla_compiles"])
+            if entry is not None:
+                span.args["xla_cost"] = {
+                    k: v for k, v in entry.items() if k != "capture_s"
+                }
+    if report is not None:
+        report.update(did)
     if metrics_registry.active:
         _account_jit_call(str(key), first, elapsed)
-    if first:
-        warm.add(key)
-        disk_compile = None
-        if aot_before is not None:
-            disk_compile = aotcache.split_cold_call(
-                elapsed, aot_before, aotcache.counters())
-        # Efficiency plane (observability/efficiency.py): global
-        # cold/warm dispatch accounting — the compile column of
-        # waste-by-cause, covering every engine that routes through
-        # this one chokepoint.  The disk-attributed compile (when
-        # available) goes to the tracker too, or /profile's compile
-        # waste would keep charging whole cold intervals the
-        # persistent cache actually saved.
-        efficiency_tracker.record_jit(str(key), first, elapsed,
-                                      compile_s=disk_compile)
-        if disk_compile is not None:
-            # Every executable came off the disk cache: the cold
-            # interval holds trace + retrieval + first run, with
-            # zero XLA compile — charge only the retrieval wall
-            # to ``compile`` so the cold-start ledger says what
-            # actually happened.
-            return out, disk_compile, elapsed
-        return out, elapsed, elapsed
-    efficiency_tracker.record_jit(str(key), first, elapsed)
-    return out, 0.0, elapsed
+    if not first:
+        efficiency_tracker.record_jit(str(key), first, elapsed)
+        return out, 0.0, elapsed
+    warm.add(key)
+    # Every executable came off the disk cache: the first interval
+    # holds trace + retrieval + first run, with zero XLA compile —
+    # charge only the retrieval wall to ``compile`` so the cold-start
+    # ledger says what actually happened.  Otherwise the whole
+    # interval stands.
+    disk_compile = (did["compile_s"] if did["cache_loads"]
+                    and not did["xla_compiles"] else None)
+    # Efficiency plane (observability/efficiency.py): global
+    # cold/warm dispatch accounting — the compile column of
+    # waste-by-cause, covering every engine that routes through this
+    # one chokepoint.
+    efficiency_tracker.record_jit(str(key), first, elapsed,
+                                  compile_s=disk_compile)
+    return out, (elapsed if disk_compile is None else disk_compile), \
+        elapsed
 
 
 def launch_jit_call(warm: set, key, fn, *args):
@@ -247,6 +266,21 @@ def _fn_label(fn) -> str:
         return name
     inner = getattr(fn, "func", None)  # functools.partial
     return getattr(inner, "__name__", None) or type(fn).__name__
+
+
+def _jit_program(name: str, fn, **jit_kwargs):
+    """``jax.jit(fn)`` as a program called ``name``.  The solver
+    programs are ``functools.partial`` objects, which have no name of
+    their own, so JAX called every one of them ``jit__unknown``: in
+    the device trace's ``XLA Modules`` line, in the HLO the profiler
+    stores, in compile logs.  The name is also part of the
+    persistent compile cache's key, and HLO metadata is not: a
+    change to a program that alters only its metadata (a
+    ``jax.named_scope``) has to rename it here, or a shared cache
+    directory goes on serving the older executable, whose trace
+    lacks the new names."""
+    fn.__name__ = name
+    return jax.jit(fn, **jit_kwargs)
 
 
 class _DecimationRun:
@@ -545,7 +579,7 @@ class MaxSumEngine:
         self._jitted: Dict[Any, Any] = {}
         self._warm: set = set()
 
-    def _call(self, key, fn, *args):
+    def _call(self, key, fn, *args, report=None):
         """See timed_jit_call (module level, shared with the dynamic
         engine).  While the profiler is enabled, every compiled
         program's measured cost/memory analysis (or its explicit
@@ -554,7 +588,7 @@ class MaxSumEngine:
         key.  The fold happens only on the COLD dispatch (the one the
         capture rode in on) — warm dispatches skip the profiler
         lock entirely."""
-        out = timed_jit_call(self._warm, key, fn, *args)
+        out = timed_jit_call(self._warm, key, fn, *args, report=report)
         if profiler.enabled and out[1] > 0:
             entry = profiler.get(key)
             if entry is not None:
@@ -588,7 +622,8 @@ class MaxSumEngine:
         ever touches the returned state."""
         key = self._segment_key(extra_cycles, stop_on_convergence)
         if key not in self._jitted:
-            self._jitted[key] = jax.jit(
+            self._jitted[key] = _jit_program(
+                "maxsum_segment",
                 partial(
                     self._ops.run_maxsum_from,
                     extra_cycles=extra_cycles,
@@ -967,7 +1002,8 @@ class MaxSumEngine:
     def _fn(self, max_cycles: int, stop_on_convergence: bool):
         key = (max_cycles, stop_on_convergence)
         if key not in self._jitted:
-            self._jitted[key] = jax.jit(
+            self._jitted[key] = _jit_program(
+                "maxsum_solve",
                 partial(
                     self._ops.run_maxsum,
                     max_cycles=max_cycles,
@@ -994,7 +1030,8 @@ class MaxSumEngine:
         key = ("trace", max_cycles, stop_on_convergence)
         if key not in self._jitted:
             base = self.meta.var_base_costs
-            self._jitted[key] = jax.jit(
+            self._jitted[key] = _jit_program(
+                "maxsum_cost_trace",
                 partial(
                     self._ops.run_maxsum_trace,
                     max_cycles=max_cycles,
@@ -1157,13 +1194,16 @@ class MaxSumEngine:
     def run(self, max_cycles: int = 1000,
             stop_on_convergence: bool = True) -> DeviceRunResult:
         """Steady-state ``time_s`` requires a prior warmup call with
-        the same (max_cycles, stop_on_convergence); a first call
-        reports the trace+compile+run total in BOTH time_s and
-        compile_time_s (bench.py warms up before timing)."""
+        the same (max_cycles, stop_on_convergence): a first call's
+        ``time_s`` holds trace + compile (or cache load) + run.  Its
+        ``compile_time_s`` is the seconds XLA compiled or loaded the
+        program from the disk cache, from the counters (0 when
+        neither happened), not a copy of ``time_s``."""
         key = (max_cycles, stop_on_convergence)
         fn = self._fn(max_cycles, stop_on_convergence)
-        (state, values), compile_s, run_s = self._call(
-            key, fn, self.graph)
+        did: Dict[str, Any] = {}
+        (state, values), _, run_s = self._call(
+            key, fn, self.graph, report=did)
         # One host transfer for all three outputs.
         values, cycle, stable = jax.device_get(
             (values, state.cycle, state.stable)
@@ -1179,12 +1219,12 @@ class MaxSumEngine:
             cycles=cycle,
             converged=stable,
             time_s=run_s,
-            compile_time_s=compile_s,
+            compile_time_s=did["compile_s"],
             metrics={
                 **self.extra_metrics,
                 "msg_count": 2 * n_msgs * cycle,
                 "cycles_per_s": cycle / run_s if run_s > 0 else 0.0,
-                "cold_start": compile_s > 0,
+                "cold_start": did["first"],
             },
         )
 
@@ -1253,8 +1293,8 @@ class ShardedMaxSumEngine(MaxSumEngine):
         self.extra_metrics.update(part_metrics)
         self._segment_span_args["shards"] = mesh.size
 
-    def _call(self, key, fn, *args):
-        out = super()._call(key, fn, *args)
+    def _call(self, key, fn, *args, report=None):
+        out = super()._call(key, fn, *args, report=report)
         if tracer.active:
             # One instant per shard with its static partition stats:
             # the honest per-shard facts a single-program dispatch

@@ -55,6 +55,7 @@ import json
 import math
 import os
 import socket
+import sys
 import threading
 import time
 from collections import defaultdict
@@ -98,7 +99,10 @@ def trace_header() -> Dict[str, Any]:
 
 
 class _NoopSpan:
-    """Shared do-nothing context manager returned while disabled."""
+    """Shared do-nothing context manager returned while disabled.
+    Writes to ``args`` and ``name`` are dropped, so a site that
+    checked ``tracer.active`` just before another thread turned the
+    tracer off cannot fail on the span it was handed."""
 
     __slots__ = ()
 
@@ -108,15 +112,51 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    @property
+    def args(self):
+        return {}
+
+    @property
+    def name(self):
+        return ""
+
+    @name.setter
+    def name(self, value):
+        pass
+
 
 NOOP_SPAN = _NoopSpan()
 
+# Prefix of the profiler annotations the bridge writes, so a reader of
+# the ``.xplane.pb`` can tell the program's spans from JAX's own.
+ANNOTATION_PREFIX = "pydcop:"
+
+
+def _open_annotation(name: str, span_id: int):
+    """The profiler bridge: an entered ``jax.profiler.TraceAnnotation``
+    named ``pydcop:<name>`` carrying ``span_id``, so that under
+    ``jax.profiler.trace`` the ``.xplane.pb`` alone holds the device's
+    operations and the program's spans on ONE clock (the profiler's).
+    None where ``jax`` was never imported: a process that has not
+    touched JAX has no device trace to line up with, and the tracer
+    must not be what imports it."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    annotation = jax.profiler.TraceAnnotation(
+        ANNOTATION_PREFIX + name, span_id=span_id)
+    annotation.__enter__()
+    return annotation
+
 
 class _Span:
-    """An open span; records a complete (``ph:"X"``) event on exit."""
+    """An open span; records a complete (``ph:"X"``) event on exit.
+    ``name`` and ``args`` may be set until then (``timed_jit_call``
+    names its span by what happened inside it); the profiler
+    annotation keeps the name the span was opened with."""
 
     __slots__ = ("_tracer", "name", "cat", "args", "span_id",
-                 "parent_id", "_t0")
+                 "parent_id", "_t0", "_in_session", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Dict[str, Any]):
@@ -127,19 +167,35 @@ class _Span:
         self.span_id = next(tracer._ids)
         self.parent_id = 0
         self._t0 = 0.0
+        self._in_session = False
+        self._annotation = None
 
     def __enter__(self):
         stack = self._tracer._stack()
         self.parent_id = stack[-1] if stack else 0
         stack.append(self.span_id)
+        # Only under a file session: the flight ring alone emits
+        # nothing to the profiler.
+        self._in_session = self._tracer.enabled
+        if self._in_session:
+            self._annotation = _open_annotation(self.name, self.span_id)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
         stack = self._tracer._stack()
         if stack and stack[-1] == self.span_id:
             stack.pop()
+        if self._in_session and not self._tracer.enabled:
+            # Opened under a file session that has ended since: it
+            # belonged to that session (most such sites record under
+            # a session only) and must not land on the flight ring
+            # instead.
+            return False
         self._tracer._record({
             "name": self.name,
             "cat": self.cat,
@@ -324,13 +380,23 @@ class Tracer:
             "args": args,
         })
 
+    def current_span_id(self) -> int:
+        """The calling thread's innermost open span (0 = none)."""
+        stack = self._stack()
+        return stack[-1] if stack else 0
+
     def complete(self, name: str, cat: str = "default", *,
-                 t0: float, t1: float, **args):
+                 t0: float, t1: float, parent: int = 0, **args):
         """Record an already-finished span from explicit
         ``perf_counter`` timestamps (seconds).  For intervals whose
         start lived on no thread — a request's queue wait starts on
         the submitting thread and ends on the scheduler thread; the
-        dispatcher records it retroactively here."""
+        dispatcher records it retroactively here.  ``parent`` nests
+        it (``tracer.current_span_id()`` where the interval lay
+        inside the calling thread's open span, so that span's self
+        time subtracts it); 0 leaves it a root.  A retroactive span
+        has no profiler annotation: an annotation cannot be
+        back-dated."""
         if not self.active:
             return
         self._record({
@@ -340,7 +406,7 @@ class Tracer:
             "ts": float(t0) * _US,
             "dur": max(float(t1) - float(t0), 0.0) * _US,
             "id": next(self._ids),
-            "parent": 0,
+            "parent": parent,
             "args": args,
         })
 

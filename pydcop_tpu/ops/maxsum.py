@@ -531,36 +531,49 @@ def superstep(state: MaxSumState, graph: CompiledFactorGraph, *,
     threaded agent runtime is what makes device-vs-thread cost parity
     assertable on large loopy graphs (bench.py cost_parity)."""
     first = state.cycle == 0
-    valids = tuple(
-        graph.var_valid[b.var_ids] for b in graph.buckets
-    )
+    with jax.named_scope("maxsum/update"):
+        valids = tuple(
+            graph.var_valid[b.var_ids] for b in graph.buckets
+        )
 
-    f2v_cand = factor_to_var(graph, state.v2f, prune=prune)
+    # Each phase carries a ``jax.named_scope`` (the same names in both
+    # layouts; metadata only, the operations and their order are
+    # unchanged), so a device trace reduces by phase whatever the
+    # compiler calls its fusions.
+    with jax.named_scope("maxsum/f2v"):
+        f2v_cand = factor_to_var(graph, state.v2f, prune=prune)
     if damp_factors and damping > 0:
-        f2v_cand = _damp(f2v_cand, state.f2v, damping, first)
+        with jax.named_scope("maxsum/update"):
+            f2v_cand = _damp(f2v_cand, state.f2v, damping, first)
 
     # Variable side uses the factor messages from the PREVIOUS cycle.
-    beliefs, sums = aggregate_beliefs(graph, state.f2v)
-    v2f_cand = var_to_factor(graph, state.f2v, beliefs, sums)
+    with jax.named_scope("maxsum/aggregate"):
+        beliefs, sums = aggregate_beliefs(graph, state.f2v)
+    with jax.named_scope("maxsum/v2f"):
+        v2f_cand = var_to_factor(graph, state.f2v, beliefs, sums)
     if damp_vars and damping > 0:
-        v2f_cand = _damp(v2f_cand, state.v2f, damping, first)
+        with jax.named_scope("maxsum/update"):
+            v2f_cand = _damp(v2f_cand, state.v2f, damping, first)
 
     f2v_new, f2v_count = [], []
     v2f_new, v2f_count = [], []
     all_match = jnp.asarray(True)
-    for i, valid in enumerate(valids):
-        sent, cnt, match = _send_or_suppress(
-            f2v_cand[i], state.f2v[i], state.f2v_count[i],
-            stability, valid, first)
-        f2v_new.append(sent)
-        f2v_count.append(cnt)
-        all_match = all_match & jnp.all(match | ~jnp.any(valid, -1))
-        sent, cnt, match = _send_or_suppress(
-            v2f_cand[i], state.v2f[i], state.v2f_count[i],
-            stability, valid, first)
-        v2f_new.append(sent)
-        v2f_count.append(cnt)
-        all_match = all_match & jnp.all(match | ~jnp.any(valid, -1))
+    with jax.named_scope("maxsum/update"):
+        for i, valid in enumerate(valids):
+            sent, cnt, match = _send_or_suppress(
+                f2v_cand[i], state.f2v[i], state.f2v_count[i],
+                stability, valid, first)
+            f2v_new.append(sent)
+            f2v_count.append(cnt)
+            all_match = all_match & jnp.all(
+                match | ~jnp.any(valid, -1))
+            sent, cnt, match = _send_or_suppress(
+                v2f_cand[i], state.v2f[i], state.v2f_count[i],
+                stability, valid, first)
+            v2f_new.append(sent)
+            v2f_count.append(cnt)
+            all_match = all_match & jnp.all(
+                match | ~jnp.any(valid, -1))
 
     return MaxSumState(
         v2f=tuple(v2f_new),
@@ -644,9 +657,10 @@ def run_maxsum_trace(graph: CompiledFactorGraph, max_cycles: int, *,
                 damp_factors=damp_factors, stability=stability,
                 prune=prune_t,
             )
-            beliefs, _ = aggregate_beliefs(graph, state.f2v)
-            values = select_values(graph, beliefs)
-            cost = cost_of(values)
+            with jax.named_scope("maxsum/select"):
+                beliefs, _ = aggregate_beliefs(graph, state.f2v)
+                values = select_values(graph, beliefs)
+                cost = cost_of(values)
             costs = jax.lax.dynamic_update_slice(
                 costs, cost[None], (state.cycle - 1,))
             return state, costs, cost
@@ -684,8 +698,9 @@ def run_maxsum_trace(graph: CompiledFactorGraph, max_cycles: int, *,
     # the curve stays a valid anytime record at full length.
     costs = jnp.where(
         jnp.arange(max_cycles) >= state.cycle, last, costs)
-    beliefs, _ = aggregate_beliefs(graph, state.f2v)
-    values = select_values(graph, beliefs)
+    with jax.named_scope("maxsum/select"):
+        beliefs, _ = aggregate_beliefs(graph, state.f2v)
+        values = select_values(graph, beliefs)
     return state, values, costs
 
 
@@ -788,6 +803,7 @@ def run_maxsum_from(graph: CompiledFactorGraph, state: MaxSumState,
             return s
 
         state = jax.lax.while_loop(lambda s: ~done(s), phases, state)
-    beliefs, _ = aggregate_beliefs(graph, state.f2v)
-    values = select_values(graph, beliefs)
+    with jax.named_scope("maxsum/select"):
+        beliefs, _ = aggregate_beliefs(graph, state.f2v)
+        values = select_values(graph, beliefs)
     return state, values

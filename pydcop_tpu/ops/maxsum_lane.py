@@ -368,35 +368,45 @@ def superstep(state: LaneState, graph: LaneGraph, *, damping: float,
     """One synchronous cycle, same Jacobi semantics as
     ops/maxsum.superstep (both sides fire from last cycle's mail)."""
     first = state.cycle == 0
-    valids = tuple(
-        graph.var_valid[:, b.var_ids] for b in graph.buckets
-    )
+    with jax.named_scope("maxsum/update"):
+        valids = tuple(
+            graph.var_valid[:, b.var_ids] for b in graph.buckets
+        )
 
-    f2v_cand = factor_to_var(graph, state.v2f)
+    # Phase names as in ops/maxsum.superstep.
+    with jax.named_scope("maxsum/f2v"):
+        f2v_cand = factor_to_var(graph, state.v2f)
     if damp_factors and damping > 0:
-        f2v_cand = _damp(f2v_cand, state.f2v, damping, first)
+        with jax.named_scope("maxsum/update"):
+            f2v_cand = _damp(f2v_cand, state.f2v, damping, first)
 
-    beliefs, sums = aggregate_beliefs(graph, state.f2v)
-    v2f_cand = var_to_factor(graph, state.f2v, beliefs, sums)
+    with jax.named_scope("maxsum/aggregate"):
+        beliefs, sums = aggregate_beliefs(graph, state.f2v)
+    with jax.named_scope("maxsum/v2f"):
+        v2f_cand = var_to_factor(graph, state.f2v, beliefs, sums)
     if damp_vars and damping > 0:
-        v2f_cand = _damp(v2f_cand, state.v2f, damping, first)
+        with jax.named_scope("maxsum/update"):
+            v2f_cand = _damp(v2f_cand, state.v2f, damping, first)
 
     f2v_new, f2v_count = [], []
     v2f_new, v2f_count = [], []
     all_match = jnp.asarray(True)
-    for i, valid in enumerate(valids):
-        sent, cnt, match = _send_or_suppress(
-            f2v_cand[i], state.f2v[i], state.f2v_count[i],
-            stability, valid, first)
-        f2v_new.append(sent)
-        f2v_count.append(cnt)
-        all_match = all_match & jnp.all(match | ~jnp.any(valid, 0))
-        sent, cnt, match = _send_or_suppress(
-            v2f_cand[i], state.v2f[i], state.v2f_count[i],
-            stability, valid, first)
-        v2f_new.append(sent)
-        v2f_count.append(cnt)
-        all_match = all_match & jnp.all(match | ~jnp.any(valid, 0))
+    with jax.named_scope("maxsum/update"):
+        for i, valid in enumerate(valids):
+            sent, cnt, match = _send_or_suppress(
+                f2v_cand[i], state.f2v[i], state.f2v_count[i],
+                stability, valid, first)
+            f2v_new.append(sent)
+            f2v_count.append(cnt)
+            all_match = all_match & jnp.all(
+                match | ~jnp.any(valid, 0))
+            sent, cnt, match = _send_or_suppress(
+                v2f_cand[i], state.v2f[i], state.v2f_count[i],
+                stability, valid, first)
+            v2f_new.append(sent)
+            v2f_count.append(cnt)
+            all_match = all_match & jnp.all(
+                match | ~jnp.any(valid, 0))
 
     return LaneState(
         v2f=tuple(v2f_new),
@@ -480,8 +490,9 @@ def run_maxsum_from(graph: LaneGraph, state: LaneState,
         state = jax.lax.while_loop(
             lambda s: s.cycle < limit, step, state,
         )
-    beliefs, _ = aggregate_beliefs(graph, state.f2v)
-    values = select_values(graph, beliefs)
+    with jax.named_scope("maxsum/select"):
+        beliefs, _ = aggregate_beliefs(graph, state.f2v)
+        values = select_values(graph, beliefs)
     return state, values
 
 
@@ -513,9 +524,11 @@ def run_maxsum_trace(graph: LaneGraph, max_cycles: int, *,
             state, graph, damping=damping, damp_vars=damp_vars,
             damp_factors=damp_factors, stability=stability,
         )
-        beliefs, _ = aggregate_beliefs(graph, state.f2v)
-        values = select_values(graph, beliefs)
-        cost = cost_of(values)
+        with jax.named_scope("maxsum/select"):
+            with jax.named_scope("maxsum/select"):
+                beliefs, _ = aggregate_beliefs(graph, state.f2v)
+                values = select_values(graph, beliefs)
+                cost = cost_of(values)
         costs = jax.lax.dynamic_update_slice(
             costs, cost[None], (state.cycle - 1,))
         return state, costs, cost
@@ -535,6 +548,7 @@ def run_maxsum_trace(graph: LaneGraph, max_cycles: int, *,
     )
     costs = jnp.where(
         jnp.arange(max_cycles) >= state.cycle, last, costs)
-    beliefs, _ = aggregate_beliefs(graph, state.f2v)
-    values = select_values(graph, beliefs)
+    with jax.named_scope("maxsum/select"):
+        beliefs, _ = aggregate_beliefs(graph, state.f2v)
+        values = select_values(graph, beliefs)
     return state, values, costs

@@ -45,7 +45,7 @@ from pydcop_tpu.observability.server import (
     get_health_provider,
     set_health_provider,
 )
-from pydcop_tpu.observability.trace import tracer
+from pydcop_tpu.observability.trace import NOOP_SPAN, tracer
 from pydcop_tpu.serving.admission import AdmissionRejected
 from pydcop_tpu.serving.service import SolveService, WidthRejected
 from pydcop_tpu.serving.sessions import (
@@ -93,10 +93,25 @@ def _result_code(result: Dict[str, Any]) -> int:
 class _ServeHandler(_Handler):
     """Telemetry routes + the solve request plane."""
 
+    # The open ``http_request`` span of the ``POST /solve`` being
+    # handled, or None: ``_json`` writes the reply's code into it.
+    _request_span = None
+
+    def _part(self, name: str):
+        """A child span of the open ``http_request`` span (file
+        session only: read, wait and reply merely tile it)."""
+        if self._request_span is not None and tracer.enabled:
+            return tracer.span(name, "serving")
+        return NOOP_SPAN
+
     def _json(self, code: int, payload: Dict[str, Any],
               close: bool = False):
-        self._reply(code, json.dumps(payload, default=str).encode(),
-                    "application/json", close=close)
+        if self._request_span is not None:
+            self._request_span.args["code"] = code
+        with self._part("http_reply"):
+            self._reply(code,
+                        json.dumps(payload, default=str).encode(),
+                        "application/json", close=close)
 
     def _read_json_body(self) -> Optional[Dict[str, Any]]:
         """Read + decode the request's JSON object body; replies the
@@ -171,7 +186,27 @@ class _ServeHandler(_Handler):
             # error path that skips the read.
             self._json(404, {"error": "unknown path"}, close=True)
             return
-        body = self._read_json_body()
+        if not tracer.active:
+            self._solve()
+            return
+        # Body read to reply written, on the flight ring too: with
+        # ``yaml_parse`` / ``yaml_build`` / ``serve_submit`` under it
+        # a slow request says where its front-end time went.
+        with tracer.span("http_request", "serving") as span:
+            self._request_span = span
+            try:
+                self._solve()
+            finally:
+                self._request_span = None
+
+    def _solve(self):
+        """``POST /solve`` (under ``_request_span`` when it is set)."""
+        span = self._request_span
+        with self._part("http_read"):
+            body = self._read_json_body()
+        if span is not None:
+            length = self.headers.get("Content-Length", "")
+            span.args["bytes"] = int(length) if length.isdigit() else 0
         if body is None:
             return
         yaml_src = body.get("dcop")
@@ -253,8 +288,18 @@ class _ServeHandler(_Handler):
         except Exception as exc:  # noqa: BLE001 — malformed problem
             self._json(400, {"error": f"bad problem: {exc}"})
             return
+        if span is not None:
+            # The identifier serve_submit / serve_queued /
+            # serve_dispatch carry: one request's spans share it.
+            try:
+                span.args["trace_id"] = service.trace_id(rid)
+            except KeyError:  # evicted already (tiny result_keep)
+                pass
         if body.get("wait"):
-            result = service.result(rid, wait=timeout)
+            # A wait, not work: the handler thread blocks here while
+            # the scheduler thread queues, dispatches and decodes.
+            with self._part("http_wait"):
+                result = service.result(rid, wait=timeout)
             if result is not None:
                 self._json(_result_code(result), result)
                 return

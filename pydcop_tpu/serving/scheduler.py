@@ -26,6 +26,7 @@ import threading
 import time
 from typing import Dict, List
 
+from pydcop_tpu.observability.trace import NOOP_SPAN, tracer
 from pydcop_tpu.serving.sessions import SessionWork
 
 logger = logging.getLogger("pydcop.serving.scheduler")
@@ -72,11 +73,26 @@ class BinScheduler:
 
     def _run(self):
         q = self.service._queue
+        # Under a file session three spans tile this thread's time
+        # between flushes: ``sched_idle`` (nothing to dispatch: from
+        # the end of a flush to the next request's arrival, ONE span
+        # however many 0.1 s polls it takes), ``sched_collect`` (the
+        # linger window) and ``sched_flush`` (plan, launch, collect).
+        # ``sched_idle`` is a live span, not a retroactive one: only
+        # an open span has a profiler annotation, and the device's
+        # idle time is attributed on the profiler's clock.
+        idle = None
         while not self._stop.is_set():
+            if idle is None and tracer.enabled:
+                idle = tracer.span("sched_idle", "serving")
+                idle.__enter__()
             try:
                 first = q.get(timeout=0.1)
             except queue.Empty:
                 continue
+            if idle is not None:
+                idle.__exit__(None, None, None)
+                idle = None
             if first is _STOP:
                 continue
             # Session work (stateful sessions, serving/sessions.py)
@@ -95,13 +111,23 @@ class BinScheduler:
             bins: Dict = {}
             bins.setdefault(first.bin, []).append(first)
             session_work: List = []
-            self._collect(q, bins, session_work)
-            self._dispatch_bins(bins)
+            traced = tracer.enabled
+            with (tracer.span("sched_collect", "serving")
+                  if traced else NOOP_SPAN) as span:
+                self._collect(q, bins, session_work)
+                n_requests = sum(len(v) for v in bins.values())
+                span.args["n_requests"] = n_requests
+            with (tracer.span("sched_flush", "serving",
+                              n_requests=n_requests)
+                  if traced else NOOP_SPAN) as span:
+                span.args["n_chunks"] = self._dispatch_bins(bins)
             # Session work drained during the window runs AFTER the
             # flush (events apply between segments/dispatches by
             # design) but in its original queue order.
             for work in session_work:
                 self.service.run_session_work(work)
+        if idle is not None:
+            idle.__exit__(None, None, None)
         # Shutdown: the service fails anything still queued.
 
     def _expire(self, req) -> bool:
@@ -146,7 +172,9 @@ class BinScheduler:
                 continue
             bins.setdefault(req.bin, []).append(req)
 
-    def _dispatch_bins(self, bins: Dict) -> None:
+    def _dispatch_bins(self, bins: Dict) -> int:
+        """Plan and run one flush; returns the number of dispatches
+        (``max_batch``-sized chunks) it made."""
         # The flush plan (serving/service.plan_flush): multi-request
         # bins keep the exact path; leftover singleton bins are
         # envelope-grouped and packed when the per-flush cost model
@@ -223,6 +251,7 @@ class BinScheduler:
                             req, f"internal dispatch error: {exc}")
         while pending:
             self._collect_one(pending.pop(0), collect)
+        return len(chunks)
 
     def _collect_one(self, pb, collect) -> None:
         """Drain one in-flight dispatch; collect_dispatch handles its

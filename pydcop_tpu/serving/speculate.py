@@ -9,7 +9,7 @@ solver statics).  Nothing about that program needs a live request to
 exist: the stacked input's avals can be derived abstractly with
 ``jax.eval_shape`` (zero device work), and the executable can be
 built with compile-only AOT lowering
-(``_batched_solve.lower(...).compile()``) which populates the PR-15
+(``_batched_maxsum_solve.lower(...).compile()``) which populates the PR-15
 persistent compile cache on disk WITHOUT touching jit's dispatch
 cache — so when the real traffic arrives, the "cold" jit call
 resolves as a fast disk hit instead of a multi-hundred-ms XLA build
@@ -286,7 +286,7 @@ class SpeculativeCompiler:
             # populates the persistent disk cache when enabled) but
             # NEVER dispatches — the device stays with the scheduler
             # thread.
-            engine_batch._batched_solve.lower(
+            engine_batch._batched_maxsum_solve.lower(
                 stacked, **job.statics).compile()
         wall = time.perf_counter() - t0
         with self._lock:

@@ -209,8 +209,8 @@ def test_dsa_program_compiles_10k(problem_10k, one_chip):
 
 def test_serve_batch_program_compiles(one_chip):
     """The vmapped program one same-structure serve batch dispatches
-    (engine/batch._batched_solve), at the smoke's batch size."""
-    from pydcop_tpu.engine.batch import _batched_solve, stack_graphs
+    (engine/batch._batched_maxsum_solve), at the smoke's batch size."""
+    from pydcop_tpu.engine.batch import _batched_maxsum_solve, stack_graphs
 
     dcop = generate_graph_coloring(
         SERVE_GRID_VARS, 3, "grid", soft=True, noagents=True, seed=1)
@@ -218,7 +218,7 @@ def test_serve_batch_program_compiles(one_chip):
     stacked = stack_graphs([graph] * SERVE_BATCH)
     _, row = compile_row(
         f"serve batch {SERVE_BATCH} x {SERVE_GRID_VARS}-var grid",
-        _batched_solve, abstract(stacked, lambda a: one_chip),
+        _batched_maxsum_solve, abstract(stacked, lambda a: one_chip),
         max_cycles=SERVE_CYCLES, damping=0.5, damp_vars=True,
         damp_factors=True, stability=STABILITY_COEFF, prune=False)
     assert device_bytes(row) < V5E_HBM_BYTES

@@ -225,9 +225,11 @@ class TestEndToEnd:
 
 
 class TestTimingConvention:
-    """DeviceRunResult timing contract (engine/runner.py docstring):
-    cold calls report the whole interval in BOTH fields with
-    cold_start=True; warm calls split compile_time_s=0."""
+    """DeviceRunResult timing contract on the whole-solve path
+    (engine/runner.py docstring): a first call has cold_start=True
+    and its compile_time_s is what XLA spent compiling (from JAX's
+    counters), a part of time_s and never a copy of it; warm calls
+    have compile_time_s=0."""
 
     def _engine(self):
         from pydcop_tpu.dcop.objects import Domain, Variable
@@ -246,7 +248,8 @@ class TestTimingConvention:
         engine = self._engine()
         cold = engine.run(max_cycles=5, stop_on_convergence=False)
         assert cold.metrics["cold_start"] is True
-        assert cold.compile_time_s == cold.time_s > 0
+        # Tracing, lowering and the run are in time_s alone.
+        assert 0 < cold.compile_time_s < cold.time_s
         warm = engine.run(max_cycles=5, stop_on_convergence=False)
         assert warm.metrics["cold_start"] is False
         assert warm.compile_time_s == 0.0

@@ -509,9 +509,16 @@ class TestFlushPlanning:
                    for p in plans)
         assert not svc.envelope_decisions
 
-    def test_losing_group_falls_back_to_solo(self):
+    def test_losing_group_falls_back_to_solo(self, monkeypatch,
+                                             tmp_path):
         """A group the cost model prices out must dispatch solo —
-        packing is an optimization, never a forced path."""
+        packing is an optimization, never a forced path.  Priced by
+        the compiled-in constants: the online fit is process-wide and
+        persisted beside the checkout, so what other tests of this
+        run dispatched would otherwise decide the outcome."""
+        monkeypatch.setenv("PYDCOP_PACK_FIT", "0")
+        monkeypatch.setenv("PYDCOP_AGG_AUTOTUNE_CACHE",
+                           str(tmp_path / "autotune.json"))
         svc = SolveService(envelope_packing=True,
                            envelope_overhead_ms=0.0)
         reqs = self._reqs(svc, [_ring(n, 3, n) for n in (6, 7)])

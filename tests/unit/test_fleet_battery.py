@@ -256,36 +256,29 @@ class TestRoutingPolicy:
 
 
 class TestAotCache:
-    def test_split_cold_call_contract(self):
+    def test_dispatch_compile_contract(self):
+        """What one dispatch did, from the calling thread's counter
+        snapshots around it: compiles, disk-cache loads, and the
+        seconds both took — 0 when neither happened, never the whole
+        interval."""
         before = {"hits": 2, "misses": 1, "retrieval_s": 0.5,
-                  "saved_s": 0.0}
-        pure_hit = {"hits": 4, "misses": 1, "retrieval_s": 0.56,
-                    "saved_s": 0.0}
-        with_miss = {"hits": 4, "misses": 2, "retrieval_s": 0.56,
-                     "saved_s": 0.0}
-        no_activity = dict(before)
-        from pydcop_tpu.engine.aotcache import _lock, _state
-
-        with _lock:
-            was = _state["enabled"]
-            _state["enabled"] = True
-        try:
-            got = aotcache.split_cold_call(1.0, before, pure_hit)
-            assert got == pytest.approx(0.06)
-            # Clamped into the measured interval.
-            assert aotcache.split_cold_call(
-                0.01, before, pure_hit) == pytest.approx(0.01)
-            # Any miss → the whole-interval convention stands.
-            assert aotcache.split_cold_call(
-                1.0, before, with_miss) is None
-            assert aotcache.split_cold_call(
-                1.0, before, no_activity) is None
-        finally:
-            with _lock:
-                _state["enabled"] = was
-        if not was:
-            assert aotcache.split_cold_call(
-                1.0, before, pure_hit) is None  # disabled → None
+                  "saved_s": 0.0, "compiles": 3, "compile_s": 4.0}
+        pure_hit = dict(before, hits=4, retrieval_s=0.56)
+        with_compile = dict(pure_hit, misses=2, compiles=4,
+                            compile_s=4.3)
+        got = aotcache.dispatch_compile(1.0, before, pure_hit)
+        assert got["cache_loads"] == 2 and got["xla_compiles"] == 0
+        assert got["compile_s"] == pytest.approx(0.06)
+        # Clamped into the measured interval.
+        assert aotcache.dispatch_compile(
+            0.01, before, pure_hit)["compile_s"] == pytest.approx(0.01)
+        # A compile adds XLA's own seconds to the retrieval wall.
+        got = aotcache.dispatch_compile(1.0, before, with_compile)
+        assert got["xla_compiles"] == 1
+        assert got["compile_s"] == pytest.approx(0.36)
+        # Neither happened: nothing is charged.
+        assert aotcache.dispatch_compile(1.0, before, dict(before)) == {
+            "xla_compiles": 0, "cache_loads": 0, "compile_s": 0.0}
 
     @pytest.mark.parametrize("case", [
         "jax_env_wins", "explicit_arg", "pydcop_env", "default"])

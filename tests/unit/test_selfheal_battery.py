@@ -724,6 +724,10 @@ class TestAsyncWriterAtexit:
         writer.submit({"x": np.zeros(3)}, 5)
         with pytest.raises(RuntimeError, match="checkpoint write"):
             writer.flush()
+        # The error was surfaced once; stop the writer's thread, or a
+        # later test of this worker finds ``pydcop-ckpt-writer``
+        # among the threads a stopped service left running.
+        writer.close()
 
     def test_explicit_close_still_raises(self, tmp_path, monkeypatch):
         writer = self._failing_writer(tmp_path, monkeypatch)

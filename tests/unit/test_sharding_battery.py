@@ -302,22 +302,35 @@ class TestPartitionedEngine:
 
 @needs_mesh
 class TestShardTraceLanes:
-    def _sharded_trace(self, tmp_path, name):
+    def _sharded_trace(self, tmp_path, name, **observe):
         from pydcop_tpu.api import solve
 
         path = str(tmp_path / name)
         solve(_grid_dcop(8), "maxsum", max_cycles=30, shards=8,
-              trace=path)
+              trace=path, **observe)
         return path
 
-    def test_engine_spans_tagged_and_instants_emitted(self, tmp_path):
+    @pytest.mark.parametrize("segmented", [False, True])
+    def test_engine_spans_tagged_and_instants_emitted(
+            self, tmp_path, segmented):
+        """``trace=`` alone traces the whole-solve program (one
+        dispatch, no ``engine_segment``); with ``metrics_file`` the
+        probe's segmented loop runs and its segments carry the shard
+        count.  Either way every shard gets its instants."""
         from pydcop_tpu.observability.trace import load_trace_file
 
+        observe = ({"metrics_file": str(tmp_path / "m.jsonl")}
+                   if segmented else {})
         events = load_trace_file(
-            self._sharded_trace(tmp_path, "a.json"))
+            self._sharded_trace(tmp_path, "a.json", **observe))
         segs = [e for e in events if e.get("name") == "engine_segment"]
-        assert segs and all(
-            e["args"].get("shards") == 8 for e in segs)
+        if segmented:
+            assert segs and all(
+                e["args"].get("shards") == 8 for e in segs)
+        else:
+            assert not segs
+            assert [e for e in events if e.get("name") in (
+                "jit_compile", "engine_call")]
         shard_ids = {e["args"]["shard"] for e in events
                      if e.get("name") == "shard_segment"}
         assert shard_ids == set(range(8))
@@ -356,8 +369,8 @@ class TestShardTraceLanes:
                 assert f"[shard {shard}]" in lane_labels[tid]
         # Host-thread spans stay off the shard lanes.
         span_tids = {e["tid"] for e in events
-                     if e.get("name") == "engine_segment"}
-        assert span_tids.isdisjoint(all_shard_tids)
+                     if e.get("name") in ("jit_compile", "engine_call")}
+        assert span_tids and span_tids.isdisjoint(all_shard_tids)
 
 
 # ------------------------- bench sentinel series -------------------- #
