@@ -28,7 +28,7 @@ import subprocess
 import sys
 from typing import Dict, List, Tuple
 
-import yaml
+from pydcop_tpu.dcop.yamldcop import _yaml_load
 
 logger = logging.getLogger("pydcop.cli.batch")
 
@@ -44,7 +44,7 @@ def set_parser(subparsers):
 
 def run_cmd(args) -> int:
     with open(args.bench_file, encoding="utf-8") as f:
-        definition = yaml.safe_load(f)
+        definition = _yaml_load(f)
     progress_file = os.path.join(
         os.path.dirname(os.path.abspath(args.bench_file)),
         "progress_" + os.path.basename(args.bench_file),

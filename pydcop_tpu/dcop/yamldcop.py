@@ -1,11 +1,17 @@
 """YAML (de)serialization for DCOPs, agents, distributions and scenarios.
 
-Reference parity: pydcop/dcop/yamldcop.py (load_dcop_from_file :63,
-load_dcop :96, dcop_yaml :119, _build_constraints :217, _build_agents
-:316, yaml_agents :397, scenario load :504).  Format spec:
-docs/usage/file_formats/dcop_format.yml in the reference — this module
-accepts the exact same files (round-trip tested against the reference's
-fixtures in tests/instances/).
+Reference parity: pydcop/dcop/yamldcop.py (load_dcop_from_file,
+load_dcop, dcop_yaml, _build_constraints, _build_agents, yaml_agents,
+load_scenario).  Format spec: docs/usage/file_formats/dcop_format.yml
+in the reference — this module accepts the exact same files
+(round-trip tested against the reference's fixtures in
+tests/instances/).
+
+The package's only calls into PyYAML are ``_yaml_load`` and
+``_yaml_dump`` below.  Loading binds to libyaml (``CSafeLoader``)
+when PyYAML was built with it, else to the pure-Python ``SafeLoader``:
+the same data, several times slower.  ``YAML_LOADER`` says which
+(``"c"`` or ``"python"``).  Dumping stays on ``SafeDumper``.
 """
 
 import os
@@ -35,6 +41,26 @@ from pydcop_tpu.distribution.objects import Distribution, DistributionHints
 from pydcop_tpu.observability.trace import tracer
 
 _RANGE_RE = re.compile(r"^\s*(-?\d+)\s*\.\.\s*(-?\d+)\s*$")
+
+# Chosen once, from what the installation has.  Always a *safe* loader:
+# a served request's body goes through here.
+if yaml.__with_libyaml__:
+    _Loader, YAML_LOADER = yaml.CSafeLoader, "c"
+else:
+    _Loader, YAML_LOADER = yaml.SafeLoader, "python"
+
+
+def _yaml_load(text):
+    """Safe-load one document from a string or an open file."""
+    return yaml.load(text, Loader=_Loader)
+
+
+def _yaml_dump(data, **kw) -> str:
+    """Safe-dump to a string, keys in insertion order.  Not libyaml's
+    emitter: it folds a long double-quoted scalar (an instance's
+    ``description``) at other places than PyYAML's, and dumped
+    problems are compared and hashed as bytes."""
+    return yaml.dump(data, Dumper=yaml.SafeDumper, sort_keys=False, **kw)
 
 
 class DcopInvalidFormatError(Exception):
@@ -111,11 +137,12 @@ def load_dcop(yaml_str: str, main_dir: str = ".") -> DCOP:
     of ``pydcop solve`` and of a served request, and a slow request's
     parse time is what a postmortem wants."""
     if not tracer.active:
-        return _build_dcop(yaml.safe_load(yaml_str), main_dir)
+        return _build_dcop(_yaml_load(yaml_str), main_dir)
     # ``bytes`` counts characters: the same number for ASCII YAML,
     # without encoding the text a second time.
-    with tracer.span("yaml_parse", "dcop", bytes=len(yaml_str)):
-        data = yaml.safe_load(yaml_str)
+    with tracer.span("yaml_parse", "dcop", bytes=len(yaml_str),
+                     loader=YAML_LOADER):
+        data = _yaml_load(yaml_str)
     with tracer.span("yaml_build", "dcop") as span:
         dcop = _build_dcop(data, main_dir)
         span.args["n_variables"] = len(dcop.variables)
@@ -400,7 +427,7 @@ def dcop_yaml(dcop: DCOP) -> str:
             hints["must_host"] = dcop.dist_hints.must_host_map
         if hints:
             data["distribution_hints"] = hints
-    return yaml.safe_dump(data, sort_keys=False, default_flow_style=False)
+    return _yaml_dump(data, default_flow_style=False)
 
 
 def yaml_agents(agents: List[AgentDef]) -> str:
@@ -424,7 +451,7 @@ def yaml_agents(agents: List[AgentDef]) -> str:
         out["hosting_costs"] = hosting
     if routes:
         out["routes"] = routes
-    return yaml.safe_dump(out, sort_keys=False)
+    return _yaml_dump(out)
 
 
 def load_agents_from_file(filename: str) -> List[AgentDef]:
@@ -433,7 +460,7 @@ def load_agents_from_file(filename: str) -> List[AgentDef]:
 
 
 def load_agents(yaml_str: str) -> List[AgentDef]:
-    data = yaml.safe_load(yaml_str) or {}
+    data = _yaml_load(yaml_str) or {}
     dcop = DCOP("agents_only")
     _build_agents(dcop, data.get("agents"), data.get("routes"),
                   data.get("hosting_costs"))
@@ -450,7 +477,7 @@ def load_scenario_from_file(filename: str) -> Scenario:
 
 
 def load_scenario(yaml_str: str) -> Scenario:
-    data = yaml.safe_load(yaml_str) or {}
+    data = _yaml_load(yaml_str) or {}
     events = []
     for espec in data.get("events") or []:
         if "delay" in espec:
@@ -480,7 +507,7 @@ def yaml_scenario(scenario: Scenario) -> str:
                     {"type": a.type, **a.args} for a in e.actions
                 ],
             })
-    return yaml.safe_dump({"events": events}, sort_keys=False)
+    return _yaml_dump({"events": events})
 
 
 # --------------------------------------------------------------------- #
@@ -493,7 +520,7 @@ def load_dist_from_file(filename: str) -> Distribution:
 
 
 def load_dist(yaml_str: str) -> Distribution:
-    data = yaml.safe_load(yaml_str) or {}
+    data = _yaml_load(yaml_str) or {}
     mapping = data.get("distribution", {})
     return Distribution({a: list(cs or []) for a, cs in mapping.items()})
 
@@ -506,4 +533,4 @@ def yaml_dist(dist: Distribution, inputs: Optional[Dict] = None,
     data["distribution"] = dist.mapping
     if cost is not None:
         data["cost"] = cost
-    return yaml.safe_dump(data, sort_keys=False)
+    return _yaml_dump(data)

@@ -100,8 +100,12 @@ def main(args=None) -> int:
     _configure_logs(parsed.verbosity)
     if parsed.version:
         import pydcop_tpu
+        from pydcop_tpu.dcop.yamldcop import YAML_LOADER
 
-        print(f"pydcop-tpu {pydcop_tpu.__version__}")
+        # "python" means PyYAML was built without libyaml: loading a
+        # problem takes several times as long.
+        print(f"pydcop-tpu {pydcop_tpu.__version__} "
+              f"(yaml loader: {YAML_LOADER})")
         return 0
     if not getattr(parsed, "func", None):
         parser.print_help()
