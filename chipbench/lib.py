@@ -1,10 +1,12 @@
 """What both runners share: the checks of an answer, the in-process
 ``pydcop`` CLI, the HTTP helpers (all lifted from ``chip_smoke.py``,
-which proved them on the chip in PR 22), the instance generator, the
+which proved them on the chip in PR 22), the lookup of a module by
+name (runner, reader, family), the instance and its shapes, the
 percentile and the traced block."""
 
 import contextlib
 import glob
+import importlib
 import io
 import json
 import math
@@ -13,6 +15,8 @@ import time
 import urllib.request
 
 import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
 
 
 class BenchFailure(Exception):
@@ -24,53 +28,41 @@ def note(**fields):
     print(json.dumps(fields), flush=True)
 
 
+def module_by_name(package, name, what):
+    """``chipbench/<package>/<name>.py``, imported; a name that
+    resolves to no file names the file looked for."""
+    path = os.path.join(HERE, package, f"{name}.py")
+    if not os.path.isfile(path):
+        raise BenchFailure(f"{what} {name!r}: no file {path}")
+    return importlib.import_module(f"chipbench.{package}.{name}")
+
+
+def family_of(spec):
+    """The module of ``chipbench/families/`` that a configuration's
+    ``generator`` names.  There is no default family."""
+    if "family" not in spec:
+        raise BenchFailure(
+            "the generator states no `family` (a file of "
+            f"{os.path.join(HERE, 'families')})")
+    return module_by_name("families", spec["family"], "family")
+
+
 def generate(spec, seed):
-    """The instance ``pydcop generate graph_coloring`` builds, as a
-    DCOP object (the function behind the command).  Where the
-    configuration fixes ``constraints``, the random graph's edges are
-    cut or filled to exactly that many, so that every seed has the
-    same shapes and finds the same compiled program."""
-    from pydcop_tpu.generators.graphcoloring import (
-        generate_graph_coloring,
-    )
-
-    dcop = generate_graph_coloring(
-        spec["variables"], spec["colors"], spec["graph"],
-        soft=spec.get("soft", False), p_edge=spec.get("p_edge"),
-        allow_subgraph=True, noagents=True, seed=seed)
-    if "constraints" in spec:
-        fix_constraint_count(dcop, spec["constraints"], seed)
-    return dcop
+    """The instance of the spec's family for this seed, as a DCOP
+    object, from the program's own generator."""
+    return family_of(spec).generate(spec, seed)
 
 
-def fix_constraint_count(dcop, count, seed):
-    """Drop seeded-random constraints, or add copies of the (one)
-    hard table between seeded-random pairs that share none yet."""
-    from pydcop_tpu.dcop.relations import NAryMatrixRelation
-
-    rng = np.random.default_rng(seed)
-    names = list(dcop.constraints)
-    surplus = len(names) - count
-    if surplus > 0:
-        for i in rng.choice(len(names), surplus, replace=False):
-            del dcop.constraints[names[i]]
-        return
-    table = np.asarray(dcop.constraints[names[0]].matrix)
-    if any(not np.array_equal(c.matrix, table)
-           for c in dcop.constraints.values()):
-        raise BenchFailure("constraints can be added only where all "
-                           "share one table (not to a soft instance)")
-    variables = list(dcop.variables.values())
-    taken = {frozenset(v.name for v in c.dimensions)
-             for c in dcop.constraints.values()}
-    while len(dcop.constraints) < count:
-        i, j = sorted(rng.choice(len(variables), 2, replace=False))
-        pair = frozenset((variables[i].name, variables[j].name))
-        if pair not in taken:
-            taken.add(pair)
-            dcop.add_constraint(NAryMatrixRelation(
-                [variables[i], variables[j]], table.copy(),
-                f"c{len(dcop.constraints)}x"))
+def shapes(dcop):
+    """The problem's own shapes, for ``chipbench/roofline.py`` and to
+    hold an instance to what its family says every seed has."""
+    by_arity = {}
+    for c in dcop.constraints.values():
+        by_arity[len(c.dimensions)] = by_arity.get(len(c.dimensions), 0) + 1
+    first = next(iter(dcop.variables.values()))
+    return {"variables": len(dcop.variables),
+            "domain": len(first.domain.values),
+            "factors_by_arity": by_arity}
 
 
 def pydcop(*args):
@@ -85,27 +77,55 @@ def pydcop(*args):
         raise BenchFailure(f"pydcop {' '.join(args)} exited {rc}")
 
 
-def answer_fault(dcop, assignment, cost, violations, reference_cost,
-                 tolerance):
-    """Why an answer is wrong, or None: it covers every variable, its
-    reported cost and violations equal ``dcop.solution_cost`` on the
-    host exactly, and the cost is no worse than the reference's by
-    more than ``tolerance`` (relative, one-sided)."""
-    if set(assignment) != set(dcop.variables):
+def check_answer(dcop, answer, reference_cost, tolerance, ends=None):
+    """``(fault, compared)`` of one answer (``assignment``, ``cost``,
+    ``violations`` and, where ``ends`` is stated, ``status`` and
+    ``cycles``).  ``fault`` says why it is wrong, or is None: it
+    covers every variable, its reported cost and violations equal
+    ``dcop.solution_cost`` on the host exactly, the cost is no worse
+    than the reference's by more than ``tolerance`` (relative,
+    one-sided), and the solve ended as the configuration's ``ends``
+    states.  ``compared`` holds each number that was compared beside
+    its limit, ``{name: [value, limit]}``."""
+    assignment, cost = answer["assignment"], answer["cost"]
+    limit = reference_cost + tolerance * abs(reference_cost)
+    compared = {
+        "cost": [cost, limit],
+        "unassigned": [len(set(assignment) ^ set(dcop.variables)), 0]}
+    if ends is not None:
+        compared["cycles"] = [answer["cycles"], ends["cycles"]]
+    if compared["unassigned"][0]:
         return (f"assignment covers {len(assignment)}/"
-                f"{len(dcop.variables)} variables")
+                f"{len(dcop.variables)} variables"), compared
     host_cost, host_violations = dcop.solution_cost(assignment)
+    compared["cost_minus_host"] = [float(cost) - float(host_cost), 0]
+    compared["violations_minus_host"] = [
+        int(answer["violations"]) - int(host_violations), 0]
+    fault = None
     if not np.isfinite(host_cost):
-        return f"host cost {host_cost}"
-    if float(cost) != float(host_cost):
-        return f"reported cost {cost} != host cost {host_cost}"
-    if int(violations) != int(host_violations):
-        return (f"reported violations {violations} != host "
-                f"{host_violations}")
-    if host_cost > reference_cost + tolerance * abs(reference_cost):
-        return (f"cost {host_cost} is more than {tolerance:.0%} worse "
-                f"than the reference's {reference_cost}")
-    return None
+        fault = f"host cost {host_cost}"
+    elif compared["cost_minus_host"][0]:
+        fault = f"reported cost {cost} != host cost {host_cost}"
+    elif compared["violations_minus_host"][0]:
+        fault = (f"reported violations {answer['violations']} != host "
+                 f"{host_violations}")
+    elif host_cost > limit:
+        fault = (f"cost {host_cost} is more than {tolerance:.0%} worse "
+                 f"than the reference's {reference_cost}")
+    elif ends is not None and (answer["status"], answer["cycles"]) != (
+            ends["status"], ends["cycles"]):
+        fault = (f"ended {answer['status']} at cycle {answer['cycles']}; "
+                 f"the configuration states {ends['status']} at "
+                 f"{ends['cycles']}")
+    return fault, compared
+
+
+def worst(checked):
+    """Of ``(fault, compared)`` pairs, the ``compared`` of the worst
+    answer: one at fault before a sound one, then the cost that lies
+    highest against its limit."""
+    return max(checked, key=lambda c: (
+        c[0] is not None, c[1]["cost"][0] - c[1]["cost"][1]))[1]
 
 
 def post_solve(url, body):

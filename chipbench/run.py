@@ -6,8 +6,11 @@
 One process per run: loads, warms up (set-up), measures for
 ``--seconds``, checks every answer, and prints as its last line one
 JSON object (``correct``, ``attempted``, ``failed``, ``metrics``,
-``device`` and, traced, ``breakdown``).  ``--trace 0`` reports the
-cell's end-to-end metrics, ``--trace 1`` its per-layer metrics.
+``device``, traced ``breakdown``, and last ``compared``: each number
+that ``correct`` rests on, of the run's worst answer, beside its
+limit; the same as the last lines of standard error).  ``--trace 0``
+reports the cell's end-to-end metrics, ``--trace 1`` its per-layer
+metrics.
 
 The cell is looked up by name in BENCHMARK.json; its configuration
 file names a ``kind``, and ``runners/<kind>.py`` runs it.  In a traced
@@ -23,7 +26,6 @@ T0 = time.perf_counter()
 
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
-import importlib  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
@@ -35,7 +37,7 @@ ROOT = os.path.dirname(HERE)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from chipbench.lib import BenchFailure, note  # noqa: E402
+from chipbench.lib import BenchFailure, module_by_name, note  # noqa: E402
 
 # What every run must have run on.  (The CPU rehearsal,
 # tests/chipbench_rehearsal, patches it from the test.)
@@ -69,13 +71,6 @@ def load_json(path, what):
         raise BenchFailure(f"{what}: no file {path}")
     with open(path, encoding="utf-8") as f:
         return json.load(f)
-
-
-def module_by_name(package, name, what):
-    path = os.path.join(HERE, package, f"{name}.py")
-    if not os.path.isfile(path):
-        raise BenchFailure(f"{what} {name!r}: no file {path}")
-    return importlib.import_module(f"chipbench.{package}.{name}")
 
 
 def resolve(bench_path, workload):
@@ -233,6 +228,9 @@ def run(args, started):
     line["metrics"] = {name: {"value": value, "unit": unit}
                        for name, (value, unit) in metrics.items()}
     device["memory_peak_bytes"] = memory_peak_bytes()
+    # Last in the line: each number `correct` rests on, of the run's
+    # worst answer, beside its limit.
+    line["compared"] = result["compared"]
     return line
 
 
@@ -255,6 +253,9 @@ def main(argv=None, started=None):
         print(f"chipbench: FAILED: {type(exc).__name__}: {exc}",
               file=sys.stderr)
         return 1
+    for name, (value, limit) in line["compared"].items():
+        print(f"chipbench: compared {name}: {value!r} (limit {limit!r})",
+              file=sys.stderr)
     print(json.dumps(line), flush=True)
     return 0
 

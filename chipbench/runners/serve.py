@@ -99,25 +99,32 @@ def settle(url, counters):
 
 
 def faults_of(records, dcops, reference_costs, tolerance):
-    """Why answers are wrong: not FINISHED, wrong on the host, worse
-    than the reference allows, or a repeated problem answered with
-    another assignment."""
-    faults, first = [], {}
+    """``(faults, compared)``: why answers are wrong (not FINISHED,
+    wrong on the host, worse than the reference allows, or a repeated
+    problem answered with another assignment), and the numbers
+    compared of the worst answer, beside the counts of requests not
+    FINISHED and of repeats that differed."""
+    faults, checked, first = [], [], {}
+    unfinished = differing = 0
     for n, record in enumerate(records):
         answer, index = record["answer"], record["index"]
-        fault = None
         if answer.get("status") != "FINISHED":
+            unfinished += 1
             fault = f"status {answer.get('status')}"
         else:
-            fault = lib.answer_fault(
-                dcops[index], answer["assignment"], answer["cost"],
-                answer["violations"], reference_costs[index], tolerance)
+            fault, compared = lib.check_answer(
+                dcops[index], answer, reference_costs[index], tolerance)
             if (fault is None and answer["assignment"]
                     != first.setdefault(index, answer["assignment"])):
+                differing += 1
                 fault = "a repeated problem got another assignment"
+            checked.append((fault, compared))
         if fault:
             faults.append(f"request {n} (problem {index}): {fault}")
-    return faults
+    compared = dict(lib.worst(checked)) if checked else {}
+    compared["not_finished"] = [unfinished, 0]
+    compared["repeats_differing"] = [differing, 0]
+    return faults, compared
 
 
 def run(cell):
@@ -205,8 +212,8 @@ def run(cell):
             f"/stats labels the backend {stats['efficiency']['backend']!r}")
 
     # ---- the checks, after the window --------------------------------
-    faults = faults_of(warm + records, dcops, reference_costs,
-                       config["cost_tolerance"])
+    faults, compared = faults_of(warm + records, dcops, reference_costs,
+                                 config["cost_tolerance"])
     for fault in faults[:5]:
         lib.note(fault=fault)
     lib.note(service={key: stats[key] for key in (
@@ -218,4 +225,4 @@ def run(cell):
             for r in records if r["answer"].get("status") == "FINISHED")
     return {"correct": not faults, "attempted": len(warm) + len(records),
             "failed": len(faults), "end_to_end": end_to_end,
-            "capture": capture}
+            "capture": capture, "compared": compared}

@@ -2,10 +2,14 @@
 again through ``api.solve``, after one timed ``pydcop solve`` of the
 same problem from its YAML file.
 
-Configuration keys: ``generator`` (the arguments of ``pydcop generate
-graph_coloring``), ``algo``, ``max_cycles``, ``cli_solve`` (whether
-set-up makes the timed CLI solve), ``cost_tolerance``.  Traffic keys:
-``warmup_solves``, ``traced_solves``.
+Configuration keys: ``generator`` (its ``family`` names a module of
+``chipbench/families/``; the rest are that family's arguments),
+``algo``, ``algo_params`` (a dict, given to ``api.solve`` and as one
+``-p name:value`` each to ``pydcop solve``), ``max_cycles``, ``ends``
+(``{"status", "cycles"}``: how every solve has to end, or null),
+``cli_solve`` (whether set-up makes the timed CLI solve),
+``cost_tolerance``.  Traffic keys: ``warmup_solves``,
+``traced_solves``.
 """
 
 import json
@@ -16,26 +20,17 @@ import time
 from chipbench import lib, reference
 
 
-def shapes(dcop):
-    """The problem's own shapes, for ``chipbench/roofline.py``."""
-    by_arity = {}
-    for c in dcop.constraints.values():
-        by_arity[len(c.dimensions)] = by_arity.get(len(c.dimensions), 0) + 1
-    first = next(iter(dcop.variables.values()))
-    return {"variables": len(dcop.variables),
-            "domain": len(first.domain.values),
-            "factors_by_arity": by_arity}
-
-
 def cli_solve(cell, path):
     """One ``pydcop solve`` of the YAML file, in this process, timed
     from the command to its result file: what the CLI's user waits
     for once the compiled program is in the cache."""
     result_path = os.path.join(cell.workdir, "result.json")
     t0 = time.perf_counter()
+    params = [arg for name, value in cell.config["algo_params"].items()
+              for arg in ("-p", f"{name}:{value}")]
     lib.pydcop("--output", result_path, "solve", "-a",
-               cell.config["algo"], "-c", str(cell.config["max_cycles"]),
-               path)
+               cell.config["algo"], *params, "-c",
+               str(cell.config["max_cycles"]), path)
     wall = time.perf_counter() - t0
     with open(result_path, encoding="utf-8") as f:
         result = json.load(f)
@@ -44,7 +39,8 @@ def cli_solve(cell, path):
             f"pydcop solve ran on {result['platform']}")
     return wall, {"assignment": result["assignment"],
                   "cost": result["cost"],
-                  "violations": result["violation"]}
+                  "violations": result["violation"],
+                  "status": result["status"], "cycles": result["cycle"]}
 
 
 def run(cell):
@@ -57,13 +53,19 @@ def run(cell):
 
     def solve():
         t0 = time.perf_counter()
-        res = api.solve(dcop, algo, max_cycles=cycles)
+        res = api.solve(dcop, algo, max_cycles=cycles,
+                        algo_params=config["algo_params"])
         return time.perf_counter() - t0, res
 
     # ---- set-up ------------------------------------------------------
     t0 = time.perf_counter()
     dcop = lib.generate(config["generator"], cell.seed)
     generate_s = time.perf_counter() - t0
+    stated = lib.family_of(config["generator"]).shapes(config["generator"])
+    if lib.shapes(dcop) != stated:
+        raise lib.BenchFailure(
+            f"seed {cell.seed} gave the shapes {lib.shapes(dcop)}; the "
+            f"configuration's family states {stated}")
     # The first solve compiles, or loads the program from the disk
     # cache; the rest of the run finds it in the process.
     warm = [solve()[0] for _ in range(traffic["warmup_solves"])]
@@ -91,8 +93,8 @@ def run(cell):
         wall, res = solve()
         walls.append(wall)
         answers.append((f"api.solve #{len(walls)}", {
-            "assignment": res["assignment"], "cost": res["cost"],
-            "violations": res["violations"]}))
+            key: res[key] for key in ("assignment", "cost", "violations",
+                                      "status", "cycles")}))
     end_to_end["solve_p50_s"] = statistics.median(walls)
     counters = aotcache.counters()
     lib.note(window={"solves": len(walls), "min_s": min(walls),
@@ -107,7 +109,7 @@ def run(cell):
     capture = None
     if cell.trace:
         capture = {"counters_before": counters_before,
-                   "shapes": shapes(dcop), "values": dict(end_to_end)}
+                   "shapes": stated, "values": dict(end_to_end)}
         traced_cycles = 0
         with lib.traced_block(cell, capture):
             for _ in range(traffic["traced_solves"]):
@@ -120,14 +122,11 @@ def run(cell):
             capture["values"]["yaml_load_s"] = time.perf_counter() - t0
 
     # ---- the checks, after the window --------------------------------
-    faults = []
-    for what, answer in answers:
-        fault = lib.answer_fault(
-            dcop, answer["assignment"], answer["cost"],
-            answer["violations"], reference_cost,
-            config["cost_tolerance"])
-        if fault:
-            faults.append(f"{what}: {fault}")
+    checked = [lib.check_answer(dcop, answer, reference_cost,
+                                config["cost_tolerance"], config["ends"])
+               for _, answer in answers]
+    faults = [f"{what}: {fault}" for (what, _), (fault, _)
+              in zip(answers, checked) if fault]
     for fault in faults[:5]:
         lib.note(fault=fault)
     if capture is not None:
@@ -135,4 +134,4 @@ def run(cell):
             a["cost"] / reference_cost for _, a in answers)
     return {"correct": not faults, "attempted": len(answers),
             "failed": len(faults), "end_to_end": end_to_end,
-            "capture": capture}
+            "capture": capture, "compared": lib.worst(checked)}

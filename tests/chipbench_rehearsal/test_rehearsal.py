@@ -23,7 +23,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 CHIPBENCH = os.path.join(REPO, "chipbench")
 
-LAST_LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+LAST_LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+                  "compared"}
 DEVICE_DERIVED = {"kernel.superstep_us", "maxsum_superstep_roofline",
                   "device.idle.solve", "device.idle.serve"}
 END_TO_END = {
@@ -33,14 +34,16 @@ END_TO_END = {
 TINY_CONFIGS = {
     "tiny_solve": {
         "name": "tiny_solve", "kind": "solve",
-        "generator": {"variables": 30, "colors": 3, "graph": "random",
-                      "p_edge": 0.3, "constraints": 130},
-        "algo": "maxsum", "max_cycles": 30, "cli_solve": True,
+        "generator": {"family": "graph_coloring", "variables": 30,
+                      "colors": 3, "graph": "random", "p_edge": 0.3,
+                      "constraints": 130},
+        "algo": "maxsum", "algo_params": {}, "max_cycles": 30,
+        "ends": {"status": "TIMEOUT", "cycles": 30}, "cli_solve": True,
         "cost_tolerance": 3.0},
     "tiny_serve": {
         "name": "tiny_serve", "kind": "serve",
-        "generator": {"variables": 16, "colors": 3, "graph": "grid",
-                      "soft": True},
+        "generator": {"family": "graph_coloring", "variables": 16,
+                      "colors": 3, "graph": "grid", "soft": True},
         "pool": 3, "params": {"max_cycles": 30},
         "service": {"batch_window_s": 0.005, "max_batch": 4,
                     "max_queue": 64},
@@ -122,10 +125,38 @@ def harness(monkeypatch, tmp_path):
         aotcache._state.update(state)
 
 
-def last_line(capsys):
-    lines = capsys.readouterr().out.strip().splitlines()
-    return json.loads(lines[-1]), [json.loads(x) for x in lines[:-1]
-                                   if x.startswith("{")]
+def last_line(capsys, compared_of=None):
+    """The result line and the notes before it.  Where ``compared_of``
+    names the kind of cell that ran sound, the line has to end with
+    the numbers compared beside their limits, and standard error too."""
+    captured = capsys.readouterr()
+    lines = captured.out.strip().splitlines()
+    line = json.loads(lines[-1])
+    if compared_of is not None:
+        compared_is_last_and_sound(line, captured.err, compared_of)
+    return line, [json.loads(x) for x in lines[:-1] if x.startswith("{")]
+
+
+def compared_is_last_and_sound(line, err, kind):
+    assert list(line)[-1] == "compared"
+    compared = line["compared"]
+    names = {"cost", "cost_minus_host", "violations_minus_host",
+             "unassigned"}
+    names |= ({"cycles"} if kind == "solve"
+              else {"not_finished", "repeats_differing"})
+    assert set(compared) == names
+    for name, (value, limit) in compared.items():
+        assert value <= limit, name
+    cost, limit = compared["cost"]
+    assert 0 < cost <= limit
+    if kind == "solve":
+        ends = TINY_CONFIGS["tiny_solve"]["ends"]
+        assert compared["cycles"] == [ends["cycles"], ends["cycles"]]
+    # The same, as the last lines of standard error.
+    said = [x for x in err.splitlines()
+            if x.startswith("chipbench: compared ")]
+    assert said == err.splitlines()[-len(compared):]
+    assert [x.split()[2].rstrip(":") for x in said] == list(compared)
 
 
 def run_cell(run, bench, kind, trace, seed=3000000001):
@@ -142,7 +173,7 @@ def run_cell(run, bench, kind, trace, seed=3000000001):
 def test_untraced_run_prints_the_end_to_end_metrics(
         harness, bench, capsys, kind):
     assert run_cell(harness, bench, kind, 0) == 0
-    line, _ = last_line(capsys)
+    line, _ = last_line(capsys, compared_of=kind)
     assert set(line) == LAST_LINE_KEYS
     assert line["correct"] is True
     assert line["attempted"] > 0 and line["failed"] == 0
@@ -165,7 +196,7 @@ def test_untraced_run_prints_the_end_to_end_metrics(
 def test_traced_run_prints_the_per_layer_metrics_a_cpu_can_give(
         harness, bench, capsys, kind, expected):
     assert run_cell(harness, bench, kind, 1) == 0
-    line, _ = last_line(capsys)
+    line, _ = last_line(capsys, compared_of=kind)
     assert set(line) == LAST_LINE_KEYS | {"breakdown"}
     assert line["correct"] is True
     # No number from a CPU run under a device metric's name.
@@ -203,6 +234,10 @@ def test_a_wrong_cost_makes_correct_false(harness, bench, capsys,
     assert line["correct"] is False
     assert line["failed"] >= 1
     assert any("reported cost" in n.get("fault", "") for n in notes)
+    # The planted fault shows in the numbers compared, of the worst
+    # answer, beside their limits.
+    assert line["compared"]["cost_minus_host"] == [1.0, 0]
+    assert line["compared"]["violations_minus_host"] == [0, 0]
 
 
 def test_no_chip_no_result(bench, capsys):
@@ -405,62 +440,120 @@ def test_spread_is_the_quartile_distance_over_the_median():
 # the reference, the instance and the checks
 
 
-def test_reference_finds_the_optimum_of_a_tree():
-    from chipbench import reference
+def _random_problem(rng, scopes, size):
+    """A DCOP of ``size`` values a variable and one factor of random
+    costs over each scope."""
     from pydcop_tpu.dcop.dcop import DCOP
     from pydcop_tpu.dcop.objects import Domain, Variable
     from pydcop_tpu.dcop.relations import NAryMatrixRelation
 
-    rng = np.random.default_rng(5)
-    domain = Domain("d", "d", ["a", "b", "c"])
-    variables = [Variable(f"v{i}", domain) for i in range(6)]
-    dcop = DCOP("tree", objective="min")
-    for i, (a, b) in enumerate([(0, 1), (0, 2), (1, 3), (1, 4), (2, 5)]):
+    domain = Domain("d", "d", [f"x{i}" for i in range(size)])
+    variables = [Variable(f"v{i}", domain)
+                 for i in range(1 + max(max(scope) for scope in scopes))]
+    dcop = DCOP("random", objective="min")
+    for i, scope in enumerate(scopes):
         dcop.add_constraint(NAryMatrixRelation(
-            [variables[a], variables[b]], rng.random((3, 3)) * 10,
-            f"c{i}"))
+            [variables[j] for j in scope],
+            rng.random((size,) * len(scope)) * 10, f"c{i}"))
+    return dcop
+
+
+def _brute_force(dcop):
+    names = list(dcop.variables)
+    return min(
+        dcop.solution_cost(dict(zip(names, values)))[0]
+        for values in itertools.product(
+            *(dcop.variables[n].domain.values for n in names)))
+
+
+def test_reference_finds_the_optimum_of_a_tree():
+    from chipbench import reference
+
+    dcop = _random_problem(np.random.default_rng(5), [
+        (0, 1), (0, 2), (1, 3), (1, 4), (2, 5)], 3)
     assignment, cost = reference.solve(dcop, cycles=30, seed=1)
-    best = min(
-        dcop.solution_cost(dict(zip((v.name for v in variables), values)))
-        [0] for values in itertools.product(domain.values, repeat=6))
-    assert cost == pytest.approx(best)
+    assert cost == pytest.approx(_brute_force(dcop))
     assert dcop.solution_cost(assignment)[0] == pytest.approx(cost)
 
 
-@pytest.mark.parametrize("count", [30, 60])
-def test_every_seed_gives_the_same_number_of_constraints(count):
+FAMILY_SPECS = {
+    "graph_coloring-cut": {
+        "family": "graph_coloring", "variables": 40, "colors": 3,
+        "graph": "random", "p_edge": 0.06, "constraints": 30},
+    "graph_coloring-filled": {
+        "family": "graph_coloring", "variables": 40, "colors": 3,
+        "graph": "random", "p_edge": 0.06, "constraints": 60},
+    "graph_coloring-grid": {
+        "family": "graph_coloring", "variables": 16, "colors": 3,
+        "graph": "grid", "soft": True},
+    "secp": {
+        "family": "secp", "lights": 56, "models": 17, "rules": 28,
+        "max_model_size": 3, "max_rule_size": 3,
+        "factors_by_arity": {"1": 60, "2": 6, "3": 11, "4": 4}},
+}
+
+
+def test_a_family_that_is_no_file_fails_naming_the_file_looked_for():
     from chipbench import lib
 
-    spec = {"variables": 40, "colors": 3, "graph": "random",
-            "p_edge": 0.06, "constraints": count}
-    for seed in (1, 2, 3000000001):
-        dcop = lib.generate(spec, seed)
-        pairs = {frozenset(v.name for v in c.dimensions)
-                 for c in dcop.constraints.values()}
-        assert len(dcop.constraints) == len(pairs) == count
-        again = lib.generate(spec, seed)
-        assert list(again.constraints) == list(dcop.constraints)
+    with pytest.raises(lib.BenchFailure) as failure:
+        lib.generate({"family": "nofamily"}, 1)
+    assert os.path.join(CHIPBENCH, "families", "nofamily.py") in str(
+        failure.value)
+    # There is no default family.
+    with pytest.raises(lib.BenchFailure, match="states no `family`"):
+        lib.generate({"variables": 9, "colors": 3, "graph": "grid"}, 1)
 
 
-def test_answer_fault_names_each_way_an_answer_can_be_wrong():
+def test_check_answer_names_each_way_an_answer_can_be_wrong():
     from chipbench import lib
 
-    dcop = lib.generate({"variables": 9, "colors": 3, "graph": "grid",
-                         "soft": True}, 1)
+    dcop = lib.generate(FAMILY_SPECS["graph_coloring-grid"], 1)
     assignment = {name: "R" for name in dcop.variables}
     cost, violations = dcop.solution_cost(assignment)
-    ok = (dcop, assignment, cost, violations)
-    assert lib.answer_fault(*ok, cost, 0.0) is None
-    assert "covers 8/9" in lib.answer_fault(
-        dcop, dict(list(assignment.items())[:-1]), cost, violations,
-        cost, 0.0)
-    assert "reported cost" in lib.answer_fault(
-        dcop, assignment, cost + 1, violations, cost, 0.0)
-    assert "reported violations" in lib.answer_fault(
-        dcop, assignment, cost, violations + 1, cost, 0.0)
-    assert "worse than the reference" in lib.answer_fault(
-        *ok, cost / 2, 0.5)
-    assert lib.answer_fault(*ok, cost / 2, 1.0) is None
+    answer = {"assignment": assignment, "cost": cost,
+              "violations": violations, "status": "TIMEOUT", "cycles": 30}
+
+    def fault(reference_cost=cost, tolerance=0.0, ends=None, **altered):
+        return lib.check_answer(dcop, dict(answer, **altered),
+                                reference_cost, tolerance, ends)
+
+    assert fault() == (None, {
+        "cost": [cost, cost], "unassigned": [0, 0],
+        "cost_minus_host": [0.0, 0], "violations_minus_host": [0, 0]})
+    said, compared = fault(
+        assignment=dict(list(assignment.items())[:-1]))
+    assert "covers 15/16" in said and compared["unassigned"] == [1, 0]
+    said, compared = fault(cost=cost + 1)
+    assert "reported cost" in said
+    assert compared["cost_minus_host"] == [1.0, 0]
+    said, compared = fault(violations=violations + 1)
+    assert "reported violations" in said
+    assert compared["violations_minus_host"] == [1, 0]
+    said, compared = fault(cost / 2, 0.5)
+    assert "worse than the reference" in said
+    assert compared["cost"] == [cost, 0.75 * cost]
+    assert fault(cost / 2, 1.0)[0] is None
+    ends = {"status": "TIMEOUT", "cycles": 30}
+    assert fault(ends=ends) == (None, dict(fault()[1], cycles=[30, 30]))
+    said, compared = fault(ends=ends, status="FINISHED", cycles=17)
+    assert said == ("ended FINISHED at cycle 17; the configuration "
+                    "states TIMEOUT at 30")
+    assert compared["cycles"] == [17, 30]
+    assert "ended TIMEOUT at cycle 29" in fault(ends=ends, cycles=29)[0]
+
+
+def test_the_worst_answer_is_one_at_fault_then_the_costliest():
+    from chipbench import lib
+
+    def compared(cost):
+        return {"cost": [cost, 10.0]}
+
+    checked = [(None, compared(4.0)), (None, compared(9.0)),
+               (None, compared(2.0))]
+    assert lib.worst(checked) == compared(9.0)
+    assert lib.worst(checked + [("reported cost", compared(1.0))]) == (
+        compared(1.0))
 
 
 # --------------------------------------------------------------------- #

@@ -8,9 +8,10 @@ solve`` in set-up, as ``gc10k_maxsum`` does.  A configuration with
 metric.  The first half drives such a cell, sound and broken, through
 ``run.main``; the second half holds ``BENCHMARK.json``'s lists and
 every file in ``chipbench/configs/`` to what the README says they
-are.  No test here names a real cell, a real configuration or a count
-of either: each expectation is read from the data, so a later PR's
-cell or metric is held to the same rules without an edit here.
+are.  No test here names a real cell, a real
+configuration or a count of either: each expectation is read from the
+data, so a later PR's cell, metric, configuration or family is held to
+the same rules without an edit here.
 """
 
 import glob
@@ -33,8 +34,8 @@ from test_rehearsal import (  # noqa: F401 - harness is a fixture
 CELL = "tiny_solve_nocli.resolve"
 NOCLI = dict(
     TINY_CONFIGS["tiny_solve"], name="tiny_solve_nocli",
-    generator={"variables": 60, "colors": 3, "graph": "random",
-               "p_edge": 0.05, "constraints": 90},
+    generator={"family": "graph_coloring", "variables": 60, "colors": 3,
+               "graph": "random", "p_edge": 0.05, "constraints": 90},
     cli_solve=False)
 CONFIG_FILES = sorted(
     os.path.basename(p)
@@ -43,7 +44,8 @@ CONFIG_FILES = sorted(
 README_KEYS = {"name", "kind", "source", "reduced", "assumed",
                "guarantees", "cost_tolerance", "why"}
 RUNNER_KEYS = {
-    "solve": {"generator", "algo", "max_cycles", "cli_solve"},
+    "solve": {"generator", "algo", "algo_params", "max_cycles", "ends",
+              "cli_solve"},
     "serve": {"generator", "pool", "params", "service"},
 }
 
@@ -73,22 +75,22 @@ def cells_the_rule_gives(bench, moves, kinds):
             and w["name"] in moved.get("workloads", [w["name"]])]
 
 
-def _write_bench(tmp_path, cli_solve_cells):
+def _write_bench(tmp_path, cli_solve_cells, configs=None):
     """A BENCHMARK.json of the tiny solve cell with a CLI solve and
-    the one without, beside a copy of ``chipbench/metrics``; the real
-    file's per-layer lists, with both cells in each solve metric but
-    those that move ``cli_solve_s``, which only the cell that makes
-    the CLI solve is in."""
+    the one without (or of ``configs``), beside a copy of
+    ``chipbench/metrics``; the real file's per-layer lists, with every
+    cell in each solve metric but those that move ``cli_solve_s``,
+    which only the cells that make the CLI solve are in."""
     data = tmp_path / "data"
     shutil.copytree(os.path.join(CHIPBENCH, "metrics"), data / "metrics")
-    configs = {"tiny_solve": TINY_CONFIGS["tiny_solve"],
-               "tiny_solve_nocli": NOCLI}
+    configs = configs or {"tiny_solve": TINY_CONFIGS["tiny_solve"],
+                          "tiny_solve_nocli": NOCLI}
     for name, config in configs.items():
         write_json(str(data / "configs" / f"{name}.json"), config)
     write_json(str(data / "traffic" / "resolve.json"),
                TINY_TRAFFIC["resolve"])
     real = _benchmark()
-    both = ["tiny_solve.resolve", CELL]
+    both = [f"{n}.resolve" for n in configs]
     bench_path = tmp_path / "BENCHMARK.json"
     write_json(str(bench_path), {
         "configs": [{"name": n, "file": f"data/configs/{n}.json"}
@@ -103,7 +105,7 @@ def _write_bench(tmp_path, cli_solve_cells):
             {"name": "solve_p50_s", "unit": "s", "workloads": both}],
         "per_layer": [
             {"name": m["name"],
-             "workloads": (["tiny_solve.resolve"]
+             "workloads": (cli_solve_cells
                            if m["moves"] == "cli_solve_s" else both)}
             for m in real["per_layer"] if m["moves"] in (
                 "cli_solve_s", "solve_p50_s")],
@@ -347,22 +349,17 @@ def test_no_two_configurations_share_a_source():
 @pytest.mark.parametrize("filename", [
     f for f in CONFIG_FILES if _config(f)["kind"] == "solve"])
 def test_a_solve_configuration_fixes_the_count_its_density_gives(filename):
-    """``constraints`` is the mean of what ``-p`` gives, p * V * (V -
-    1) / 2, to the nearest whole number, and the runner reads the
-    roofline's shapes from the instance as the file states them (tried
-    on the same generator at a size a test can hold)."""
+    """The counts the file fixes follow from the parameters its
+    ``source`` names, by its family's own rule, and every seed's
+    instance has the shapes the family states (tried on the same
+    family at a size a test can hold)."""
     from chipbench import lib
-    from chipbench.runners import solve
 
     generator = _config(filename)["generator"]
-    variables = generator["variables"]
-    mean = generator["p_edge"] * variables * (variables - 1) / 2
-    assert abs(mean - generator["constraints"]) < 2
-    scale = variables // 1000
-    small = dict(generator, variables=1000,
-                 p_edge=generator["p_edge"] * scale,
-                 constraints=generator["constraints"] // scale)
-    dcop = lib.generate(small, 4100000007)
-    assert solve.shapes(dcop) == {
-        "variables": 1000, "domain": generator["colors"],
-        "factors_by_arity": {2: small["constraints"]}}
+    family = lib.family_of(generator)
+    family.check(generator)
+    small = family.small(generator)
+    assert sum(family.shapes(small)["factors_by_arity"].values()) <= 1500
+    assert small["family"] == generator["family"]
+    family.check(small)
+    assert lib.shapes(lib.generate(small, 4100000007)) == family.shapes(small)

@@ -400,8 +400,8 @@ def test_a_real_profile_holds_the_scopes_and_the_annotations(tmp_path):
 
     from chipbench import lib
 
-    dcop = lib.generate({"variables": 16, "colors": 3, "graph": "grid",
-                         "soft": True}, 1)
+    dcop = lib.generate({"family": "graph_coloring", "variables": 16,
+                         "colors": 3, "graph": "grid", "soft": True}, 1)
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     options.host_tracer_level = 1
