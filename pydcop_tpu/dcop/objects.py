@@ -16,7 +16,9 @@ makes cost parity between runs impossible; we fix that deliberately).
 
 import hashlib
 import itertools
-from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple, Union
+from typing import (
+    Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple, Union,
+)
 
 import numpy as np
 
@@ -266,6 +268,119 @@ def _stable_noise(name: str, n: int, noise_level: float,
     h = hashlib.sha256(f"{name}:{seed}".encode()).digest()
     rng = np.random.default_rng(int.from_bytes(h[:8], "little"))
     return rng.random(n) * noise_level
+
+
+_M32 = 0xFFFFFFFF
+_U32 = np.uint64(32)
+_LOW32 = np.uint64(_M32)
+# PCG64's 128-bit multiplier 0x2360ED051FC65DA44385DF649FCCF645: its
+# 64-bit halves, and the low half's 32-bit limbs.
+_PCG_MULT_HI = np.uint64(0x2360ED051FC65DA4)
+_PCG_MULT_LO = np.uint64(0x4385DF649FCCF645)
+_PCG_MULT_LO1 = np.uint64(0x4385DF64)
+_PCG_MULT_LO0 = np.uint64(0x9FCCF645)
+
+
+def _seed_sequence_state(seeds: np.ndarray) -> List[np.ndarray]:
+    """``SeedSequence(s).generate_state(4, uint64)`` for every 64-bit
+    ``s`` of ``seeds`` at once, as four uint64 arrays
+    (numpy/random/bit_generator.pyx: ``mix_entropy`` then
+    ``generate_state``).  The hash constants do not depend on the data,
+    so they run as Python ints masked to 32 bits; the data are uint32
+    arrays, whose arithmetic wraps."""
+    hash_const = 0x43B0D7E5  # INIT_A
+
+    def hashmix(value):
+        nonlocal hash_const
+        value = value ^ hash_const
+        hash_const = (hash_const * 0x931E8875) & _M32  # MULT_A
+        value = value * hash_const
+        return value ^ (value >> 16)
+
+    def mix(x, y):
+        result = x * 0xCA01F9DD - y * 0x4973F715  # MIX_MULT_L, _R
+        return result ^ (result >> 16)
+
+    # A seed under 2**32 is one entropy word and the pool's other
+    # slots hash a zero: the same as its (zero) high word.
+    zero = np.zeros(seeds.shape, np.uint32)
+    entropy = [(seeds & _LOW32).astype(np.uint32),
+               (seeds >> _U32).astype(np.uint32), zero, zero]
+    pool = [hashmix(word) for word in entropy]
+    for i_src in range(4):
+        for i_dst in range(4):
+            if i_src != i_dst:
+                pool[i_dst] = mix(pool[i_dst], hashmix(pool[i_src]))
+    hash_const = 0x8B51F9DD  # INIT_B
+    words = []
+    for i in range(8):
+        value = pool[i % 4] ^ hash_const
+        hash_const = (hash_const * 0x58F38DED) & _M32  # MULT_B
+        value = value * hash_const
+        words.append((value ^ (value >> 16)).astype(np.uint64))
+    return [words[2 * i] | (words[2 * i + 1] << _U32) for i in range(4)]
+
+
+def _pcg_step(hi, lo, inc_hi, inc_lo):
+    """``state * multiplier + inc`` modulo 2**128 on (high, low) uint64
+    arrays: the low halves' full product from 32-bit limbs, the cross
+    terms and the carries into the high half."""
+    a0, a1 = lo & _LOW32, lo >> _U32
+    p00, p01, p10 = a0 * _PCG_MULT_LO0, a0 * _PCG_MULT_LO1, a1 * _PCG_MULT_LO0
+    mid = (p00 >> _U32) + (p01 & _LOW32) + (p10 & _LOW32)
+    new_lo = (p00 & _LOW32) | (mid << _U32)
+    new_hi = (a1 * _PCG_MULT_LO1 + (p01 >> _U32) + (p10 >> _U32)
+              + (mid >> _U32) + hi * _PCG_MULT_LO + lo * _PCG_MULT_HI)
+    new_lo = new_lo + inc_lo
+    new_hi = new_hi + inc_hi + (new_lo < inc_lo)
+    return new_hi, new_lo
+
+
+def _uniform_streams(seeds: np.ndarray, n: int) -> np.ndarray:
+    """``np.random.default_rng(s).random(n)`` for every 64-bit ``s`` of
+    the uint64 array ``seeds``, as one float64 ``[len(seeds), n]``
+    array, bit for bit: ``SeedSequence``'s pool, ``PCG64``'s seeding
+    (numpy/random/src/pcg64/pcg64.h ``pcg64_srandom_r``), and per draw
+    one step of the generator, its XSL-RR output and the 53-bit
+    double."""
+    s0, s1, s2, s3 = _seed_sequence_state(seeds)
+    # initstate = (s0, s1), initseq = (s2, s3); inc = initseq << 1 | 1.
+    one = np.uint64(1)
+    inc_hi = (s2 << one) | (s3 >> np.uint64(63))
+    inc_lo = (s3 << one) | one
+    # From state 0 one step gives inc; add initstate; step again.
+    lo = inc_lo + s1
+    hi = inc_hi + s0 + (lo < s1)
+    hi, lo = _pcg_step(hi, lo, inc_hi, inc_lo)
+    out = np.empty((len(seeds), n), dtype=np.float64)
+    for k in range(n):
+        hi, lo = _pcg_step(hi, lo, inc_hi, inc_lo)
+        x = hi ^ lo
+        rot = hi >> np.uint64(58)
+        x = (x >> rot) | (x << ((np.uint64(64) - rot) & np.uint64(63)))
+        out[:, k] = (x >> np.uint64(11)) * (1.0 / 9007199254740992.0)
+    return out
+
+
+def stable_noise_batch(names: Sequence[str], n: int, noise_level: float,
+                       seed: Optional[int]) -> np.ndarray:
+    """``_stable_noise(name, n, noise_level, seed)`` for every name, as
+    one float64 ``[len(names), n]`` array, bit for bit.
+
+    Building ``np.random.default_rng`` per name (a ``SeedSequence``, a
+    ``PCG64`` and a ``Generator`` for a handful of draws) was five
+    eighths of a 10 000-variable host compile (engine/compile.py), so
+    the per-name ``sha256`` seeds are kept and their streams computed
+    for all names at once.  A stream's first ``d`` draws are the first
+    ``d`` of ``n``, so callers with mixed domain sizes draw the largest
+    and mask.
+    """
+    digest = hashlib.sha256
+    seeds = np.frombuffer(
+        b"".join([digest(f"{name}:{seed}".encode()).digest()[:8]
+                  for name in names]),
+        dtype="<u8").astype(np.uint64)
+    return _uniform_streams(seeds, n) * noise_level
 
 
 class VariableNoisyCostFunc(VariableWithCostFunc):

@@ -60,7 +60,7 @@ from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 import numpy as np
 
 from pydcop_tpu.dcop.dcop import DCOP
-from pydcop_tpu.dcop.objects import Variable, _stable_noise
+from pydcop_tpu.dcop.objects import Variable, stable_noise_batch
 from pydcop_tpu.dcop.relations import Constraint, NAryFunctionRelation
 from pydcop_tpu.observability.metrics import registry as metrics_registry
 from pydcop_tpu.observability.trace import tracer
@@ -346,29 +346,30 @@ def build_aggregation_arrays(buckets: Sequence[FactorBucket],
     return None, None, None, None, ell
 
 
-def _factor_table(c: Constraint, sign: float, dtype,
-                  memo: Dict, vectorize: bool) -> np.ndarray:
-    """Sign-adjusted dense table for one factor, memoized on the
-    structural table signature: factors whose expressions differ only
-    in variable names (every generated-edge family) evaluate ONCE per
-    bucket instead of once per factor, and each evaluation is the
-    vectorized numpy path (relations.NAryFunctionRelation.to_array)
-    instead of a d^arity python loop.  ``vectorize=False`` restores
-    the per-factor per-assignment reference path — the A/B baseline
+def _factor_table(c: Constraint, memo: Dict,
+                  vectorize: bool) -> np.ndarray:
+    """Dense table of one factor as the constraint gives it (the
+    bucket casts and sign-adjusts its tables together), memoized on
+    the structural table signature: factors whose expressions differ
+    only in variable names (every generated-edge family) evaluate
+    ONCE per bucket instead of once per factor, and each evaluation
+    is the vectorized numpy path
+    (relations.NAryFunctionRelation.to_array) instead of a d^arity
+    python loop.  ``vectorize=False`` restores the per-factor
+    per-assignment reference path — the A/B baseline
     ``make perf-smoke`` measures against."""
     if not vectorize:
         if isinstance(c, NAryFunctionRelation):
             # The pre-vectorization behavior: the base per-assignment
             # enumeration loop.
-            return sign * np.asarray(
-                Constraint.to_array(c), dtype=dtype)
-        return sign * np.asarray(c.to_array(), dtype=dtype)
+            return np.asarray(Constraint.to_array(c))
+        return np.asarray(c.to_array())
     sig = c.table_signature()
     if sig is not None:
         table = memo.get(sig)
         if table is not None:
             return table
-    table = sign * np.asarray(c.to_array(), dtype=dtype)
+    table = np.asarray(c.to_array())
     if sig is not None:
         memo[sig] = table
     return table
@@ -412,58 +413,113 @@ def compile_factor_graph(
         )
 
 
+def _variable_tables(variables, names, domains, sign, noise_level,
+                     noise_seed, dtype):
+    """``var_costs`` / ``var_valid`` (with the sentinel row) and the
+    noise-free ``var_base``, filled over all variables at once: the
+    domain mask from the sizes, the base costs stacked, the
+    tie-breaking noise of every variable in one draw."""
+    v_count = len(variables)
+    sizes = np.fromiter(map(len, domains), dtype=np.int64, count=v_count)
+    dmax = int(sizes.max()) if v_count else 1
+    valid = np.arange(dmax) < sizes[:, None]
+    # A plain Variable costs nothing by definition: only classes that
+    # bring their own costs are asked for them.
+    base = np.zeros((v_count, dmax), dtype=np.float64)
+    costly = {
+        cls for cls in set(map(type, variables))
+        if cls.cost_for_val is not Variable.cost_for_val
+        or cls.cost_vector is not Variable.cost_vector
+    }
+    if costly:
+        for i, v in enumerate(variables):
+            if type(v) in costly:
+                d = sizes[i]
+                base[i, :d] = v.cost_vector()[:d]
+    costs = sign * base
+    var_base = np.where(valid, costs, 0.0).astype(dtype)
+    if noise_level:
+        costs = costs + stable_noise_batch(
+            names, dmax, noise_level, noise_seed)
+    var_costs = np.full((v_count + 1, dmax), BIG, dtype=dtype)
+    np.copyto(var_costs[:v_count], costs, where=valid, casting="unsafe")
+    var_valid = np.zeros((v_count + 1, dmax), dtype=bool)
+    var_valid[:v_count] = valid
+    return var_costs, var_valid, var_base
+
+
+def _bucket_costs(facs, n_rows, arity, dmax, sign, dtype, vectorize):
+    """One bucket's ``[n_rows, dmax, ...]`` cost tensor: BIG on domain
+    padding, zero on padding rows, and the factors' tables cast,
+    sign-adjusted and written with one stacked assignment per table
+    shape and dtype (a single one wherever every scope variable has
+    ``dmax`` values, as the tables of one source have one dtype)."""
+    costs = np.full((n_rows,) + (dmax,) * arity, BIG, dtype=dtype)
+    memo: Dict = {}
+    groups: Dict[Tuple, Tuple[List[int], List[np.ndarray]]] = {}
+    for fi, c in enumerate(facs):
+        table = _factor_table(c, memo, vectorize)
+        rows, tables = groups.setdefault(
+            (table.shape, table.dtype), ([], []))
+        rows.append(fi)
+        tables.append(table)
+    for (shape, _), (rows, tables) in groups.items():
+        where = slice(0, len(facs)) if len(groups) == 1 else rows
+        costs[(where,) + tuple(slice(0, s) for s in shape)] = (
+            sign * np.asarray(np.array(tables), dtype=dtype))
+    # Padding rows keep cost 0 and the sentinel variable.
+    costs[len(facs):] = 0.0
+    return costs
+
+
 def _compile_factor_graph(variables, constraints, mode, noise_level,
                           noise_seed, pad_to, dtype, aggregation,
                           vectorize, use_cache):
-    variables = list(variables)
-    constraints = list(constraints)
-    var_index = {v.name: i for i, v in enumerate(variables)}
-    for c in constraints:
-        for v in c.dimensions:
-            if v.name not in var_index:
-                raise ValueError(
-                    f"Constraint {c.name} references variable {v.name} "
-                    "which has no computation node — external (read-"
-                    "only) variables require the 'maxsum_dynamic' "
-                    "algorithm, which slices them out before compiling"
-                )
+    names = [v.name for v in variables]
+    var_index = {name: i for i, name in enumerate(names)}
     v_count = len(variables)
-    dmax = max((len(v.domain) for v in variables), default=1)
     sign = 1.0 if mode == "min" else -1.0
 
-    # Variable cost table (+ sentinel row for padding edges).
-    var_costs = np.full((v_count + 1, dmax), BIG, dtype=dtype)
-    var_valid = np.zeros((v_count + 1, dmax), dtype=bool)
-    var_base = np.zeros((v_count, dmax), dtype=dtype)
-    for i, v in enumerate(variables):
-        d = len(v.domain)
-        costs = sign * v.cost_vector()[:d]
-        var_base[i, :d] = costs
-        if noise_level:
-            costs = costs + _stable_noise(v.name, d, noise_level, noise_seed)
-        var_costs[i, :d] = costs
-        var_valid[i, :d] = True
-
+    # One walk over the constraints: membership of every scope
+    # variable, the zero-ary constant, and per arity the factors with
+    # their scope indices (flat, reshaped below).
     constant_cost = 0.0
     by_arity: Dict[int, List[Constraint]] = {}
+    flat_ids: Dict[int, List[int]] = {}
     for c in constraints:
-        if c.arity == 0:
+        dims = c.dimensions
+        if not dims:
             constant_cost += float(c())
             continue
-        by_arity.setdefault(c.arity, []).append(c)
+        try:
+            ids = [var_index[v.name] for v in dims]
+        except KeyError as missing:
+            raise ValueError(
+                f"Constraint {c.name} references variable "
+                f"{missing.args[0]} "
+                "which has no computation node — external (read-"
+                "only) variables require the 'maxsum_dynamic' "
+                "algorithm, which slices them out before compiling"
+            ) from None
+        by_arity.setdefault(len(dims), []).append(c)
+        flat_ids.setdefault(len(dims), []).extend(ids)
+
+    # Variable cost table (+ sentinel row for padding edges).
+    domains = [v.domain for v in variables]
+    var_costs, var_valid, var_base = _variable_tables(
+        variables, names, domains, sign, noise_level, noise_seed, dtype)
+    dmax = var_costs.shape[1]
 
     # Per-factor scope indices, one [n_facs, arity] array per arity.
     # Needed both for the bucket layout and as the structure-cache
     # key: the layout (padded var_ids + agg_* arrays) is a pure
     # function of these indices + (v_count, pad_to, aggregation).
     arities = sorted(by_arity)
-    scope_ids: Dict[int, np.ndarray] = {}
-    for arity in arities:
-        facs = by_arity[arity]
-        scope_ids[arity] = np.array(
-            [[var_index[v.name] for v in c.dimensions] for c in facs],
-            dtype=np.int32,
-        ).reshape(len(facs), arity)
+    scope_ids: Dict[int, np.ndarray] = {
+        arity: np.array(flat_ids[arity], dtype=np.int32).reshape(
+            len(by_arity[arity]), arity)
+        for arity in arities
+    }
 
     layout = None
     cache_key = None
@@ -511,18 +567,12 @@ def _compile_factor_graph(variables, constraints, mode, noise_level,
     bucket_sizes: List[int] = []
     for arity in arities:
         facs = by_arity[arity]
-        n_rows = var_ids_by_arity[arity].shape[0]
-        shape = (n_rows,) + (dmax,) * arity
-        costs = np.full(shape, BIG, dtype=dtype)
-        memo: Dict = {}
-        for fi, c in enumerate(facs):
-            factor_names.append(c.name)
-            table = _factor_table(c, sign, dtype, memo, vectorize)
-            idx = tuple(slice(0, s) for s in table.shape)
-            costs[(fi,) + idx] = table
-        # Padding rows keep cost 0 and the sentinel variable.
-        costs[len(facs):] = 0.0
-        buckets.append(FactorBucket(costs, var_ids_by_arity[arity]))
+        var_ids = var_ids_by_arity[arity]
+        factor_names.extend(c.name for c in facs)
+        buckets.append(FactorBucket(
+            _bucket_costs(facs, var_ids.shape[0], arity, dmax, sign,
+                          dtype, vectorize),
+            var_ids))
         bucket_sizes.append(len(facs))
     compiled = CompiledFactorGraph(
         var_costs=var_costs,
@@ -534,9 +584,12 @@ def _compile_factor_graph(variables, constraints, mode, noise_level,
         agg_ends=ends,
         agg_ell=ell,
     )
+    # Variables mostly share a few Domain objects: one tuple each.
+    domain_values = {
+        key: tuple(d) for key, d in {id(d): d for d in domains}.items()}
     meta = FactorGraphMeta(
-        var_names=tuple(v.name for v in variables),
-        domains=tuple(tuple(v.domain) for v in variables),
+        var_names=tuple(names),
+        domains=tuple(domain_values[id(d)] for d in domains),
         factor_names=tuple(factor_names),
         bucket_sizes=tuple(bucket_sizes),
         mode=mode,

@@ -342,9 +342,11 @@ class TestDurableResults:
         replacement's /result-path lookups (result/status/trace_id)
         answer from the journal, and the outcome equals the
         predecessor's."""
+        from pydcop_tpu.observability.profiler import profiler
         from pydcop_tpu.serving.service import SolveService
 
         d = str(tmp_path)
+        was_profiling = profiler.enabled
         svc = SolveService(journal_dir=d).start()
         rid = svc.submit(load_dcop(dcop_yaml(_path_dcop(8, 11))),
                          params={"max_cycles": 30})
@@ -368,6 +370,9 @@ class TestDurableResults:
                 svc2.result("never-acked")
         finally:
             svc2.stop(drain=False)
+            # The killed service never gave the profiler back, and
+            # the rest of this worker's tests would run profiled.
+            profiler.enabled = was_profiling
 
 
 # ------------------------------------------------------------------ #

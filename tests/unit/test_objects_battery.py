@@ -15,10 +15,13 @@ from pydcop_tpu.dcop.objects import (
     VariableNoisyCostFunc,
     VariableWithCostDict,
     VariableWithCostFunc,
+    _stable_noise,
+    _uniform_streams,
     binary_domain,
     create_agents,
     create_binary_variables,
     create_variables,
+    stable_noise_batch,
 )
 from pydcop_tpu.utils.simple_repr import from_repr, simple_repr
 
@@ -156,6 +159,52 @@ class TestCostVariables:
                                   seed=3)
         v2 = from_repr(simple_repr(v))
         assert v2.cost_for_val(1) == v.cost_for_val(1)
+
+
+# The batch draw (the host compile's, engine/compile.py) against the
+# per-name ``default_rng`` stream it replaces there: `==` on every
+# element, so a moved bit fails here and not in a tie-break.
+NOISE_NAMES = {
+    "ascii": ["v", "x_12", "light_0003", "a" * 300, "v"],
+    "non_ascii": ["é_ü", "变量_1", "ß", "🙂"],
+    "empty_name": [""],
+    "generated_10k": [f"v{i:05d}" for i in range(10_000)],
+    "no_names": [],
+}
+NOISE_SEEDS = [None, 0, 2**32 - 1, 2**32 + 5]
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 5, 10, 40])
+@pytest.mark.parametrize("seed", NOISE_SEEDS,
+                         ids=["none", "0", "under_2_32", "over_2_32"])
+@pytest.mark.parametrize("names", list(NOISE_NAMES))
+def test_batch_noise_is_the_per_name_stream(names, seed, n):
+    names = NOISE_NAMES[names]
+    if len(names) > 1000 and (seed, n) not in [(None, 3), (2**32 + 5, 40)]:
+        names = names[::97]  # the whole 10k on two cases, a stride else
+    batch = stable_noise_batch(names, n, 0.01, seed)
+    assert batch.dtype == np.float64 and batch.shape == (len(names), n)
+    for name, row in zip(names, batch):
+        assert (row == _stable_noise(name, n, 0.01, seed)).all(), name
+
+
+@pytest.mark.parametrize("seed", [0, 2**32 - 1, 2**32, 2**64 - 1])
+def test_uniform_streams_are_numpys_default_rng(seed):
+    # Straight against numpy: a release that moved SeedSequence's
+    # mixing or PCG64's stream would change every noisy solve.
+    ours = _uniform_streams(np.array([seed, seed], dtype=np.uint64), 40)
+    theirs = np.random.default_rng(seed).random(40)
+    assert (ours[0] == theirs).all() and (ours[1] == theirs).all()
+
+
+def test_batch_noise_prefix_and_level():
+    # Mixed domain sizes draw the largest and mask: the first d of n
+    # draws are the d-draw stream; a level of 0 is all zeros.
+    names = ["a", "b", "c"]
+    wide = stable_noise_batch(names, 10, 0.02, 7)
+    assert (wide[:, :3] == stable_noise_batch(names, 3, 0.02, 7)).all()
+    assert (0 <= wide).all() and (wide < 0.02).all()
+    assert not stable_noise_batch(names, 4, 0.0, 7).any()
 
 
 class TestBinaryAndExternal:
