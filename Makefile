@@ -4,7 +4,7 @@
 
 PY ?= python
 
-.PHONY: all test chaos chaos-soak chaos-soak-quick trace-demo perf-smoke serve-smoke shard-smoke bench-check unit api cli check doctest bench dryrun chip-smoke
+.PHONY: all test chaos chaos-soak chaos-soak-quick trace-demo serve-smoke shard-smoke unit api cli check doctest dryrun chip-smoke
 
 # 0 = the full scenario matrix; `make test` runs the --quick
 # device-side gate (chaos_soak.QUICK_GATE; fixed seed, ~20 s).
@@ -52,16 +52,6 @@ chaos-soak-quick:
 trace-demo:
 	$(PY) tools/trace_demo.py
 
-# Perf-smoke gate: the hot-path claims measured on CPU — vectorized
-# compile >= 3x over the per-factor loop on a 10k-factor expression
-# instance, a structure-cache hit skipping layout construction
-# (counter-asserted) and compiling faster, the aggregation autotuner
-# picking a valid strategy + replaying from its JSON cache, and the
-# always-on flight recorder costing <= 5% on the segmented-run
-# benchmark.  See tools/perf_smoke.py.
-perf-smoke:
-	$(PY) tools/perf_smoke.py
-
 # Serve-smoke gate: the solve service end-to-end over real HTTP —
 # a mixed-structure burst of N requests must complete in fewer than
 # N device dispatches (batch coalescing counter-asserted), every
@@ -81,16 +71,7 @@ serve-smoke:
 shard-smoke:
 	$(PY) tools/shard_smoke.py
 
-# Bench regression sentinel: noise-aware (median ± MAD per backend)
-# run-over-run check of the BENCH_r*.json trajectory, with a
-# sparkline trajectory line per backend.  Hard gate standalone; `make
-# test` runs it ADVISORY (`-` prefix: a slow shared host must not
-# block an unrelated PR).  See tools/bench_sentinel.py.
-bench-check:
-	$(PY) tools/bench_sentinel.py
-
-test: trace-demo perf-smoke serve-smoke shard-smoke
-	-$(PY) tools/bench_sentinel.py
+test: trace-demo serve-smoke shard-smoke
 	$(MAKE) chaos-soak-quick
 	$(PY) -m pytest tests/ -q
 
@@ -105,11 +86,6 @@ cli:
 
 check: doctest
 	$(PY) tools/static_check.py
-
-# Needs a TPU: without one bench.py exits non-zero (an explicit
-# JAX_PLATFORMS=cpu keeps the CPU path for tests and tools/*_smoke.py).
-bench:
-	$(PY) bench.py
 
 dryrun:
 	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \

@@ -285,7 +285,7 @@ def phase_sync_vs_block(dcop) -> None:
     ``jax.block_until_ready`` on the same dispatched program: if the
     fetch after a returned ``block_until_ready`` costs only its
     round trip, the plain idiom is a true barrier here (ROADMAP
-    Queue 3 item 1)."""
+    Queue 3, "`engine/timing.py`'s `sync`")."""
     with Phase("sync_vs_block_until_ready") as out:
         import jax
 

@@ -39,7 +39,8 @@ algo_params = [
     # kernels (ops/localsearch.py): "scatter" is the parity
     # default; "ell" replaces every segment_sum/max/min with
     # compile-time dense-gather edge lists (the TPU HBM-regime
-    # candidate, benchmarks/exp_aggregation.py).  Single-device;
+    # candidate, not yet decided on the chip: ROADMAP.md Queue 3
+    # "Four aggregations").  Single-device;
     # sharded runs always use scatter.
     AlgoParameterDef(
         "aggregation", "str", ["scatter", "ell"], "scatter"

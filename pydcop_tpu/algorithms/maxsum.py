@@ -82,15 +82,18 @@ algo_params = [
     # min-aggregation and a compacted reduction does ~D/K of the dense
     # work once the survivors fit the static budget.  Results are
     # IDENTICAL to the unpruned kernel (bit-identical on integer
-    # tables — gated in make perf-smoke); wins on large domains
-    # (D >= ~32), edge layout only.
+    # tables: tests/unit/test_workreduction_battery.py); meant for
+    # large domains (D >= ~32), edge layout only.  Not yet timed on
+    # the chip: ROADMAP.md Queue 3 "Branch-and-bound pruning".
     AlgoParameterDef("prune", "bool", None, False),
     # Variable-aggregation strategy for the superstep (device path;
     # see engine/compile.build_aggregation_arrays).  "scatter" is the
     # parity default; "sorted" and "ell" (padded dense-gather edge
-    # lists — no scatter at all) are the HBM-regime alternatives
-    # measured by benchmarks/exp_aggregation.py.  The fourth strategy
-    # there ("boundary", prefix-sum + boundary differences) is
+    # lists — no scatter at all) are the HBM-regime alternatives,
+    # not yet decided on the chip (ROADMAP.md Queue 3 "Four
+    # aggregations").  The fourth strategy of
+    # engine/compile.AGGREGATIONS ("boundary", prefix-sum + boundary
+    # differences) is
     # experiment-only: f32 prefix sums over millions of edges cancel
     # catastrophically at exactly the scale it targets, and TPUs have
     # no f64 to accumulate in — so it is not offered for solves.
@@ -105,8 +108,9 @@ algo_params = [
     ),
     # Message-array layout (device path).  "edge" keeps messages as
     # [F, arity, D] (domain minor); "lane" transposes to [D, arity, F]
-    # — factors on the TPU lane axis — the HBM-regime candidate
-    # measured by benchmarks/exp_layout.py (see ops/maxsum_lane.py).
+    # — factors on the TPU lane axis — the HBM-regime candidate, not
+    # yet decided on the chip (ROADMAP.md Queue 3 "Two layouts"; see
+    # ops/maxsum_lane.py).
     # Single-device and scatter-aggregation only.
     AlgoParameterDef("layout", "str", ["edge", "lane"], "edge"),
 ]

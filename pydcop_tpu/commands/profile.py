@@ -12,11 +12,8 @@ and on which backend?  Two modes over the same report shape:
 - offline, over artifacts: ``--trace FILE...`` aggregates an exported
   trace's spans into the time breakdown (``serve_queued`` /
   ``serve_dispatch`` / ``engine_segment`` / ``jit_compile`` — the
-  span taxonomy maps onto the ledger components), ``--metrics
-  FILE.jsonl`` reads the last registry snapshot's ledger counters,
-  and ``--bench DIR`` adds the per-leg resolved-backend table from
-  ``BENCH_r*.json`` ``leg_backends`` (backend honesty: which legs
-  actually ran on the accelerator).
+  span taxonomy maps onto the ledger components) and ``--metrics
+  FILE.jsonl`` reads the last registry snapshot's ledger counters.
 
 Output: a where-the-time-went breakdown (component seconds + share),
 the top-N structures by device time, waste by cause (padding vs
@@ -25,10 +22,7 @@ the full document for tooling.  docs/observability.md "Efficiency
 accounting" documents the fields.
 """
 
-import glob as glob_mod
 import json
-import os
-import re
 import sys
 from typing import Any, Dict, List, Optional
 
@@ -73,10 +67,6 @@ def set_parser(subparsers):
         "--metrics", default=None, metavar="FILE",
         help="metrics snapshot JSONL (--metrics runs): ledger "
              "counters from the last snapshot")
-    report.add_argument(
-        "--bench", default=None, metavar="DIR",
-        help="bench history directory (BENCH_r*.json): per-leg "
-             "resolved-backend table")
     report.add_argument(
         "--top", type=int, default=10,
         help="structures to list by device time (default 10)")
@@ -208,37 +198,6 @@ def metrics_breakdown(path: str) -> Dict[str, Any]:
     return out
 
 
-def bench_backends(root: str) -> List[Dict[str, Any]]:
-    """Per-leg resolved-backend table from the bench history's
-    ``leg_backends`` keys (absent before PR 11 — older rounds report
-    only their headline backend)."""
-    rows: List[Dict[str, Any]] = []
-    numbered = []
-    for path in glob_mod.glob(os.path.join(root, "BENCH_r*.json")):
-        match = re.fullmatch(r"BENCH_r(\d+)\.json",
-                             os.path.basename(path))
-        if match:
-            numbered.append((int(match.group(1)), path))
-    for _, path in sorted(numbered):
-        try:
-            with open(path, encoding="utf-8") as f:
-                doc = json.load(f)
-        except (OSError, ValueError):
-            continue
-        parsed = doc.get("parsed") or {}
-        rows.append({
-            "source": os.path.basename(path),
-            "backend": parsed.get("backend") or "cpu",
-            "leg_backends": {
-                leg: info.get("backend")
-                for leg, info in (
-                    parsed.get("leg_backends") or {}).items()
-                if isinstance(info, dict)
-            },
-        })
-    return rows
-
-
 # ------------------------------------------------------------------ #
 # rendering
 # ------------------------------------------------------------------ #
@@ -335,9 +294,6 @@ def run_report(args) -> int:
                   f"{args.metrics}: {exc}", file=sys.stderr)
             return 2
         report["mode"].append("metrics")
-    if args.bench:
-        report["bench_backends"] = bench_backends(args.bench)
-        report["mode"].append("bench")
     if not report["mode"]:
         # No source named: report on THIS process's tracker (mostly a
         # plumbing self-test, like `pydcop debug bundle` without
@@ -360,11 +316,4 @@ def run_report(args) -> int:
         print(f"\nmetrics snapshot: "
               f"{json.dumps(report['metrics'], default=str)}",
               file=out)
-    if "bench_backends" in report:
-        print("\nbench legs by resolved backend:", file=out)
-        for row in report["bench_backends"]:
-            legs = (", ".join(f"{leg}={b}" for leg, b in
-                              sorted(row["leg_backends"].items()))
-                    or f"(pre-leg_backends: {row['backend']})")
-            print(f"  {row['source']:<16} {legs}", file=out)
     return 0

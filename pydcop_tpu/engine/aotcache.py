@@ -24,9 +24,8 @@ or fixed, never derived from ``tempfile``, a pid or the time:
 3. otherwise ``<checkout>/.cache/jax`` (:data:`DEFAULT_DIR`, derived
    from the package location, git-ignored).
 
-``pydcop solve``, ``pydcop serve``, ``chip_smoke.py`` and ``bench.py``
-all call :func:`enable_persistent_compile_cache` before their first
-jit.
+``pydcop solve``, ``pydcop serve`` and ``chip_smoke.py`` all call
+:func:`enable_persistent_compile_cache` before their first jit.
 
 **The set-before-jit latch.**  JAX latches its cache configuration on
 the FIRST jit compilation: setting ``jax_compilation_cache_dir`` after

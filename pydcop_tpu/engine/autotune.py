@@ -728,8 +728,8 @@ _packfit_dirty: Dict[str, int] = {}
 
 def pack_fit_enabled() -> bool:
     """``PYDCOP_PACK_FIT=0`` freezes the pack planner on the
-    compiled-in default constants (the on/off isolation knob the
-    perf-smoke pairwise gate and the serving bench A/B use)."""
+    compiled-in default constants (the on/off isolation knob of an
+    A/B; ROADMAP.md Queue 3 "Three packing tiers")."""
     return os.environ.get("PYDCOP_PACK_FIT", "1") != "0"
 
 

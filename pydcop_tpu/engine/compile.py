@@ -164,8 +164,9 @@ class CompiledFactorGraph(NamedTuple):
     (optionally sharded).
 
     The optional ``agg_*`` arrays select the variable-aggregation
-    strategy for the MaxSum superstep (see ops/maxsum.aggregate_beliefs
-    and benchmarks/exp_aggregation.py for the measured decision):
+    strategy for the MaxSum superstep (see ops/maxsum.aggregate_beliefs;
+    not yet decided on the chip: ROADMAP.md Queue 3 "Four
+    aggregations"):
 
     - all None (default): unsorted scatter-add (``segment_sum``);
     - perm + sorted_seg: compile-time edge sort, per-cycle gather into
@@ -356,8 +357,7 @@ def _factor_table(c: Constraint, memo: Dict,
     is the vectorized numpy path
     (relations.NAryFunctionRelation.to_array) instead of a d^arity
     python loop.  ``vectorize=False`` restores the per-factor
-    per-assignment reference path — the A/B baseline
-    ``make perf-smoke`` measures against."""
+    per-assignment reference path the tests compare against."""
     if not vectorize:
         if isinstance(c, NAryFunctionRelation):
             # The pre-vectorization behavior: the base per-assignment

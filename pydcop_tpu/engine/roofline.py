@@ -20,8 +20,8 @@ when `working_set_bytes` fits comfortably in on-chip VMEM (most
 problems below ~1M variables, including the 10k north-star bench), XLA
 keeps all state resident across supersteps, actual HBM traffic is near
 zero, and the byte model is a ceiling rather than a measurement —
-`hbm_util` is then None with `vmem_resident: True`.  bench.py's 1M-var
-scale leg exists precisely to measure the HBM-bound regime.
+`hbm_util` is then None with `vmem_resident: True`.  The HBM-bound
+regime waits for a cell of that size (ROADMAP.md Queue 2 B).
 
 Peak numbers come from public chip specs, keyed on
 `jax.devices()[0].device_kind` so each TPU generation gets its own

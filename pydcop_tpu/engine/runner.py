@@ -155,8 +155,7 @@ def timed_jit_call(warm: set, key, fn, *args,
     # is exactly the signal a flight-recorder postmortem needs); warm
     # dispatches only under a file session — in flight-only mode the
     # enclosing engine_segment span already marks every segment, and
-    # the redundant per-segment event would eat the ring AND the ≤5%
-    # overhead budget gated in make perf-smoke.
+    # the redundant per-segment event would eat the ring.
     span = NOOP_SPAN
     if tracer.enabled or (first and tracer.active):
         span = tracer.span("engine_call", "engine", key=str(key))
@@ -1022,8 +1021,8 @@ class MaxSumEngine:
                   ) -> "DeviceRunResult":
         """Run recording the constraint cost of the selected
         assignment after every cycle (metrics['cost_trace'], numpy
-        [max_cycles]) — the curve behind time-to-equal-cost claims
-        (bench.py).  Default ``stop_on_convergence`` matches
+        [max_cycles]) — the curve behind time-to-equal-cost claims.
+        Default ``stop_on_convergence`` matches
         :meth:`run`: the loop exits at the fixpoint, the cycle count
         agrees with an untraced solve, and the curve's tail holds the
         final cost (still a valid anytime record at full length)."""

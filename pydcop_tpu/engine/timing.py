@@ -10,7 +10,7 @@ result measures the enqueue, not the run.  Two tools:
   platform implements ``jax.block_until_ready`` (``chip_smoke.py``
   prints both on the same dispatched program, so whether the plain
   idiom can replace this one is decided from a chip reading — ROADMAP
-  Queue 3 item 1).  Include the fetch in the timed window and the
+  Queue 3, "`engine/timing.py`'s `sync`").  Include the fetch in the timed window and the
   number is end to end.
 - :func:`marginal_seconds_per_cycle` removes the per-call constant
   (enqueue + sync + fetch, independent of program length) by timing

@@ -57,9 +57,9 @@ default backend, device kind and device count), so a CPU number can
 never be read as a TPU number.
 
 Overhead: recording is a dict update under one lock per DISPATCH
-(milliseconds of device work), never per cycle; ``make perf-smoke``
-gates the plane at ≤ 5% with the pairwise-interleaved on/off
-methodology.  ``PYDCOP_EFFICIENCY=0`` disables recording entirely.
+(milliseconds of device work), never per cycle (its cost on the chip
+has not been read: ROADMAP.md Queue 3 "Off-switches").
+``PYDCOP_EFFICIENCY=0`` disables recording entirely.
 """
 
 import os

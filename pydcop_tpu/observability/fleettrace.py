@@ -30,7 +30,7 @@ closes the loop in three pieces:
   request at ``GET /fleet/forensics/<id>``.
 
 ``PYDCOP_FLEET_TRACE=0`` turns the whole plane off (read per call so
-the perf-smoke pairwise gate can toggle it at runtime); the spawned
+``FleetRouter.set_fleet_trace`` can toggle it at runtime); the spawned
 workers inherit the knob through the router's environment.
 """
 

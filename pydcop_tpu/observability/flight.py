@@ -9,8 +9,7 @@ the black box: a bounded per-process ring buffer that receives every
 span/instant recorded through the tracer EVEN WHILE file tracing is
 off (``tracer.set_flight``; sites guard on ``tracer.active``), so the
 last-N events before an anomaly are always available.  Overhead is a
-deque append per event at segment/request cadence — gated ≤ 5% on the
-segmented-run benchmark in ``make perf-smoke``; the per-message hot
+deque append per event at segment/request cadence; the per-message hot
 paths stay gated on ``tracer.enabled`` so the ring holds signal, not
 message spam.
 
@@ -131,8 +130,7 @@ class FlightRecorder:
     """The ring + the bundle writer.
 
     ``record`` is the tracer-side sink (one bounded-deque append —
-    atomic under the GIL, so the hot path takes no lock; the 5%
-    overhead budget is gated in ``make perf-smoke``); ``snapshot``
+    atomic under the GIL, so the hot path takes no lock); ``snapshot``
     retries on ``deque mutated during iteration`` so a bundle cut on
     a busy process never loses its event tail to a concurrent
     append; ``trigger`` records the anomaly as a trace instant

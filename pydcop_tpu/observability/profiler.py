@@ -25,7 +25,7 @@ can distinguish "not profiled" from "profiled, backend said nothing".
 
 Enablement: :class:`~pydcop_tpu.observability.ObservabilitySession`
 turns the profiler on for observed solves; ``PYDCOP_XLA_PROFILE=1``
-forces it on (bench.py), ``=0`` forces it off regardless of session.
+forces it on, ``=0`` forces it off regardless of session.
 """
 
 import os

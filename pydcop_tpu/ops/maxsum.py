@@ -311,7 +311,7 @@ def _pruned_binary_update(bucket, msgs: jnp.ndarray,
     matches the dense path exactly ((costs + m0) + m1, reduce,
     subtract own message) — so on integer cost tables the whole
     trajectory is bit-identical (asserted in
-    tests/unit/test_workreduction_battery.py, gated in perf-smoke).
+    tests/unit/test_workreduction_battery.py).
 
     Work shape: survivors are compacted sort-free — the j-th survivor
     index is recovered from the monotone prefix counts by an unrolled
@@ -410,8 +410,8 @@ def aggregate_beliefs(graph: CompiledFactorGraph, f2v: Msgs
     This aggregation is the single cross-shard op per superstep, and
     the suspect past the size that fits fast memory (~100k vars).
     Strategy is chosen at compile time via the graph's ``agg_*`` arrays
-    (engine/compile.build_aggregation_arrays; A/B harness
-    benchmarks/exp_aggregation.py):
+    (engine/compile.build_aggregation_arrays; not yet decided on the
+    chip: ROADMAP.md Queue 3 "Four aggregations"):
 
     - default: unsorted scatter-add, one ``segment_sum`` per bucket —
       the only option for sharded graphs;
@@ -423,9 +423,8 @@ def aggregate_beliefs(graph: CompiledFactorGraph, f2v: Msgs
       differences cancel catastrophically at the million-edge scale
       this strategy targets (absolute error ~ulp of the running
       total, which dwarfs the 0.01 tie-breaking noise), and TPUs
-      have no f64 to accumulate in.  Valid for throughput A/Bs
-      (exp_aggregation, bench_scale) and small problems; not offered
-      as a maxsum algo param.
+      have no f64 to accumulate in.  Valid for throughput A/Bs and
+      small problems; not offered as a maxsum algo param.
     - ell: dense gather + K-way sum over compile-time per-variable
       edge lists padded to the max degree — no scatter, no sort.
       Numerically safe (each variable's sum is over its own K terms,
@@ -528,8 +527,8 @@ def superstep(state: MaxSumState, graph: CompiledFactorGraph, *,
     each see only last cycle's mail, reference
     SynchronousComputationMixin), with per-edge damping and SAME_COUNT
     send-suppression.  This cycle-for-cycle equivalence with the
-    threaded agent runtime is what makes device-vs-thread cost parity
-    assertable on large loopy graphs (bench.py cost_parity)."""
+    threaded agent runtime is what makes device-vs-thread parity
+    assertable (tests/api/test_device_thread_parity.py)."""
     first = state.cycle == 0
     with jax.named_scope("maxsum/update"):
         valids = tuple(

@@ -6,9 +6,9 @@ that layout leaves 120+ of the 128 TPU lanes idle in every vector op,
 and past the size that fits fast memory (~100k vars) the
 scatter/gather traffic is issued in D-element slivers.  This module
 is the full-superstep version of the transposed layout (not yet
-measured against edge-major on the chip — PERF.md), A/B-able
-via benchmarks/exp_layout.py and selectable with the maxsum
-``layout="lane"`` algo param (engine/runner.MaxSumEngine).
+measured against edge-major on the chip: ROADMAP.md Queue 3
+"Two layouts"), selectable with the maxsum ``layout="lane"`` algo
+param (engine/runner.MaxSumEngine).
 
 Layout (one bucket of arity ``a``, F factors, padded domain D):
 

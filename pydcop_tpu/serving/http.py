@@ -343,7 +343,7 @@ class _ServeHandler(_Handler):
             # fleet-collector address here (at fleet start, after a
             # replica restart, on a --join) so this process's span
             # shipper knows where completed spans go; ``enable:
-            # false`` detaches it (the perf-smoke pairwise gate
+            # false`` detaches it (``FleetRouter.set_fleet_trace``
             # toggles tracing at runtime this way).
             body = self._read_json_body()
             if body is None:

@@ -17,8 +17,8 @@ RENDEZVOUS HASHING on it: every replica scores
 ``sha1(key || replica_id)`` and the highest healthy scorer wins, so
 same-structure traffic deterministically lands where the compiled
 program (and the batch-mates to coalesce with) is already warm —
-cache-affinity beats round-robin, and the bench proves it
-(bench.py bench_serving_fleet, ``affinity_hit_fraction`` in /stats).
+cache-affinity should beat round-robin (``affinity_hit_fraction`` in
+/stats; not yet read on the chip: ROADMAP.md Queue 1 "The fleet").
 Rendezvous keeps the map stable under membership change: a replica
 death remaps ONLY the keys it owned.  Two escape hatches keep
 affinity from becoming a liability: **least-loaded spillover** (a
@@ -1088,8 +1088,8 @@ class FleetRouter:
                 self.push_trace_config(replica, enable=False)
 
     def set_fleet_trace(self, on: bool) -> None:
-        """Runtime toggle (the perf-smoke pairwise gate flips this
-        between timed phases): sets the env knob gating this
+        """Runtime toggle (an on/off A/B flips this between timed
+        phases): sets the env knob gating this
         process's header stamping and minting, then re-arms or
         disarms the collector and every worker's shipper."""
         os.environ[fleettrace.ENV_KNOB] = "1" if on else "0"

@@ -44,7 +44,7 @@ def _acceptable(res) -> bool:
 
 
 def _random_coloring(n_vars: int, n_colors: int, seed: int,
-                     n_agents: int = 4) -> DCOP:
+                     n_agents: int = 4, density: float = 1.8) -> DCOP:
     rng = np.random.default_rng(seed)
     dom = Domain("colors", "color", list(range(n_colors)))
     dcop = DCOP(f"gc{n_vars}_{seed}", objective="min")
@@ -53,7 +53,7 @@ def _random_coloring(n_vars: int, n_colors: int, seed: int,
         dcop.add_variable(v)
     eq = np.eye(n_colors, dtype=np.float64)
     seen, k = set(), 0
-    while k < int(n_vars * 1.8):
+    while k < int(n_vars * density):
         i, j = rng.choice(n_vars, size=2, replace=False)
         key = (min(i, j), max(i, j))
         if key in seen:
@@ -180,3 +180,28 @@ def test_seeded_random_instances_quality(algo):
     assert abs(np.mean(dev) - np.mean(thr)) <= 0.10 * 30 * 1.8, (
         f"device {dev} vs thread {thr}"
     )
+
+def test_maxsum_device_equals_thread_once_frozen():
+    """Where the BSP trajectory freezes (send-suppression quiets
+    every edge: a sparse colouring, 1.5 edges a variable) the device
+    engine and the threaded agent runtime end
+    on the IDENTICAL assignment, hence the same cost.  The thread
+    runtime stops on its timeout, so it is given longer until it has
+    run at least the cycles the device needed to converge: the
+    comparison is made once both are frozen, whatever the host's
+    speed."""
+    for timeout in (4, 8, 16, 32):
+        dcop = _random_coloring(60, 3, seed=3, n_agents=8,
+                                density=1.5)
+        r_thr = solve(
+            dcop, "maxsum", backend="thread", timeout=timeout,
+            distribution=_pack_distribution(dcop, "maxsum"))
+        r_dev = solve(dcop, "maxsum", backend="device",
+                      max_cycles=max(int(r_thr["cycles"]), 50))
+        if r_dev["status"] == "FINISHED":
+            break
+    assert r_dev["status"] == "FINISHED", (
+        f"the thread runtime ran {r_thr['cycles']} cycles in "
+        f"{timeout} s, fewer than the device needs to converge")
+    assert r_thr["assignment"] == r_dev["assignment"]
+    assert r_thr["cost"] == r_dev["cost"]

@@ -145,13 +145,17 @@ def test_exact_solvers_cost_parity(algo):
 
 
 def _grid_dcop(side=10, seed=4):
-    """4-neighbor grid coloring: the locally-connected loopy shape
-    the partitioner is built for (single-digit-percent cuts).  One
-    shared builder across the bench, the shard-smoke gate and both
-    test batteries — see bench.build_grid_dcop."""
-    from bench import build_grid_dcop
+    """4-neighbor grid coloring with random integer tables (`pydcop
+    generate graph_coloring -g grid --soft`): the locally-connected
+    loopy shape the partitioner is built for (single-digit-percent
+    cuts), as the shard-smoke gate and the sharding battery build
+    it."""
+    from pydcop_tpu.generators.graphcoloring import (
+        generate_graph_coloring,
+    )
 
-    return build_grid_dcop(side, seed=seed)
+    return generate_graph_coloring(
+        side * side, 3, "grid", soft=True, noagents=True, seed=seed)
 
 
 @pytest.mark.parametrize("shards", [2, 8])

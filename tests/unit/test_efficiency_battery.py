@@ -4,22 +4,16 @@ binned, envelope-packed, lane-packed and session dispatch paths;
 attainment math on synthetic cost entries; the tracker rollup
 (per-backend / per-structure separation, waste by cause); the
 ``/profile`` endpoint and ``pydcop profile report --json`` schemas;
-backend-label propagation into the metrics exposition; the sentinel's
-cross-backend refusal; the dynamic engine's deferred-edit batching
-(behavior-identical to per-action application, incl. mid-batch
-recompile and the failed-batch partial-apply contract); and the
-postmortem bundle's efficiency section."""
+backend-label propagation into the metrics exposition; the dynamic
+engine's deferred-edit batching (behavior-identical to per-action
+application, incl. mid-batch recompile and the failed-batch
+partial-apply contract); and the postmortem bundle's efficiency
+section."""
 
 import json
-import os
-import sys
 
 import numpy as np
 import pytest
-
-REPO = os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__))))
-sys.path.insert(0, os.path.join(REPO, "tools"))
 
 from pydcop_tpu.dcop.dcop import DCOP
 from pydcop_tpu.dcop.objects import AgentDef, Domain, Variable
@@ -451,87 +445,6 @@ class TestSurfaces:
                 "jit_compile"} <= spans
         assert doc["structures"][0]["structure"] == "v6d3habc"
 
-    def test_profile_report_bench_mode(self, tmp_path):
-        from pydcop_tpu.commands.profile import bench_backends
-
-        json.dump(
-            {"parsed": {"value": 1.0, "backend": "tpu",
-                        "leg_backends": {
-                            "serve": {"backend": "cpu"},
-                            "headline": {"backend": "tpu"}}}},
-            open(tmp_path / "BENCH_r01.json", "w"))
-        rows = bench_backends(str(tmp_path))
-        assert rows[0]["leg_backends"] == {"serve": "cpu",
-                                           "headline": "tpu"}
-
-
-# ------------------------------------------------------------------ #
-# sentinel cross-backend refusal
-# ------------------------------------------------------------------ #
-
-def _write_round(root, i, serve_value, headline_backend,
-                 serve_backend, with_legs=True):
-    parsed = {"value": 900, "backend": headline_backend,
-              "serve_problems_per_sec": serve_value}
-    if with_legs:
-        parsed["leg_backends"] = {
-            "headline": {"backend": headline_backend},
-            "serve": {"backend": serve_backend},
-        }
-    json.dump({"parsed": parsed},
-              open(os.path.join(root, f"BENCH_r{i:02d}.json"), "w"))
-
-
-class TestSentinelBackendRefusal:
-    def _write_round(self, root, i, serve_value, headline_backend,
-                     serve_backend, with_legs=True):
-        _write_round(root, i, serve_value, headline_backend,
-                     serve_backend, with_legs)
-
-    def test_cpu_fallback_leg_never_pads_tpu_baseline(self, tmp_path):
-        import bench_sentinel
-
-        root = str(tmp_path)
-        # TPU serve history, then a round whose serve leg fell back
-        # to CPU with a (for TPU) catastrophic value.
-        for i, v in enumerate([500, 510, 505, 498], 1):
-            self._write_round(root, i, v, "tpu", "tpu")
-        self._write_round(root, 5, 30, "tpu", "cpu")
-        report = bench_sentinel.run_check(root)
-        # The cpu leg forms its own 1-point series (insufficient),
-        # the tpu baseline is NOT judged against (or padded by) it,
-        # and the mismatch is named.
-        assert report["series"]["serve:cpu"]["verdict"] == \
-            "insufficient"
-        assert 30 not in report["series"]["serve:tpu"]["values"]
-        assert any("SKIPPED" in line and "cpu" in line
-                   and "tpu" in line for line in report["lines"])
-        assert not report["failed"]
-
-    def test_matching_backend_is_judged(self, tmp_path):
-        import bench_sentinel
-
-        root = str(tmp_path)
-        for i, v in enumerate([100, 102, 99, 101], 1):
-            self._write_round(root, i, v, "cpu", "cpu")
-        self._write_round(root, 5, 30, "cpu", "cpu")
-        report = bench_sentinel.run_check(root)
-        assert report["series"]["serve:cpu"]["verdict"] == \
-            "regressed"
-        assert report["failed"]
-
-    def test_legacy_rows_without_leg_backends_unchanged(self,
-                                                        tmp_path):
-        import bench_sentinel
-
-        root = str(tmp_path)
-        for i, v in enumerate([100, 102, 99, 101, 100], 1):
-            self._write_round(root, i, v, "cpu", "cpu",
-                              with_legs=False)
-        report = bench_sentinel.run_check(root)
-        assert report["series"]["serve:cpu"]["verdict"] == "ok"
-        assert not any("SKIPPED" in line for line in report["lines"])
-
 
 # ------------------------------------------------------------------ #
 # deferred-edit batching (the PR-13 efficiency-note fix)
@@ -866,43 +779,6 @@ class TestReviewRegressions:
         assert not _apply_all(engine, MUTATION_LADDER[:1],
                               batched=False)
 
-    def test_sentinel_newest_is_the_newest_numbered_round(
-            self, tmp_path):
-        """The newest numbered round defines which backend the latest
-        round resolved; nothing else in the directory does."""
-        import bench_sentinel
-
-        root = str(tmp_path)
-        for i, v in enumerate([900, 910, 905, 898, 902], 1):
-            json.dump({"parsed": {
-                "value": v, "backend": "cpu",
-                "leg_backends": {"headline": {"backend": "cpu"}}}},
-                open(os.path.join(root, f"BENCH_r0{i}.json"), "w"))
-        report = bench_sentinel.run_check(root)
-        assert report["series"]["cpu"]["verdict"] == "ok"
-        assert not any("SKIPPED" in line for line in report["lines"])
-
-    def test_stale_backend_series_reports_but_does_not_gate(
-            self, tmp_path):
-        """A regression inside a backend series the newest round did
-        NOT resolve must not fail CI — the report already says those
-        rows were not compared against the round under test."""
-        import bench_sentinel
-
-        root = str(tmp_path)
-        # A tpu serve history that ends on a (for tpu) catastrophic
-        # value, then a newest round whose serve leg resolved cpu.
-        for i, v in enumerate([500, 510, 505, 498, 300], 1):
-            _write_round(root, i, v, "tpu", "tpu")
-        _write_round(root, 6, 120, "tpu", "cpu")
-        report = bench_sentinel.run_check(root)
-        tpu = report["series"]["serve:tpu"]
-        assert tpu["verdict"] == "regressed"
-        assert tpu["gating"] is False
-        assert any("stale backend — not gating" in line
-                   for line in report["lines"])
-        assert not report["failed"]
-
     def test_dynamic_engine_outside_sessions_labels_dynamic(self):
         """A scenario replay / direct dynamic engine is NOT a
         session: its dispatches must not masquerade as session work
@@ -1012,6 +888,22 @@ class TestPipelinedFlush:
         assert stats["pipeline"]["pipelined_dispatches"] == 0
         for res in results:
             _assert_ledger_sums(res["ledger"])
+
+    def test_pipelined_answers_equal_synchronous(self):
+        """Four structure bins of two requests in one flush: the
+        pipelined path answers every request as the synchronous path
+        does, and only the pipelined service pipelined."""
+        dcops = [_ring(n, s) for n in (17, 18, 19, 20)
+                 for s in (0, 1)]
+        answers = {}
+        for pipeline in (False, True):
+            _ids, results, _reqs, stats, _comp = self._pipelined_burst(
+                dcops, service_kw={"pipeline": pipeline,
+                                   "speculate": False})
+            answers[pipeline] = [r["assignment"] for r in results]
+            pipelined = stats["pipeline"]["pipelined_dispatches"]
+            assert (pipelined > 0) == pipeline, stats
+        assert answers[True] == answers[False]
 
     def test_stubbed_run_batch_never_pipelines(self):
         # A test double stubbing the device call IS the contract

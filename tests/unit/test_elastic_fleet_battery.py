@@ -22,10 +22,7 @@ session migration, SLO-driven autoscaling, and weighted fair queuing.
   verdict reroutes over ForwardNotSent to a survivor, (b) an open
   SSE stream through the router ends in a clean reconnectable EOF
   (never a hang), (c) the reconnect resumes the stream and acked
-  event batches survive;
-- the bench sentinel's ``fleet_elastic`` family: empty, malformed
-  and too-short histories report instead of crashing, and a real
-  regression in the new family still trips the gate.
+  event batches survive.
 """
 
 import json
@@ -53,9 +50,6 @@ from pydcop_tpu.serving.router import (
     FleetRouter,
     Replica,
 )
-
-REPO = os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__))))
 
 SESSION_PARAMS = {"noise": 0.01, "stability": 0.001,
                   "max_cycles": 500}
@@ -653,78 +647,3 @@ class TestAdminSurface:
             assert status == 400, body
         finally:
             handle.stop()
-
-
-# ------------------------------------------------------------------ #
-# bench sentinel: the brand-new fleet_elastic family
-
-
-def _load_sentinel():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_sentinel_under_test",
-        os.path.join(REPO, "tools", "bench_sentinel.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _round(n, value=100.0, fleet_elastic=None, backend="cpu"):
-    parsed = {"value": value, "backend": backend}
-    if fleet_elastic is not None:
-        parsed["fleet_elastic_problems_per_sec"] = fleet_elastic
-        parsed["leg_backends"] = {
-            "fleet_elastic": {"backend": backend}}
-    return {"n": n, "parsed": parsed}
-
-
-class TestSentinelNewFamily:
-    def test_empty_history_reports_instead_of_crashing(self,
-                                                       tmp_path):
-        sentinel = _load_sentinel()
-        report = sentinel.run_check(str(tmp_path))
-        assert report["failed"] is False
-        assert report["series"] == {}
-
-    def test_malformed_history_is_skipped(self, tmp_path):
-        sentinel = _load_sentinel()
-        (tmp_path / "BENCH_r1.json").write_text("[1, 2]")
-        (tmp_path / "BENCH_r2.json").write_text(
-            '{"parsed": "not a dict"}')
-        (tmp_path / "BENCH_r3.json").write_text("not json at all")
-        report = sentinel.run_check(str(tmp_path))
-        assert report["failed"] is False
-        assert len(report["skipped"]) == 3
-
-    def test_new_family_with_short_history_is_insufficient(
-            self, tmp_path):
-        sentinel = _load_sentinel()
-        (tmp_path / "BENCH_r1.json").write_text(
-            json.dumps(_round(1, fleet_elastic=5.0)))
-        report = sentinel.run_check(str(tmp_path))
-        assert report["failed"] is False
-        verdicts = report["series"]
-        assert verdicts["fleet_elastic:cpu"]["verdict"] \
-            == "insufficient"
-
-    def test_regression_in_the_new_family_trips_the_gate(
-            self, tmp_path):
-        sentinel = _load_sentinel()
-        for n, v in enumerate([10.0, 10.0, 10.0, 3.0], start=1):
-            (tmp_path / f"BENCH_r{n}.json").write_text(
-                json.dumps(_round(n, fleet_elastic=v)))
-        report = sentinel.run_check(str(tmp_path))
-        assert report["failed"] is True
-        assert report["series"]["fleet_elastic:cpu"]["verdict"] \
-            == "regressed"
-
-    def test_healthy_new_family_passes(self, tmp_path):
-        sentinel = _load_sentinel()
-        for n, v in enumerate([10.0, 10.5, 9.8, 10.2], start=1):
-            (tmp_path / f"BENCH_r{n}.json").write_text(
-                json.dumps(_round(n, fleet_elastic=v)))
-        report = sentinel.run_check(str(tmp_path))
-        assert report["failed"] is False
-        assert report["series"]["fleet_elastic:cpu"]["verdict"] \
-            == "ok"

@@ -4,8 +4,8 @@ bit-identity against solo dispatches across topologies / arities /
 domains, lane-packed disjoint unions (values, honest per-member
 convergence), pad-accounting honesty (``envelope_waste`` sums), the
 pack-vs-solo cost model and its portfolio-cache prior replay, the
-scheduler's flush planning, the ``normalize_params`` ``prune=-1``
-fall-through regression, and the ``serve_mixed`` sentinel family."""
+scheduler's flush planning, and the ``normalize_params``
+``prune=-1`` fall-through regression."""
 
 import json
 
@@ -556,7 +556,7 @@ class TestFlushPlanning:
 
 
 # ------------------------------------------------------------------ #
-# satellites: normalize_params prune fall-through + sentinel family
+# satellites: normalize_params prune fall-through
 
 
 class TestParamValidation:
@@ -576,33 +576,3 @@ class TestParamValidation:
         assert binning.normalize_params({"prune": 1})["prune"] == 1
         assert binning.normalize_params(
             {"prune": "auto"})["prune"] == "auto"
-
-
-class TestSentinelServeMixedFamily:
-    def _write_round(self, root, idx, mixed):
-        doc = {"n": idx, "parsed": {
-            "value": 800.0, "backend": "cpu",
-            "serve_mixed_problems_per_sec": mixed,
-        }}
-        (root / f"BENCH_r{idx:02d}.json").write_text(json.dumps(doc))
-
-    def test_serve_mixed_series_judged(self, tmp_path):
-        import sys
-
-        sys.path.insert(0, "tools")
-        try:
-            import bench_sentinel
-        finally:
-            sys.path.pop(0)
-        for i, v in enumerate([200.0, 210.0, 190.0], start=1):
-            self._write_round(tmp_path, i, v)
-        ok = bench_sentinel.run_check(str(tmp_path))
-        assert "serve_mixed:cpu" in ok["series"]
-        assert ok["series"]["serve_mixed:cpu"]["verdict"] == "ok"
-        assert not ok["failed"]
-        # A collapsed newest round regresses the family.
-        self._write_round(tmp_path, 4, 60.0)
-        bad = bench_sentinel.run_check(str(tmp_path))
-        assert bad["series"]["serve_mixed:cpu"]["verdict"] == \
-            "regressed"
-        assert bad["failed"]

@@ -725,78 +725,3 @@ class TestHttpStrictFields:
         finally:
             front.stop()
             svc.stop(drain=False)
-
-
-# ------------------------------------------------------------------ #
-# sentinel: recovery-latency series are judged lower-is-better
-
-
-class TestRecoverySentinelSeries:
-    def _write(self, root, replay, shardrec):
-        for i, (rv, sv) in enumerate(zip(replay, shardrec)):
-            doc = {"n": i, "parsed": {
-                "value": 800.0 + i, "backend": "cpu",
-                "serve_recovery_replay_s": rv,
-                "shard_recovery_s": sv,
-                "sharded_backend": "cpu",
-            }}
-            with open(os.path.join(
-                    root, f"BENCH_r{i:02d}.json"), "w") as f:
-                json.dump(doc, f)
-
-    def _sentinel(self):
-        import sys
-
-        sys.path.insert(0, os.path.join(
-            os.path.dirname(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__)))), "tools"))
-        import bench_sentinel
-
-        return bench_sentinel
-
-    def test_faster_recovery_is_never_a_regression(self, tmp_path):
-        bench_sentinel = self._sentinel()
-        d = str(tmp_path / "ok")
-        os.makedirs(d)
-        self._write(d, [0.5, 0.52, 0.48, 0.5, 0.2],
-                    [0.02, 0.021, 0.019, 0.02, 0.01])
-        report = bench_sentinel.run_check(d)
-        assert report["series"]["serve_recovery:cpu"]["verdict"] \
-            == "ok"
-        assert report["series"]["shard_recovery:cpu"]["verdict"] \
-            == "ok"
-        assert not report["failed"]
-
-    def test_recovery_time_spike_regresses(self, tmp_path):
-        """A SLOWER recovery regresses on its own: the polarity is
-        inverted relative to the throughput families."""
-        bench_sentinel = self._sentinel()
-        d = str(tmp_path / "bad")
-        os.makedirs(d)
-        self._write(d, [0.5, 0.52, 0.48, 0.5, 2.5],
-                    [0.02, 0.021, 0.019, 0.02, 0.02])
-        report = bench_sentinel.run_check(d)
-        assert report["series"]["serve_recovery:cpu"]["verdict"] \
-            == "regressed"
-        assert report["failed"]
-        assert any("serve_recovery[cpu]" in line
-                   and "ceiling" in line
-                   for line in report["lines"])
-
-    def test_history_without_recovery_metric_unaffected(
-            self, tmp_path):
-        """Pre-PR-8 rows carry no recovery keys: the series simply
-        starts later, never crashes the sentinel."""
-        bench_sentinel = self._sentinel()
-        d = str(tmp_path / "old")
-        os.makedirs(d)
-        for i in range(4):
-            doc = {"n": i, "parsed": {
-                "value": 800.0 + i, "backend": "cpu"}}
-            with open(os.path.join(d, f"BENCH_r{i:02d}.json"),
-                      "w") as f:
-                json.dump(doc, f)
-        report = bench_sentinel.run_check(d)
-        assert "serve_recovery:cpu" not in report["series"]
-        assert "shard_recovery:cpu" not in report["series"]
-        assert not report["failed"]

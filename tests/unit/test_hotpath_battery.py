@@ -19,8 +19,6 @@ Contracts pinned here:
   records its decision in result metrics.
 """
 
-import os
-
 import numpy as np
 import pytest
 
@@ -184,8 +182,8 @@ class TestCompile:
     def test_cache_hit_skips_layout_build(self):
         vs, cons = _mixed_problem(penalty=9)
         g1, _ = compile_factor_graph(vs, cons, aggregation="ell")
-        assert compile_cache.stats()["layout_builds"] == 1
-        assert compile_cache.stats()["misses"] == 1
+        assert compile_cache.stats() == {
+            "hits": 0, "misses": 1, "layout_builds": 1, "entries": 1}
         # Same structure, different cost tables.
         vs2, cons2 = _mixed_problem(penalty=4)
         g2, _ = compile_factor_graph(vs2, cons2, aggregation="ell")
@@ -544,7 +542,7 @@ class TestAutotuner:
 
 
 # ------------------------------------------------------------------ #
-# Satellites: edge-free aggregation crash, bench flags, sync debug
+# Satellites: edge-free aggregation crash, sync debug
 # ------------------------------------------------------------------ #
 
 
@@ -581,29 +579,6 @@ class TestEdgeFreeAggregation:
                     algo_params={"aggregation": aggregation})
         assert res["status"] == "FINISHED"
         assert res["cost"] == 0.0
-
-
-class TestBenchScaleFlags:
-
-    def _run(self, **flags):
-        import bench
-
-        return bench.bench_scale(n_vars=64, edge_factor=1.0,
-                                 cycles=3, **flags)
-
-    def test_flags_compose(self):
-        out = self._run(return_values=True, detail=True)
-        assert len(out) == 4
-        cps, graph, values, info = out
-        assert values.shape == (64,)
-        assert set(info) == {"sec_per_cycle", "fixed_overhead_s"}
-
-    def test_single_flag_shapes_preserved(self):
-        cps, graph, values = self._run(return_values=True)
-        assert values.shape == (64,)
-        cps, graph, info = self._run(detail=True)
-        assert "sec_per_cycle" in info
-        assert len(self._run()) == 2
 
 
 class TestSyncDebug:

@@ -16,8 +16,6 @@ differ):
   tolerance.
 """
 
-import os
-
 import numpy as np
 import pytest
 
@@ -258,38 +256,26 @@ class TestEngineLayout:
         assert lane.assignment == edge.assignment
 
 
-REPO_ROOT = os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__))))
+class TestLargerInstanceLayout:
+    """300 variables, 450 factors: what the layouts' A/B ran at its
+    smallest size."""
 
-
-class TestBenchScaleLayout:
-    def test_bench_scale_lane_agrees(self):
-        import sys
-
-        sys.path.insert(0, REPO_ROOT)
-        import bench as bench_mod
-        from functools import partial
-
-        _, edge_graph = bench_mod.bench_scale(
-            n_vars=300, cycles=10, layout="edge")
-        _, lane_graph = bench_mod.bench_scale(
-            n_vars=300, cycles=10, layout="lane")
-        _, ev = jax.jit(partial(
-            edge_ops.run_maxsum, max_cycles=10,
-            stop_on_convergence=False))(edge_graph)
-        _, lv = jax.jit(partial(
-            lane_ops.run_maxsum, max_cycles=10,
-            stop_on_convergence=False))(lane_graph)
+    def test_lane_agrees_with_edge(self):
+        graph, _ = compile_dcop(
+            _random_dcop(n_vars=300, n_edges=450, seed=7),
+            noise_level=0.01)
+        _, ev = jax.jit(lambda g: edge_ops.run_maxsum(
+            g, 10, stop_on_convergence=False))(graph)
+        _, lv = jax.jit(lambda g: lane_ops.run_maxsum(
+            g, 10, stop_on_convergence=False))(
+                lane_ops.to_lane_graph(graph))
         agree = np.mean(np.asarray(ev) == np.asarray(lv))
         assert agree > 0.99
 
-    def test_bench_scale_lane_rejects_sorted(self):
-        import sys
-
-        sys.path.insert(0, REPO_ROOT)
-        import bench as bench_mod
-
+    @pytest.mark.parametrize("aggregation", ["sorted", "ell"])
+    def test_lane_rejects_a_sort_based_aggregation(self, aggregation):
+        graph, meta = compile_dcop(
+            _random_dcop(n_vars=300, n_edges=450, seed=7),
+            aggregation=aggregation)
         with pytest.raises(ValueError, match="scatter"):
-            bench_mod.bench_scale(
-                n_vars=100, cycles=2, aggregation="sorted",
-                layout="lane")
+            MaxSumEngine(graph, meta, layout="lane")

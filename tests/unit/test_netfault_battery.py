@@ -126,6 +126,19 @@ class TestDeterminism:
                         timeout=0.01) == \
             {"dup": False, "lose_response": False}
 
+    def test_plan_that_matches_nothing_injects_nothing(self):
+        """An installed plan whose clauses and partition all miss the
+        live link scans in full on every hop and counts no fault."""
+        p = FaultPlan.parse(
+            "seed=5;link=*>replica-*,drop=1.0,delay_ms=5;"
+            "link=*>*,path=/no-such-endpoint,blackhole=1;"
+            "partition=ghost-a/ghost-b")
+        for _ in range(40):
+            assert p.decide("client", "worker-0", timeout=0.01,
+                            path="/solve") == \
+                {"dup": False, "lose_response": False}
+        assert p.injected() == {}
+
     def test_times_retires_the_clause(self):
         p = FaultPlan.parse("link=*>*,lose_response=1.0,times=1")
         first = p.decide("router", "replica-0", timeout=0.01)

@@ -1,6 +1,6 @@
 """Performance-intelligence battery: XLA cost attribution, live
-telemetry endpoint, multi-process trace merge/diff, bench regression
-sentinel, and the observability hardening satellites.
+telemetry endpoint, multi-process trace merge/diff, and the
+observability hardening satellites.
 
 Acceptance targets (ISSUE 5): a ``run_checkpointed`` solve on CPU
 records per-segment XLA cost/memory-analysis metrics (or an explicit
@@ -8,10 +8,9 @@ records per-segment XLA cost/memory-analysis metrics (or an explicit
 growing cycle counter (the mid-run leg lives in tools/trace_demo.py,
 the endpoint contract here); ``pydcop trace merge`` of two
 concurrent-process traces yields one well-nested trace with distinct
-lanes; the bench sentinel passes on the repo's real history and fails
-on a synthetic 30% regression; histogram Prometheus output survives a
-promtool-style parser including ``+Inf``/``le``/escaping; and the
-metrics registry + tracer lose nothing under 8-thread concurrency.
+lanes; histogram Prometheus output survives a promtool-style parser
+including ``+Inf``/``le``/escaping; and the metrics registry + tracer
+lose nothing under 8-thread concurrency.
 """
 
 import json
@@ -57,9 +56,6 @@ from pydcop_tpu.observability.trace import (
 
 REPO = os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__))))
-sys.path.insert(0, os.path.join(REPO, "tools"))
-
-import bench_sentinel  # noqa: E402  (tools/ is not a package)
 
 
 @pytest.fixture(autouse=True)
@@ -137,10 +133,10 @@ class TestXlaCostAttribution:
                 assert entry["reason"]
 
     def test_flops_counted_per_loop_body_not_per_trip(self):
-        """bench.py treats XLA flops as per-cycle numbers because XLA
-        counts a while-loop body once; pin that invariant so a future
-        XLA that scales by trip count fails HERE, not silently in a
-        bench line."""
+        """The roofline's measured override reads XLA flops as
+        per-cycle numbers because XLA counts a while-loop body once;
+        pin that invariant so a future XLA that scales by trip count
+        fails HERE, not silently in a report."""
         profiler.enabled = True
         engine = _tiny_engine()
         for cycles in (8, 16):
@@ -155,8 +151,8 @@ class TestXlaCostAttribution:
         assert len(flops) == 2
         a, b = sorted(flops.values())
         assert a == pytest.approx(b, rel=0.01), (
-            "XLA flops now scale with trip count; bench.py's "
-            "per-cycle normalization must divide by cycles")
+            "XLA flops now scale with trip count; a per-cycle "
+            "normalization must divide by cycles")
 
     def test_unavailable_marker_on_analysis_failure(self, monkeypatch):
         profiler.enabled = True
@@ -203,7 +199,7 @@ class TestXlaCostAttribution:
         assert sum(v for _, v in metric.samples()) > 0
 
     def test_registry_untouched_without_active(self):
-        """profiler on + registry inactive (the bench.py mode): cost
+        """profiler on + registry inactive: cost
         entries flow through DeviceRunResult only — no key-labeled
         series leak into the shared registry for a later solve's
         .prom dump."""
@@ -312,6 +308,19 @@ class TestXlaCostAttribution:
         compile_dcop(dcop)
         compile_dcop(dcop)
         assert counter.value(outcome="hit") > before_hit
+
+    def test_device_fn_profile_label_is_stable(self):
+        from functools import partial
+
+        from pydcop_tpu.engine.runner import _fn_label
+
+        def run_solver(graph, max_cycles=10):
+            return graph
+
+        assert _fn_label(run_solver) == "run_solver"
+        label = _fn_label(partial(run_solver, max_cycles=99))
+        assert label == "run_solver"
+        assert "0x" not in label  # never a repr with an address
 
 
 # ------------------------------------------------------------------ #
@@ -910,21 +919,32 @@ class TestThreadSafety:
     def test_export_during_active_recording(self, tmp_path):
         """export_chrome while other threads record: the export is a
         consistent snapshot (valid JSON, well-formed events), no
-        crash, and recording continues unhindered.  Each recorder is
-        BOUNDED (an unbounded spin would grow the buffers faster than
-        the ever-larger exports can serialize them)."""
+        crash, and recording continues unhindered.  No recorder
+        stops before the first export is read back: past its quota
+        it waits on that event between spans (not a race against the
+        clock), and each is BOUNDED (an unbounded spin would grow the
+        buffers faster than the ever-larger exports can serialize
+        them)."""
         t = Tracer()
         t.enable()
-        started = threading.Event()
+        recording = threading.Semaphore(0)
+        exported = threading.Event()
         errors = []
         spans_per_thread = 2000
+        cap = 10 * spans_per_thread
+        counts = [0] * self.N_THREADS
 
         def recorder(i):
             try:
-                for _ in range(spans_per_thread):
+                n = 0
+                while n < spans_per_thread or not (
+                        n >= cap or exported.wait(0.001)):
                     with t.span(f"work{i}", "t", n=i):
                         t.instant("tick", "t")
-                    started.set()
+                    n += 1
+                    if n == 1:
+                        recording.release()
+                counts[i] = n
             except Exception as exc:  # noqa: BLE001
                 errors.append(exc)
 
@@ -932,19 +952,26 @@ class TestThreadSafety:
                    for i in range(self.N_THREADS)]
         for th in threads:
             th.start()
-        started.wait(10)
-        rounds = 0
-        while any(th.is_alive() for th in threads) and rounds < 5:
-            path = tmp_path / f"live{rounds}.json"
-            t.export_chrome(str(path))
-            events = load_trace_file(str(path))
-            for ev in events:
-                assert "name" in ev and "ts" in ev
-            rounds += 1
-        for th in threads:
-            th.join(timeout=30)
-        assert rounds >= 1, "recorders finished before any export"
+        try:
+            for _ in threads:
+                assert recording.acquire(timeout=30)
+            rounds = 0
+            while rounds < 5 and (
+                    rounds == 0
+                    or any(th.is_alive() for th in threads)):
+                path = tmp_path / f"live{rounds}.json"
+                t.export_chrome(str(path))
+                events = load_trace_file(str(path))
+                for ev in events:
+                    assert "name" in ev and "ts" in ev
+                rounds += 1
+                exported.set()
+        finally:
+            exported.set()
+            for th in threads:
+                th.join(timeout=60)
         assert not errors
+        assert min(counts) >= spans_per_thread
         t.disable()
         # The buffers survived concurrent export: the final export
         # holds every span from every worker lane.
@@ -953,221 +980,4 @@ class TestThreadSafety:
         events = load_trace_file(str(final))
         spans = [e for e in events
                  if e["name"].startswith("work")]
-        assert len(spans) == self.N_THREADS * spans_per_thread
-
-
-# ------------------------------------------------------------------ #
-# bench regression sentinel
-
-
-def _write_history(path, values, backend="cpu", start=1):
-    for i, v in enumerate(values, start):
-        with open(os.path.join(path, f"BENCH_r{i:02d}.json"),
-                  "w", encoding="utf-8") as f:
-            json.dump({"n": i, "parsed": {
-                "value": v, "backend": backend,
-                "unit": "cycles/s"}}, f)
-
-
-class TestBenchSentinel:
-    STEADY = [900.0, 860.0, 910.0, 880.0, 895.0, 905.0]
-
-    def test_passes_on_the_repo_root_without_history(self):
-        """The tree keeps no bench rounds (the driver's record is
-        PERF_LEDGER.jsonl): an empty history is a pass, not a
-        crash."""
-        report = bench_sentinel.run_check(REPO)
-        assert report["failed"] is False
-        assert report["series"] == {}
-        assert bench_sentinel.main(["--root", REPO]) == 0
-
-    def test_fails_on_synthetic_30pct_regression(self, tmp_path):
-        """The acceptance fixture: steady history, newest 30% down."""
-        _write_history(str(tmp_path), self.STEADY + [0.7 * 890.0])
-        report = bench_sentinel.run_check(str(tmp_path))
-        assert report["failed"] is True
-        assert report["series"]["cpu"]["verdict"] == "regressed"
-        assert bench_sentinel.main(["--root", str(tmp_path)]) == 1
-
-    def test_noise_within_mad_passes(self, tmp_path):
-        _write_history(str(tmp_path), self.STEADY + [850.0])
-        report = bench_sentinel.run_check(str(tmp_path))
-        assert report["failed"] is False
-        assert bench_sentinel.main(["--root", str(tmp_path)]) == 0
-
-    def test_backends_tracked_separately(self, tmp_path):
-        _write_history(str(tmp_path), self.STEADY, backend="cpu")
-        # A TPU series two orders of magnitude faster, also steady,
-        # appended AFTER the cpu rounds — per-backend split means
-        # neither series sees the other's values.
-        _write_history(str(tmp_path),
-                       [50_000.0, 52_000.0, 51_000.0, 50_500.0],
-                       backend="tpu", start=len(self.STEADY) + 1)
-        report = bench_sentinel.run_check(str(tmp_path))
-        assert report["failed"] is False
-        assert report["series"]["cpu"]["points"] == len(self.STEADY)
-        assert report["series"]["tpu"]["points"] == 4
-
-    def test_insufficient_history_never_fails(self, tmp_path):
-        _write_history(str(tmp_path), [900.0, 100.0])
-        report = bench_sentinel.run_check(str(tmp_path))
-        assert report["failed"] is False
-        assert report["series"]["cpu"]["verdict"] == "insufficient"
-
-    def test_unreadable_files_skipped_not_fatal(self, tmp_path):
-        _write_history(str(tmp_path), self.STEADY)
-        with open(os.path.join(str(tmp_path), "BENCH_r99.json"),
-                  "w", encoding="utf-8") as f:
-            f.write("{torn")
-        # Glob-matched but not a numbered round: ignored, not a crash.
-        with open(os.path.join(str(tmp_path), "BENCH_rerun.json"),
-                  "w", encoding="utf-8") as f:
-            f.write("{}")
-        report = bench_sentinel.run_check(str(tmp_path))
-        assert report["skipped"] == ["BENCH_r99.json"]
-        assert report["failed"] is False
-
-    @pytest.mark.parametrize("backend", ["tpu", "cpu"])
-    def test_only_numbered_rounds_form_the_series(self, tmp_path,
-                                                  backend):
-        """The history is the numbered rounds and nothing else: a
-        stray artifact beside them (the last-known-TPU file earlier
-        trees kept) is never read, so it can neither seed a series
-        nor be judged as 'the newest run'."""
-        rounds = [1000.0, 1050.0, 990.0, 1020.0]
-        _write_history(str(tmp_path), rounds, backend=backend)
-        with open(os.path.join(str(tmp_path), "BENCH_TPU_LAST.json"),
-                  "w", encoding="utf-8") as f:
-            json.dump({"value": 500.0, "backend": "tpu"}, f)
-        report = bench_sentinel.run_check(str(tmp_path))
-        assert list(report["series"]) == [backend]
-        assert report["series"][backend]["values"] == rounds
-        assert report["failed"] is False
-
-    def test_device_fn_profile_label_is_stable(self):
-        from functools import partial
-
-        from pydcop_tpu.engine.runner import _fn_label
-
-        def run_solver(graph, max_cycles=10):
-            return graph
-
-        assert _fn_label(run_solver) == "run_solver"
-        label = _fn_label(partial(run_solver, max_cycles=99))
-        assert label == "run_solver"
-        assert "0x" not in label  # never a repr with an address
-
-    def test_missing_backend_key_treated_as_cpu(self, tmp_path):
-        for i, v in enumerate(self.STEADY, 1):
-            with open(os.path.join(str(tmp_path),
-                                   f"BENCH_r{i:02d}.json"),
-                      "w", encoding="utf-8") as f:
-                json.dump({"n": i, "parsed": {"value": v}}, f)
-        report = bench_sentinel.run_check(str(tmp_path))
-        assert list(report["series"]) == ["cpu"]
-
-    def test_sparkline_shape(self):
-        line = bench_sentinel.sparkline([1.0, 2.0, 3.0, 2.0])
-        assert len(line) == 4
-        assert line[0] == "▁" and line[2] == "█"
-        assert bench_sentinel.sparkline([5.0, 5.0]) == "▄▄"
-
-    def test_json_output(self, tmp_path, capsys):
-        _write_history(str(tmp_path), self.STEADY)
-        rc = bench_sentinel.main(["--root", str(tmp_path), "--json"])
-        assert rc == 0
-        doc = json.loads(capsys.readouterr().out)
-        assert doc["series"]["cpu"]["verdict"] == "ok"
-        assert doc["series"]["cpu"]["values"] == self.STEADY
-
-
-def _write_serving_history(path, rounds):
-    """Rounds with the closed-loop serving families alongside the
-    compute headline — the population the host-shift guard pools.
-    Each round is a dict of parsed keys; ``value``/``backend`` are
-    filled in when absent."""
-    for i, parsed in enumerate(rounds, 1):
-        doc = {"value": 890.0, "backend": "cpu", **parsed}
-        with open(os.path.join(path, f"BENCH_r{i:02d}.json"),
-                  "w", encoding="utf-8") as f:
-            json.dump({"n": i, "parsed": doc}, f)
-
-
-class TestHostShiftGuard:
-    """Common-mode rejection for host-scheduler-bound serving legs
-    (ISSUE 19): a drop shared by the whole host-bound population —
-    including the envelope-off control arm — is a host-class change
-    and must not gate, while an isolated family drop (which cannot
-    move the population median) must still fail the sentinel."""
-
-    STEADY = {
-        "serve_problems_per_sec": 120.0,
-        "serve_mixed_problems_per_sec": 270.0,
-        "serve_mixed_baseline_problems_per_sec": 210.0,
-        "fleet_elastic_problems_per_sec": 8.0,
-    }
-
-    def _history(self, newest):
-        return [dict(self.STEADY) for _ in range(6)] + [newest]
-
-    def test_common_mode_drop_held_not_gated(self, tmp_path):
-        """Every host-bound series (and the control arm) at 55% of
-        its median: a host shift — reported, estimator recorded, but
-        ``failed`` stays False."""
-        newest = {k: 0.55 * v for k, v in self.STEADY.items()}
-        _write_serving_history(str(tmp_path), self._history(newest))
-        report = bench_sentinel.run_check(str(tmp_path))
-        assert report["failed"] is False
-        guard = report["host_shift"]
-        assert guard["fired"] is True
-        assert guard["estimator"] == pytest.approx(0.55, abs=0.01)
-        assert (report["series"]["serve_mixed:cpu"]["verdict"]
-                == "host-shift")
-        assert (report["series"]["serve_mixed:cpu"]["gating"]
-                is False)
-        assert any("host-shift guard" in line
-                   for line in report["lines"])
-        # The compute headline was steady and still judges normally.
-        assert report["series"]["cpu"]["verdict"] == "ok"
-        assert bench_sentinel.main(["--root", str(tmp_path)]) == 0
-
-    def test_isolated_drop_still_gates(self, tmp_path):
-        """Only serve_mixed collapses; the rest of the population
-        (control arm included) is steady, so the median ratio stays
-        ~1 and the regression gates exactly as before the guard."""
-        newest = dict(self.STEADY)
-        newest["serve_mixed_problems_per_sec"] = (
-            0.55 * self.STEADY["serve_mixed_problems_per_sec"])
-        _write_serving_history(str(tmp_path), self._history(newest))
-        report = bench_sentinel.run_check(str(tmp_path))
-        assert report["failed"] is True
-        assert report["host_shift"]["fired"] is False
-        assert (report["series"]["serve_mixed:cpu"]["verdict"]
-                == "regressed")
-        assert bench_sentinel.main(["--root", str(tmp_path)]) == 1
-
-    def test_compute_regression_gates_through_host_shift(
-            self, tmp_path):
-        """A genuine compute regression coinciding with a host shift
-        still fails: the headline family is not host-bound, so the
-        guard never holds it."""
-        newest = {k: 0.55 * v for k, v in self.STEADY.items()}
-        newest["value"] = 0.6 * 890.0
-        _write_serving_history(str(tmp_path), self._history(newest))
-        report = bench_sentinel.run_check(str(tmp_path))
-        assert report["failed"] is True
-        assert report["host_shift"]["fired"] is True
-        assert report["series"]["cpu"]["verdict"] == "regressed"
-
-    def test_control_arm_alone_never_fails(self, tmp_path):
-        """The control arm regressing by itself is host evidence, not
-        a PR regression — too few host-bound series for the guard to
-        conclude anything, and the control family never gates."""
-        rounds = [{"serve_mixed_baseline_problems_per_sec": 210.0}
-                  for _ in range(6)]
-        rounds.append({"serve_mixed_baseline_problems_per_sec": 80.0})
-        _write_serving_history(str(tmp_path), rounds)
-        report = bench_sentinel.run_check(str(tmp_path))
-        assert report["failed"] is False
-        assert (report["series"]["serve_mixed_baseline:cpu"]["gating"]
-                is False)
+        assert len(spans) == sum(counts)

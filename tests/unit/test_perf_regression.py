@@ -1,27 +1,13 @@
-"""Performance-regression gate for the flagship device kernels
-(maxsum superstep, dsa, mgm, dpop sweep).
+"""Semantics freezes for the flagship device kernels (maxsum
+superstep, dsa, mgm, dpop sweep).
 
-Motivation (round-3 verdict): the bench's absolute CPU cycles/s drifted
-927 -> 755 -> 665 across rounds.  Investigation showed the r1->r2 step
-was a real feature cost (exact-parity send-suppression landed between
-BENCH_r01 and r02) and the rest was machine load — the r1 tree re-run on
-the r4 machine measures the same as the r4 tree.  An absolute wall-clock
-budget would therefore false-alarm on load and miss nothing; instead
-each live kernel races a FROZEN copy of itself (golden_*.py) in the
-same process and must stay within its RATIO_TOL of it.  A slowdown
-beyond the tolerance fails here regardless of machine speed.
-
-The parity tests double as semantics freezes: each live kernel must
-produce its golden copy's exact seeded trajectory so "optimizations"
-cannot silently change semantics.
-
-Tolerance ratchet: maxsum's gate has a round of stability history
-(r4 -> r5) and runs at 1.25; the dsa/mgm/dpop gates are new this round
-and start at 1.35 — tighten them toward 1.2 once they too have a
-stable round behind them.
+Each live kernel must produce the exact seeded trajectory of a FROZEN
+copy of itself (golden_*.py, the reference implementations these tests
+compare against), so an "optimization" cannot silently change
+semantics.  How fast a kernel runs is read on the chip
+(``chipbench/``, ``kernel.superstep_us``), not raced here on a CPU.
 """
 
-import time
 from functools import partial
 
 import jax
@@ -35,9 +21,6 @@ from tests.unit import golden_maxsum_kernel as golden
 N_VARS = 2_000
 N_COLORS = 3
 CYCLES = 100
-RATIO_TOL = 1.25
-NEW_GATE_TOL = 1.35  # dsa/mgm/dpop: first round, no stability history
-REPEATS = 5
 
 
 @pytest.fixture(scope="module")
@@ -65,36 +48,6 @@ def problem():
             [variables[i], variables[j]], eq, f"c{k}"))
     graph, meta = compile_dcop(dcop, noise_level=0.01)
     return jax.device_put(graph)
-
-
-def _best_time(fn, graph):
-    from pydcop_tpu.engine.timing import sync, timed_call
-
-    sync(fn(graph))  # compile + warm (true completion, not a partial
-    #                  sync — engine/timing.py; on the CPU test
-    #                  backend the two are equivalent)
-    best = float("inf")
-    for _ in range(REPEATS):
-        _, elapsed = timed_call(fn, graph)
-        best = min(best, elapsed)
-    return best
-
-
-def test_superstep_not_slower_than_golden(problem):
-    from pydcop_tpu.ops import maxsum as ops
-
-    live = jax.jit(partial(
-        ops.run_maxsum, max_cycles=CYCLES, stop_on_convergence=False))
-    gold = jax.jit(partial(golden.run_maxsum, max_cycles=CYCLES))
-    t_live = _best_time(live, problem)
-    t_gold = _best_time(gold, problem)
-    ratio = t_live / t_gold
-    assert ratio <= RATIO_TOL, (
-        f"live superstep is {ratio:.2f}x the frozen r4 baseline "
-        f"({t_live*1e3:.2f} ms vs {t_gold*1e3:.2f} ms for {CYCLES} "
-        f"cycles) — a real kernel regression, not machine noise "
-        f"(both timed in this process)"
-    )
 
 
 def test_superstep_semantics_frozen(problem):
@@ -143,22 +96,6 @@ def hypergraph_problem():
     return jax.device_put(graph)
 
 
-def test_dsa_kernel_not_slower_than_golden(hypergraph_problem):
-    from pydcop_tpu.ops import dsa as ops
-
-    live = jax.jit(partial(
-        ops.run_dsa, max_cycles=CYCLES, variant="B", seed=3))
-    gold = jax.jit(partial(
-        golden_ls.run_dsa, max_cycles=CYCLES, variant="B", seed=3))
-    t_live = _best_time(live, hypergraph_problem)
-    t_gold = _best_time(gold, hypergraph_problem)
-    ratio = t_live / t_gold
-    assert ratio <= NEW_GATE_TOL, (
-        f"live dsa kernel is {ratio:.2f}x the frozen r5 baseline "
-        f"({t_live*1e3:.2f} ms vs {t_gold*1e3:.2f} ms)"
-    )
-
-
 def test_dsa_kernel_semantics_frozen(hypergraph_problem):
     from pydcop_tpu.ops import dsa as ops
 
@@ -175,25 +112,6 @@ def test_dsa_kernel_semantics_frozen(hypergraph_problem):
             err_msg=f"dsa variant {variant} trajectory changed",
         )
         assert float(c_live) == float(c_gold)
-
-
-def test_mgm_kernel_not_slower_than_golden(hypergraph_problem):
-    from pydcop_tpu.ops import mgm as ops
-
-    n = int(hypergraph_problem.var_costs.shape[0])
-    ranks = jax.numpy.arange(n, dtype=jax.numpy.float32)
-    live = jax.jit(partial(
-        ops.run_mgm, max_cycles=CYCLES, lexic_ranks=ranks, seed=3))
-    gold = jax.jit(partial(
-        golden_ls.run_mgm, max_cycles=CYCLES, lexic_ranks=ranks,
-        seed=3))
-    t_live = _best_time(live, hypergraph_problem)
-    t_gold = _best_time(gold, hypergraph_problem)
-    ratio = t_live / t_gold
-    assert ratio <= NEW_GATE_TOL, (
-        f"live mgm kernel is {ratio:.2f}x the frozen r5 baseline "
-        f"({t_live*1e3:.2f} ms vs {t_gold*1e3:.2f} ms)"
-    )
 
 
 def test_mgm_kernel_semantics_frozen(hypergraph_problem):
@@ -245,28 +163,6 @@ def dpop_tree():
             [variables[p], variables[i]],
             rng.random((N_COLORS, N_COLORS)).round(3), f"c{i}"))
     return build_computation_graph(dcop)
-
-
-def _best_time_host(fn, *args):
-    fn(*args)  # compile + warm the kernel caches
-    best = float("inf")
-    for _ in range(REPEATS):
-        t0 = time.perf_counter()
-        fn(*args)
-        best = min(best, time.perf_counter() - t0)
-    return best
-
-
-def test_dpop_sweep_not_slower_than_golden(dpop_tree):
-    from pydcop_tpu.ops import dpop as ops
-
-    t_live = _best_time_host(ops.solve_sweep, dpop_tree)
-    t_gold = _best_time_host(golden_dpop.solve_sweep, dpop_tree)
-    ratio = t_live / t_gold
-    assert ratio <= NEW_GATE_TOL, (
-        f"live dpop sweep is {ratio:.2f}x the frozen r5 baseline "
-        f"({t_live*1e3:.1f} ms vs {t_gold*1e3:.1f} ms end to end)"
-    )
 
 
 def test_dpop_sweep_semantics_frozen(dpop_tree):

@@ -920,94 +920,6 @@ class TestTraceFileErrors:
 
 
 # ------------------------------------------------------------------ #
-# bench-sentinel exemplar hygiene
-
-
-class TestSentinelExemplar:
-    def _sentinel(self):
-        import sys
-
-        sys.path.insert(0, os.path.join(os.path.dirname(
-            os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__)))), "tools"))
-        import bench_sentinel
-
-        return bench_sentinel
-
-    def _write(self, root, serve_values, exemplars):
-        for i, (sv, ex) in enumerate(zip(serve_values, exemplars)):
-            doc = {"n": i, "parsed": {
-                "value": 800.0, "backend": "cpu",
-                "serve_problems_per_sec": sv,
-                "exemplar_trace_id": ex,
-            }}
-            with open(os.path.join(
-                    root, f"BENCH_r{i:02d}.json"), "w") as f:
-                json.dump(doc, f)
-
-    def test_regression_line_names_the_exemplar_trace(self,
-                                                      tmp_path):
-        sentinel = self._sentinel()
-        d = str(tmp_path / "reg")
-        os.makedirs(d)
-        self._write(d, [50.0, 51.0, 49.0, 50.0, 10.0],
-                    [None, None, None, None, "deadbeef01"])
-        report = sentinel.run_check(d)
-        assert report["failed"]
-        assert report["series"]["serve:cpu"]["exemplar"] \
-            == "deadbeef01"
-        assert any("deadbeef01" in line
-                   and "trace query --request" in line
-                   for line in report["lines"]), report["lines"]
-
-    def test_regression_without_exemplar_prints_no_pointer(
-            self, tmp_path):
-        sentinel = self._sentinel()
-        d = str(tmp_path / "noex")
-        os.makedirs(d)
-        self._write(d, [50.0, 51.0, 49.0, 50.0, 10.0],
-                    [None] * 5)
-        report = sentinel.run_check(d)
-        assert report["failed"]
-        assert "exemplar" not in report["series"]["serve:cpu"]
-        assert not any("trace query" in line
-                       for line in report["lines"])
-
-    def test_non_serve_regression_never_claims_the_exemplar(
-            self, tmp_path):
-        """The exemplar is the SERVING leg's p99 trace — a headline-
-        bench regression must not point investigators at it."""
-        sentinel = self._sentinel()
-        d = str(tmp_path / "bench_reg")
-        os.makedirs(d)
-        for i, v in enumerate([800.0, 810.0, 790.0, 800.0, 100.0]):
-            doc = {"n": i, "parsed": {
-                "value": v, "backend": "cpu",
-                "serve_problems_per_sec": 50.0,
-                "exemplar_trace_id": "deadbeef01",
-            }}
-            with open(os.path.join(
-                    d, f"BENCH_r{i:02d}.json"), "w") as f:
-                json.dump(doc, f)
-        report = sentinel.run_check(d)
-        assert report["series"]["cpu"]["verdict"] == "regressed"
-        assert report["series"]["serve:cpu"]["verdict"] == "ok"
-        assert not any("trace query" in line
-                       for line in report["lines"])
-
-    def test_healthy_series_never_prints_exemplars(self, tmp_path):
-        sentinel = self._sentinel()
-        d = str(tmp_path / "ok")
-        os.makedirs(d)
-        self._write(d, [50.0, 51.0, 49.0, 50.0, 50.5],
-                    ["a1", "a2", "a3", "a4", "a5"])
-        report = sentinel.run_check(d)
-        assert not report["failed"]
-        assert not any("trace query" in line
-                       for line in report["lines"])
-
-
-# ------------------------------------------------------------------ #
 # convergence-health telemetry
 
 

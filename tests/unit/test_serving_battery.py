@@ -519,61 +519,6 @@ class TestHealthzBody:
 
 
 # ------------------------------------------------------------------ #
-# bench sentinel: serving metric tracked per backend
-
-
-class TestSentinelServeMetric:
-    def _write(self, path, rows):
-        import os
-
-        for i, row in enumerate(rows, 1):
-            with open(os.path.join(str(path),
-                                   f"BENCH_r{i:02d}.json"),
-                      "w", encoding="utf-8") as f:
-                json.dump({"n": i, "parsed": row}, f)
-
-    def test_serve_series_judged_separately(self, tmp_path):
-        import os
-        import sys
-
-        sys.path.insert(0, os.path.join(
-            os.path.dirname(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__)))), "tools"))
-        import bench_sentinel
-
-        steady = [900.0, 860.0, 910.0, 880.0, 895.0, 905.0]
-        serve = [50.0, 52.0, 51.0, 49.0, 50.0, 14.0]  # 70% down
-        self._write(tmp_path, [
-            {"value": v, "backend": "cpu",
-             "serve_problems_per_sec": s}
-            for v, s in zip(steady, serve)
-        ])
-        report = bench_sentinel.run_check(str(tmp_path))
-        # Headline series fine, serving series regressed: the serve
-        # metric is tracked (and can fail the gate) on its own.
-        assert report["series"]["cpu"]["verdict"] == "ok"
-        assert report["series"]["serve:cpu"]["verdict"] == "regressed"
-        assert report["failed"] is True
-        assert any("serve[cpu]" in line for line in report["lines"])
-
-    def test_history_without_serve_metric_unaffected(self, tmp_path):
-        import os
-        import sys
-
-        sys.path.insert(0, os.path.join(
-            os.path.dirname(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__)))), "tools"))
-        import bench_sentinel
-
-        steady = [900.0, 860.0, 910.0, 880.0]
-        self._write(tmp_path, [
-            {"value": v, "backend": "cpu"} for v in steady])
-        report = bench_sentinel.run_check(str(tmp_path))
-        assert report["failed"] is False
-        assert "serve:cpu" not in report["series"]
-
-
-# ------------------------------------------------------------------ #
 # concurrent-client soak
 
 

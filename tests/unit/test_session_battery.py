@@ -21,7 +21,7 @@
 - session-scoped tracing: ``pydcop trace query --request`` material —
   one well-nested tagged tree per session;
 - scenario replay (``pydcop solve --scenario`` machinery) over
-  generated factor scenarios, and the sentinel's session families.
+  generated factor scenarios.
 """
 
 import json
@@ -1230,83 +1230,3 @@ class TestScenarioReplay:
         out = replay_scenario(dcop, loaded, params={"noise": 0.0},
                               max_cycles=200)
         assert out["event_count"] == 5
-
-
-# ------------------------------------------------------------------ #
-# sentinel: session families
-
-
-class TestSessionSentinelFamilies:
-    def _sentinel(self):
-        import sys
-
-        sys.path.insert(0, os.path.join(
-            os.path.dirname(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__)))), "tools"))
-        import bench_sentinel
-
-        return bench_sentinel
-
-    def _write(self, root, ttr, eps):
-        for i, (t, e) in enumerate(zip(ttr, eps)):
-            doc = {"n": i, "parsed": {
-                "value": 800.0 + i, "backend": "cpu",
-                "session_time_to_recovered_cost_ms": t,
-                "session_events_per_sec": e,
-            }}
-            with open(os.path.join(
-                    root, f"BENCH_r{i:02d}.json"), "w") as f:
-                json.dump(doc, f)
-
-    def test_session_families_ok(self, tmp_path):
-        bench_sentinel = self._sentinel()
-        d = str(tmp_path / "ok")
-        os.makedirs(d)
-        self._write(d, [2.0, 2.1, 1.9, 2.0, 1.5],
-                    [80, 82, 78, 81, 90])
-        report = bench_sentinel.run_check(d)
-        assert report["series"]["session_recovery:cpu"]["verdict"] \
-            == "ok"
-        assert report["series"]["session_events:cpu"]["verdict"] \
-            == "ok"
-        assert not report["failed"]
-
-    def test_session_recovery_spike_regresses(self, tmp_path):
-        bench_sentinel = self._sentinel()
-        d = str(tmp_path / "bad")
-        os.makedirs(d)
-        self._write(d, [2.0, 2.1, 1.9, 2.0, 9.0],
-                    [80, 82, 78, 81, 80])
-        report = bench_sentinel.run_check(d)
-        assert report["series"]["session_recovery:cpu"]["verdict"] \
-            == "regressed"
-        assert report["failed"]
-        assert any("session_recovery[cpu]" in line
-                   and "ceiling" in line
-                   for line in report["lines"])
-
-    def test_session_throughput_drop_regresses(self, tmp_path):
-        bench_sentinel = self._sentinel()
-        d = str(tmp_path / "slow")
-        os.makedirs(d)
-        self._write(d, [2.0, 2.1, 1.9, 2.0, 2.0],
-                    [80, 82, 78, 81, 20])
-        report = bench_sentinel.run_check(d)
-        assert report["series"]["session_events:cpu"]["verdict"] \
-            == "regressed"
-        assert report["failed"]
-
-    def test_history_without_session_metrics_unaffected(
-            self, tmp_path):
-        bench_sentinel = self._sentinel()
-        d = str(tmp_path / "old")
-        os.makedirs(d)
-        for i in range(4):
-            doc = {"n": i, "parsed": {
-                "value": 800.0 + i, "backend": "cpu"}}
-            with open(os.path.join(d, f"BENCH_r{i:02d}.json"),
-                      "w") as f:
-                json.dump(doc, f)
-        report = bench_sentinel.run_check(d)
-        assert "session_recovery:cpu" not in report["series"]
-        assert "session_events:cpu" not in report["series"]

@@ -1,27 +1,20 @@
 """Battery for ISSUE 7: min-edge-cut partitioning, the partitioned
-shard_map engine's plumbing, per-shard trace lanes, and the sharded
-bench sentinel series.
+shard_map engine's plumbing and per-shard trace lanes.
 
 End-to-end sharded-vs-single parity lives in
 tests/api/test_sharded_parity.py; this battery covers the host-side
 pieces (partitioner invariants, cache, communication accounting,
-merge-lane separation, sentinel) plus kernel edge cases (mixed
+merge-lane separation) plus kernel edge cases (mixed
 arity, constraint-free graphs) that the api battery's problem
 generators don't reach.
 """
 
 import json
-import os
-import sys
 
 import numpy as np
 import pytest
 
 import jax
-
-REPO = os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__))))
-sys.path.insert(0, os.path.join(REPO, "tools"))
 
 from pydcop_tpu.dcop.dcop import DCOP
 from pydcop_tpu.dcop.objects import Domain, Variable
@@ -55,11 +48,15 @@ def _grid_scopes(side):
 
 
 def _grid_dcop(side=8, seed=0):
-    """Shared 4-neighbor grid-coloring builder (bench.build_grid_dcop
-    — the same instance family the bench and shard-smoke measure)."""
-    from bench import build_grid_dcop
+    """4-neighbor grid coloring with random integer tables (`pydcop
+    generate graph_coloring -g grid --soft`), as the shard-smoke gate
+    and the sharded parity tests build it."""
+    from pydcop_tpu.generators.graphcoloring import (
+        generate_graph_coloring,
+    )
 
-    return build_grid_dcop(side, seed=seed)
+    return generate_graph_coloring(
+        side * side, 3, "grid", soft=True, noagents=True, seed=seed)
 
 
 # ------------------------------ partitioner ------------------------- #
@@ -247,7 +244,8 @@ class TestPartitionedEngine:
         engine = build_engine(dcop, {"noise": 0.01}, shards=8)
         res = engine.run(max_cycles=30, stop_on_convergence=False)
         values = np.asarray([
-            res.assignment[f"v{i}"] for i in range(len(dcop.variables))
+            domain.index(res.assignment[name]) for name, domain
+            in zip(engine.meta.var_names, engine.meta.domains)
         ], np.int32)
         device_cost = float(engine._ops.assignment_constraint_cost(
             engine.graph, values))
@@ -371,53 +369,3 @@ class TestShardTraceLanes:
         span_tids = {e["tid"] for e in events
                      if e.get("name") in ("jit_compile", "engine_call")}
         assert span_tids and span_tids.isdisjoint(all_shard_tids)
-
-
-# ------------------------- bench sentinel series -------------------- #
-
-
-class TestShardedSentinel:
-    def _write_history(self, root, sharded_values):
-        for i, v in enumerate(sharded_values, start=1):
-            doc = {
-                "n": i,
-                "parsed": {
-                    "metric":
-                        "maxsum_cycles_per_sec_10kvar_graphcoloring",
-                    "value": 800.0 + i,
-                    "backend": "cpu",
-                    "maxsum_cycles_per_sec_sharded": v,
-                    "sharded_backend": "cpu",
-                },
-            }
-            with open(os.path.join(root, f"BENCH_r{i:02d}.json"),
-                      "w") as f:
-                json.dump(doc, f)
-
-    def test_sharded_series_ok(self, tmp_path):
-        from bench_sentinel import run_check
-
-        self._write_history(str(tmp_path), [700, 710, 695, 705, 702])
-        report = run_check(str(tmp_path))
-        assert not report["failed"]
-        assert "sharded:cpu" in report["series"]
-        assert report["series"]["sharded:cpu"]["verdict"] == "ok"
-        assert any(line.startswith("sharded[cpu]")
-                   for line in report["lines"])
-
-    def test_sharded_regression_flagged(self, tmp_path):
-        from bench_sentinel import run_check
-
-        self._write_history(str(tmp_path), [700, 710, 695, 705, 420])
-        report = run_check(str(tmp_path))
-        assert report["failed"]
-        assert report["series"]["sharded:cpu"]["verdict"] == "regressed"
-
-    def test_missing_sharded_values_skipped(self, tmp_path):
-        """Pre-PR-7 history rows carry no sharded key: the series
-        simply starts later, never crashes the sentinel."""
-        from bench_sentinel import run_check
-
-        self._write_history(str(tmp_path), [None, None, 700, 705, 702])
-        report = run_check(str(tmp_path))
-        assert report["series"]["sharded:cpu"]["points"] == 3

@@ -1,12 +1,11 @@
 """Chaos soak: a seeded scenario matrix asserting global invariants.
 
-The robustness analogue of ``make perf-smoke``: where the perf gate
-proves the hot path is *fast*, this gate proves the runtime *heals* —
-every scenario injects a distinct failure combination (message drop +
-duplicate + delay, network partition with healing, silent agent kill,
-engine guard trips, checkpoint corruption, serve-process crash with
-journal replay, poison requests in a batched bin, device loss
-mid-sharded-solve) and asserts the system-wide invariants that define
+This gate proves the runtime *heals* — every scenario injects a
+distinct failure combination (message drop + duplicate + delay,
+network partition with healing, silent agent kill, engine guard
+trips, checkpoint corruption, serve-process crash with journal
+replay, poison requests in a batched bin, device loss mid-sharded-
+solve) and asserts the system-wide invariants that define
 "self-healing":
 
 - **valid assignment** — every variable ends with a value from its

@@ -1,6 +1,6 @@
 """Serve-smoke gate: end-to-end proof of the solve service's batching.
 
-Part of ``make test`` (like ``make trace-demo`` / ``make perf-smoke``).
+Part of ``make test`` (like ``make trace-demo`` / ``make shard-smoke``).
 Starts the real service on port 0 and drives it over HTTP:
 
 1. **Coalescing + parity**: a concurrent burst of N same-structure

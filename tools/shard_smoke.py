@@ -1,6 +1,6 @@
 """Shard-smoke gate: the partitioned engine's claims, on CPU.
 
-Part of ``make test`` (like ``make chaos`` / ``make perf-smoke``):
+Part of ``make test`` (like ``make chaos`` / ``make serve-smoke``):
 quick, deterministic checks that the sharded superstep actually is
 what ISSUE 7 says it is —
 
@@ -58,15 +58,21 @@ def main() -> int:
         fail(f"only {len(jax.devices())} devices (forced-host flag "
              "not honored?)")
 
-    from bench import build_grid_dcop
     from pydcop_tpu.engine.compile import compile_dcop
     from pydcop_tpu.engine.runner import (
         MaxSumEngine,
         ShardedMaxSumEngine,
     )
     from pydcop_tpu.engine.sharding import make_mesh, shard_graph
+    from pydcop_tpu.generators.graphcoloring import (
+        generate_graph_coloring,
+    )
 
-    dcop = build_grid_dcop(GRID_SIDE)
+    # `pydcop generate graph_coloring -g grid --soft`: random integer
+    # tables on a 4-neighbor grid.
+    dcop = generate_graph_coloring(
+        GRID_SIDE * GRID_SIDE, 3, "grid", soft=True, noagents=True,
+        seed=0)
     graph, meta = compile_dcop(dcop, noise_level=0.01)
 
     single = MaxSumEngine(graph, meta)
