@@ -82,6 +82,7 @@ from pydcop_tpu.engine.runner import (
     DeviceRunResult,
     finish_jit_call,
     launch_jit_call,
+    shape_signature as _shape_signature,
     timed_jit_call,
 )
 from pydcop_tpu.observability import efficiency
@@ -296,13 +297,6 @@ def _batched_maxsum_solve(stacked, *, max_cycles, damping, damp_vars,
         return values, state.cycle, state.stable
 
     return jax.vmap(solve_one)(stacked)
-
-
-def _shape_signature(stacked: CompiledFactorGraph) -> tuple:
-    return (
-        (stacked.var_costs.shape,)
-        + tuple(b.costs.shape for b in stacked.buckets)
-    )
 
 
 # The rollup's per-structure cell label (ONE definition, shared with

@@ -90,9 +90,13 @@ class DeviceRunResult:
     it was separately measurable, else ``time_s`` itself.  Either
     way the two fields overlap — never sum them — and
     ``metrics['cold_start']`` is True on the first call of a
-    program.  Callers that need steady-state execution time
-    (benchmarks) warm the engine up with an identical call first; the
-    warm call has ``compile_time_s == 0``."""
+    program: for the whole-solve, segment and cost-trace programs of
+    a single-device or replicated-mesh MaxSum engine, the first call
+    in the process with those parameters and shapes, whichever
+    engine makes it (the programs are the process's; see
+    ``_process_program``).  Callers that need steady-state execution
+    time (benchmarks) make an identical call first; the warm call
+    has ``compile_time_s == 0``."""
 
     assignment: Dict[str, Any]
     cycles: int
@@ -121,15 +125,22 @@ def timed_jit_call(warm: set, key, fn, *args,
     executables all came off the disk cache, when compile is the
     retrieval wall; warm calls report (0, elapsed).
 
+    ``warm`` is the set of whoever owns ``fn``: the process's for a
+    process-level program (``_process_warm`` here, engine/batch.py's
+    ``_warm``), whose key then carries everything JAX keys the
+    program on — statics and the arrays' shapes — so that "first" is
+    the first dispatch of that program in the process by any engine;
+    an engine's own set for a program the engine built.
+
     What the first call really did comes from JAX's own counters
     (engine/aotcache.dispatch_compile) and names the span:
     ``jit_compile`` only when XLA compiled during the call, else
-    ``engine_call`` (a new engine whose program came from the disk
-    cache did not compile), with ``first`` / ``cache_loads`` /
-    ``xla_compiles`` in the args of a first call.  ``report``, when
-    given, is filled with the same and with ``compile_s``, the
-    seconds XLA compiled or loaded (0 when neither happened): the
-    whole-solve path reports that as its compile time.
+    ``engine_call`` (the first solve of a process whose program came
+    from the disk cache did not compile), with ``first`` /
+    ``cache_loads`` / ``xla_compiles`` in the args of a first call.
+    ``report``, when given, is filled with the same and with
+    ``compile_s``, the seconds XLA compiled or loaded (0 when neither
+    happened): the whole-solve path reports that as its compile time.
 
     Completion is forced with engine.timing.sync (a host fetch of
     the smallest output — see the timing module docstring).
@@ -144,9 +155,12 @@ def timed_jit_call(warm: set, key, fn, *args,
     # live arrays).
     entry = None
     before = None
+    # A process-level program may have been warmed before the profiler
+    # was turned on: its cost is captured on the first dispatch the
+    # profiler sees.
+    if profiler.enabled and (first or profiler.get(key) is None):
+        entry = profiler.capture(key, fn, args)
     if first:
-        if profiler.enabled:
-            entry = profiler.capture(key, fn, args)
         aotcache.install_listeners()
         before = aotcache.counters(thread=True)
     did = _WARM_DISPATCH
@@ -269,10 +283,13 @@ def _fn_label(fn) -> str:
 
 def _jit_program(name: str, fn, **jit_kwargs):
     """``jax.jit(fn)`` as a program called ``name``.  The solver
-    programs are ``functools.partial`` objects, which have no name of
-    their own, so JAX called every one of them ``jit__unknown``: in
-    the device trace's ``XLA Modules`` line, in the HLO the profiler
-    stores, in compile logs.  The name is also part of the
+    programs are ``functools.partial`` objects of an ops function
+    (over nothing for a process-level program, whose parameters are
+    static arguments; over an engine's parameters for a per-engine
+    one), which have no name of their own, so JAX called every one
+    of them ``jit__unknown``: in the device trace's ``XLA Modules``
+    line, in the HLO the profiler stores, in compile logs.  The name
+    is also part of the
     persistent compile cache's key, and HLO metadata is not: a
     change to a program that alters only its metadata (a
     ``jax.named_scope``) has to rename it here, or a shared cache
@@ -280,6 +297,81 @@ def _jit_program(name: str, fn, **jit_kwargs):
     lacks the new names."""
     fn.__name__ = name
     return jax.jit(fn, **jit_kwargs)
+
+
+def shape_signature(graph) -> tuple:
+    """What of a graph decides which compiled program runs it: the
+    dtype and shape of every array, ``None`` where an optional
+    aggregation array is absent.  Edge-major, lane-major and stacked
+    graphs alike (shared with engine/batch.py, whose warm keys it
+    also makes)."""
+    return tuple(
+        None if x is None
+        else f"{x.dtype.name}[{','.join(map(str, x.shape))}]"
+        for x in jax.tree_util.tree_leaves(
+            graph, is_leaf=lambda x: x is None))
+
+
+# The solver parameters a whole-solve, segment or cost-trace program
+# is specialised on, besides its cycle count: static arguments, so
+# JAX's own cache keys a program on them and on the avals and
+# shardings of the graph it is handed.
+_SOLVER_STATICS = ("damping", "damp_vars", "damp_factors", "stability",
+                   "stop_on_convergence", "prune")
+
+# The programs of the two ops modules (ops/maxsum.py,
+# ops/maxsum_lane.py) belong to the process, as engine/batch.py's
+# ``_batched_maxsum_solve`` does: nothing in them is an engine's own,
+# so a second engine over the same shapes and parameters dispatches
+# the program the first one traced, without tracing, lowering or
+# reading the disk cache again.  ``_process_warm`` is their
+# ``timed_jit_call`` warm set, of MaxSumEngine._program's keys.
+_process_programs: Dict[Any, Any] = {}
+_process_warm: set = set()
+
+
+def _process_program(name: str, fn, cycles_arg: str,
+                     donate_argnums: tuple = ()):
+    """The process's jitted ``fn`` (a function of an ops module) as
+    program ``name``, built on first use."""
+    key = (name, fn, donate_argnums)
+    program = _process_programs.get(key)
+    if program is None:
+        program = _process_programs.setdefault(key, _jit_program(
+            name, partial(fn),
+            static_argnames=(cycles_arg,) + _SOLVER_STATICS,
+            donate_argnums=donate_argnums))
+    return program
+
+
+def reset_process_programs():
+    """Forget the process-level programs and their warmth (what
+    ``jax.clear_caches()`` is to JAX): the next solve of any shape
+    traces, lowers and compiles or loads from the disk cache again,
+    as in a new process.  For tests, and for a long-lived process
+    that wants the memory of programs it no longer runs back;
+    otherwise a program is kept as long as JAX's jit cache keeps
+    it."""
+    _process_programs.clear()
+    _process_warm.clear()
+
+
+class _BoundProgram:
+    """A process-level program with an engine's parameters bound:
+    called and lowered with the arrays alone, as a per-engine
+    ``jax.jit(partial(...))`` is."""
+
+    __slots__ = ("program", "statics")
+
+    def __init__(self, program, statics: Dict[str, Any]):
+        self.program = program
+        self.statics = statics
+
+    def __call__(self, *args, **kwargs):
+        return self.program(*args, **kwargs, **self.statics)
+
+    def lower(self, *args, **kwargs):
+        return self.program.lower(*args, **kwargs, **self.statics)
 
 
 class _DecimationRun:
@@ -555,9 +647,8 @@ class MaxSumEngine:
         self.damp_vars = damping_nodes in ("vars", "both")
         self.damp_factors = damping_nodes in ("factors", "both")
         self.stability = stability
-        # Branch-and-bound message pruning (ops/maxsum.prune_tables):
-        # a per-engine constant, so the per-engine jit caches need no
-        # extra key term.  Pruning changes wall-clock, never values.
+        # Branch-and-bound message pruning (ops/maxsum.prune_tables).
+        # Pruning changes wall-clock, never values.
         self.prune = prune
         # Donate the state argument of the segment program: XLA then
         # writes each segment's output state into the input buffers
@@ -575,8 +666,25 @@ class MaxSumEngine:
         # partitioned engine tags its shard count here so trace
         # tooling can tell sharded segments apart).
         self._segment_span_args: Dict[str, Any] = {}
+        # The programs that close over this engine (guard, margin,
+        # decimation rounds) and, where ``_ops`` is not an ops module
+        # (ShardOps bakes in its mesh), the solver programs too.
         self._jitted: Dict[Any, Any] = {}
-        self._warm: set = set()
+        # What the code can observe decides: the solver programs of
+        # an ops module are the process's (``_process_program``), and
+        # so is their warmth; the rest of their key is then what else
+        # JAX keys them on — layout, mesh, the placed graph's arrays.
+        if self._ops in (maxsum_ops, lane_ops):
+            self._warm = _process_warm
+            mesh = self.mesh
+            self._placed = (
+                self.layout,
+                None if mesh is None
+                else tuple(d.id for d in mesh.devices.flat),
+                shape_signature(self.graph))
+        else:
+            self._warm = set()
+            self._placed = ()
 
     def _call(self, key, fn, *args, report=None):
         """See timed_jit_call (module level, shared with the dynamic
@@ -584,15 +692,17 @@ class MaxSumEngine:
         program's measured cost/memory analysis (or its explicit
         unavailable marker) is folded into ``extra_metrics`` so each
         DeviceRunResult carries ``metrics['xla_cost']`` keyed by cache
-        key.  The fold happens only on the COLD dispatch (the one the
-        capture rode in on) — warm dispatches skip the profiler
-        lock entirely."""
+        key.  The fold happens once per engine and key (a program may
+        be warm in the process when this engine first runs it); later
+        dispatches skip the profiler lock."""
         out = timed_jit_call(self._warm, key, fn, *args, report=report)
-        if profiler.enabled and out[1] > 0:
-            entry = profiler.get(key)
-            if entry is not None:
-                self.extra_metrics.setdefault(
-                    "xla_cost", {})[key_str(key)] = entry
+        if profiler.enabled:
+            skey = key_str(key)
+            if skey not in self.extra_metrics.get("xla_cost", ()):
+                entry = profiler.get(key)
+                if entry is not None:
+                    self.extra_metrics.setdefault(
+                        "xla_cost", {})[skey] = entry
         return out
 
     def init_state(self):
@@ -602,40 +712,54 @@ class MaxSumEngine:
         device placement)."""
         return self._ops.init_state(self.graph)
 
-    def _segment_key(self, extra_cycles: int,
-                     stop_on_convergence: bool):
-        """Cache key of one segment program.  Damping parameters are
-        part of the key: a recovery damping bump
-        (resilience/recovery.py) mid-run must compile a fresh program,
-        not silently reuse the one that baked in the old damping."""
-        return ("segment", extra_cycles, stop_on_convergence,
-                self.damping, self.damp_vars, self.damp_factors)
-
-    def _segment_fn(self, extra_cycles: int, stop_on_convergence: bool):
-        """Cached-jit ``run_maxsum_from`` for one K-cycle segment (the
-        checkpointed loop re-enters the solve with device state, the
-        warm-start primitive dynamic DCOPs already use).  With
-        ``donate=True`` (default) the state argument is donated, so
-        every segment reuses the previous segment's buffers in place
-        — the donated input is dead after the call; the loop only
-        ever touches the returned state."""
-        key = self._segment_key(extra_cycles, stop_on_convergence)
+    def _program(self, name: str, fn, stop_on_convergence: bool,
+                 donate_argnums: tuple = (), **cycles):
+        """``(key, program)`` of one solver program: ``fn`` (of
+        ``_ops``) specialised on its cycle count (``max_cycles=`` or
+        ``extra_cycles=``) and this engine's parameters, called with
+        the arrays alone.  ``key`` is its ``timed_jit_call`` key.
+        The parameters are read now, not at construction: after a
+        recovery damping bump (resilience/recovery.py) the next
+        segment is another key and another program, never the one
+        specialised on the old damping."""
+        statics = dict(
+            cycles,
+            damping=self.damping,
+            damp_vars=self.damp_vars,
+            damp_factors=self.damp_factors,
+            stability=self.stability,
+            stop_on_convergence=stop_on_convergence,
+            prune=self.prune,
+        )
+        key = (name, tuple(sorted(statics.items())),
+               donate_argnums) + self._placed
+        if self._warm is _process_warm:
+            (cycles_arg,) = cycles
+            return key, _BoundProgram(
+                _process_program(name, fn, cycles_arg, donate_argnums),
+                statics)
         if key not in self._jitted:
             self._jitted[key] = _jit_program(
-                "maxsum_segment",
-                partial(
-                    self._ops.run_maxsum_from,
-                    extra_cycles=extra_cycles,
-                    damping=self.damping,
-                    damp_vars=self.damp_vars,
-                    damp_factors=self.damp_factors,
-                    stability=self.stability,
-                    stop_on_convergence=stop_on_convergence,
-                    prune=self.prune,
-                ),
-                donate_argnums=(1,) if self.donate else (),
-            )
-        return self._jitted[key]
+                name, partial(fn, **statics),
+                donate_argnums=donate_argnums)
+        return key, self._jitted[key]
+
+    def _segment(self, extra_cycles: int, stop_on_convergence: bool):
+        """``(key, program)`` of ``run_maxsum_from`` for one K-cycle
+        segment (the checkpointed loop re-enters the solve with
+        device state, the warm-start primitive dynamic DCOPs already
+        use).  With ``donate=True`` (default) the state argument is
+        donated, so every segment reuses the previous segment's
+        buffers in place — the donated input is dead after the call;
+        the loop only ever touches the returned state."""
+        return self._program(
+            "maxsum_segment", self._ops.run_maxsum_from,
+            stop_on_convergence,
+            donate_argnums=(1,) if self.donate else (),
+            extra_cycles=extra_cycles)
+
+    def _segment_fn(self, extra_cycles: int, stop_on_convergence: bool):
+        return self._segment(extra_cycles, stop_on_convergence)[1]
 
     def _guard_fn(self, with_cost: bool = True):
         """Cached-jit segment-boundary guard: NaN/Inf scan over every
@@ -820,8 +944,7 @@ class MaxSumEngine:
                 # value selection: a zero-extra segment computes it
                 # without stepping.
                 extra = min(every, max(max_cycles - cycle, 0))
-                fn = self._segment_fn(extra, stop_on_convergence)
-                seg_key = self._segment_key(extra, stop_on_convergence)
+                seg_key, fn = self._segment(extra, stop_on_convergence)
                 if tracer.active:
                     with tracer.span("engine_segment", "engine",
                                      segment=segments,
@@ -943,11 +1066,9 @@ class MaxSumEngine:
             # A zero-extra segment computes the selection without
             # stepping (the same trick the resume-at-budget path
             # uses).
-            fn = self._segment_fn(0, stop_on_convergence)
+            seg_key, fn = self._segment(0, stop_on_convergence)
             (state, values), c_s, _ = self._call(
-                self._segment_key(0, stop_on_convergence), fn,
-                graph, state,
-            )
+                seg_key, fn, graph, state)
             compile_s += c_s
         total = time.perf_counter() - t0
         values_host, cycle, stable = jax.device_get(
@@ -998,23 +1119,14 @@ class MaxSumEngine:
             self._jitted[key] = jax.jit(margin_of)
         return self._jitted[key]
 
+    def _solve(self, max_cycles: int, stop_on_convergence: bool):
+        """``(key, program)`` of the whole solve, ``run_maxsum``."""
+        return self._program(
+            "maxsum_solve", self._ops.run_maxsum, stop_on_convergence,
+            max_cycles=max_cycles)
+
     def _fn(self, max_cycles: int, stop_on_convergence: bool):
-        key = (max_cycles, stop_on_convergence)
-        if key not in self._jitted:
-            self._jitted[key] = _jit_program(
-                "maxsum_solve",
-                partial(
-                    self._ops.run_maxsum,
-                    max_cycles=max_cycles,
-                    damping=self.damping,
-                    damp_vars=self.damp_vars,
-                    damp_factors=self.damp_factors,
-                    stability=self.stability,
-                    stop_on_convergence=stop_on_convergence,
-                    prune=self.prune,
-                )
-            )
-        return self._jitted[key]
+        return self._solve(max_cycles, stop_on_convergence)[1]
 
     def run_trace(self, max_cycles: int,
                   stop_on_convergence: bool = True
@@ -1026,28 +1138,17 @@ class MaxSumEngine:
         :meth:`run`: the loop exits at the fixpoint, the cycle count
         agrees with an untraced solve, and the curve's tail holds the
         final cost (still a valid anytime record at full length)."""
-        key = ("trace", max_cycles, stop_on_convergence)
-        if key not in self._jitted:
-            base = self.meta.var_base_costs
-            self._jitted[key] = _jit_program(
-                "maxsum_cost_trace",
-                partial(
-                    self._ops.run_maxsum_trace,
-                    max_cycles=max_cycles,
-                    damping=self.damping,
-                    damp_vars=self.damp_vars,
-                    damp_factors=self.damp_factors,
-                    stability=self.stability,
-                    var_base_costs=(
-                        None if base is None else jnp.asarray(base)
-                    ),
-                    stop_on_convergence=stop_on_convergence,
-                    prune=self.prune,
-                )
-            )
-        fn = self._jitted[key]
+        key, fn = self._program(
+            "maxsum_cost_trace", self._ops.run_maxsum_trace,
+            stop_on_convergence, max_cycles=max_cycles)
+        # An argument of the program, not a constant closed over: the
+        # program is then the same for every problem of these shapes.
+        base = self.meta.var_base_costs
+        if base is not None:
+            base = jnp.asarray(base)
         (state, values, costs), compile_s, run_s = self._call(
-            key, fn, self.graph)
+            key + shape_signature(base),
+            partial(fn, var_base_costs=base), self.graph)
         values, cycle, stable, costs = jax.device_get(
             (values, state.cycle, state.stable, costs)
         )
@@ -1192,14 +1293,14 @@ class MaxSumEngine:
 
     def run(self, max_cycles: int = 1000,
             stop_on_convergence: bool = True) -> DeviceRunResult:
-        """Steady-state ``time_s`` requires a prior warmup call with
-        the same (max_cycles, stop_on_convergence): a first call's
-        ``time_s`` holds trace + compile (or cache load) + run.  Its
-        ``compile_time_s`` is the seconds XLA compiled or loaded the
-        program from the disk cache, from the counters (0 when
-        neither happened), not a copy of ``time_s``."""
-        key = (max_cycles, stop_on_convergence)
-        fn = self._fn(max_cycles, stop_on_convergence)
+        """Steady-state ``time_s`` requires a prior call, by any
+        engine of this process, with the same parameters over a graph
+        of the same shapes: a first call's ``time_s`` holds trace +
+        compile (or cache load) + run.  Its ``compile_time_s`` is the
+        seconds XLA compiled or loaded the program from the disk
+        cache, from the counters (0 when neither happened), not a
+        copy of ``time_s``."""
+        key, fn = self._solve(max_cycles, stop_on_convergence)
         did: Dict[str, Any] = {}
         (state, values), _, run_s = self._call(
             key, fn, self.graph, report=did)

@@ -229,7 +229,9 @@ class TestTimingConvention:
     (engine/runner.py docstring): a first call has cold_start=True
     and its compile_time_s is what XLA spent compiling (from JAX's
     counters), a part of time_s and never a copy of it; warm calls
-    have compile_time_s=0."""
+    have compile_time_s=0.  "First" is first in the process, by
+    any engine: the programs are the process's, and every test
+    starts with none of them warm (tests/conftest.py)."""
 
     def _engine(self):
         from pydcop_tpu.dcop.objects import Domain, Variable

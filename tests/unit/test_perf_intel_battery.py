@@ -119,7 +119,7 @@ class TestXlaCostAttribution:
             stop_on_convergence=False)
         xla = res.metrics.get("xla_cost")
         assert xla, "no xla_cost in DeviceRunResult.metrics"
-        seg_keys = [k for k in xla if k.startswith("('segment'")]
+        seg_keys = [k for k in xla if k.startswith("('maxsum_segment'")]
         assert seg_keys
         for k in seg_keys:
             entry = xla[k]
@@ -146,7 +146,7 @@ class TestXlaCostAttribution:
         entries = profiler.snapshot()
         flops = {
             k: v["flops"] for k, v in entries.items()
-            if k.startswith("('segment'") and v.get("available")
+            if k.startswith("('maxsum_segment'") and v.get("available")
         }
         assert len(flops) == 2
         a, b = sorted(flops.values())

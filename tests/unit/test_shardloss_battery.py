@@ -219,8 +219,7 @@ class TestRepartitionStateRemap:
         engine.run(max_cycles=8)
         state = engine.init_state()
         (state, _), _, _ = engine._call(
-            engine._segment_key(8, False),
-            engine._segment_fn(8, False), engine.graph, state)
+            *engine._segment(8, False), engine.graph, state)
         snap = jax.tree_util.tree_map(lambda x: x, state)
         new_state = engine.repartition_after_loss(2, snap)
         assert engine.mesh.size == 3
