@@ -466,9 +466,13 @@ def phase_four_chips(seed: int) -> None:
         out.update(variables=len(dcop.variables),
                    constraints=len(dcop.constraints))
     results = {}
-    for name, kwargs in (("single", {}), ("n_devices_4",
-                                          {"n_devices": 4}),
-                         ("shards_4", {"shards": 4})):
+    # The mesh and the partitioned engine are edge-major: the parity
+    # rule holds them to the single-device solve of that layout (an
+    # unset layout would run the single device lane-major).
+    for name, kwargs in (
+            ("single", {"algo_params": {"layout": "edge"}}),
+            ("n_devices_4", {"n_devices": 4}),
+            ("shards_4", {"shards": 4})):
         with Phase(f"solve_100k_maxsum_{name}") as out:
             res = api.solve(dcop, "maxsum", max_cycles=BIG_CYCLES,
                             **kwargs)

@@ -38,6 +38,7 @@ from pydcop_tpu.computations_graph import factor_graph as fg
 from pydcop_tpu.dcop.dcop import DCOP
 from pydcop_tpu.engine.compile import compile_dcop, validated_aggregation
 from pydcop_tpu.engine.runner import DeviceRunResult, MaxSumEngine
+from pydcop_tpu.ops import maxsum as maxsum_ops
 
 GRAPH_TYPE = "factor_graph"
 
@@ -108,11 +109,13 @@ algo_params = [
     ),
     # Message-array layout (device path).  "edge" keeps messages as
     # [F, arity, D] (domain minor); "lane" transposes to [D, arity, F]
-    # — factors on the TPU lane axis — the HBM-regime candidate, not
-    # yet decided on the chip (ROADMAP.md Queue 3 "Two layouts"; see
-    # ops/maxsum_lane.py).
-    # Single-device and scatter-aggregation only.
-    AlgoParameterDef("layout", "str", ["edge", "lane"], "edge"),
+    # — factors on the TPU lane axis (ops/maxsum_lane.py) — so no
+    # buffer is padded from D to the 128 lanes.  Unset, the code
+    # selects (``select_layout``): lane for a plain single-device
+    # scatter solve, edge wherever a feature indexes the edge-major
+    # arrays.  A value given here is honoured as given; "lane" is
+    # single-device and scatter-aggregation only.
+    AlgoParameterDef("layout", "str", ["edge", "lane"], None),
 ]
 
 
@@ -182,12 +185,50 @@ def decimation_plan_from_params(params: dict):
     )
 
 
+def select_layout(params: dict, mesh=None,
+                  n_devices: Optional[int] = None,
+                  shards: Optional[int] = None,
+                  whole_solve: bool = False):
+    """``(layout, source)`` of a MaxSum solve: the ``layout`` param
+    as given (source ``"param"``), else what the code selects
+    (``"selected"``) from what it is handed — ``"lane"`` when the
+    solve is the plain whole-solve program of one device with the
+    scatter aggregation, ``"edge"`` wherever something reads the
+    edge-major arrays: a mesh or a partition (``shard_graph`` and
+    ``ShardOps`` shard edge-major rows), the sorted / ell / auto
+    aggregations, ``prune``, decimation, the Pallas kernel, and the
+    segmented loop (``whole_solve=False``: its checkpoints restore
+    into an edge-major state and ``EngineProbe`` reads the edge
+    graph)."""
+    asked = params.get("layout")
+    if asked is not None:
+        return asked, "param"
+    lane = (
+        whole_solve
+        and (mesh is None or mesh.size <= 1)
+        and (n_devices or 1) <= 1
+        and (shards or 1) <= 1
+        and params.get("aggregation", "scatter") == "scatter"
+        and not params.get("prune", False)
+        and decimation_plan_from_params(params) is None
+        and not maxsum_ops.pallas_requested()
+    )
+    return ("lane" if lane else "edge"), "selected"
+
+
 def build_engine(dcop: DCOP, params: dict, mesh=None,
                  n_devices: Optional[int] = None,
-                 shards: Optional[int] = None) -> MaxSumEngine:
+                 shards: Optional[int] = None,
+                 whole_solve: bool = False) -> MaxSumEngine:
     """Compile + construct the engine from validated algo params — the
     single place the parameter->engine wiring lives (solve_on_device
     and the CLI's device-mode trace reconstruction both use it).
+
+    ``whole_solve=True`` says the caller only runs the engine's
+    whole-solve programs (``run`` / ``run_trace``), which lets an
+    unset ``layout`` resolve to lane-major (:func:`select_layout`);
+    every result's metrics say what ran (``layout``,
+    ``layout_source``).
 
     ``aggregation='auto'`` compiles with scatter (the universally
     valid baseline), measures the candidate strategies on the actual
@@ -204,12 +245,15 @@ def build_engine(dcop: DCOP, params: dict, mesh=None,
     (engine/sharding.py; docs/sharding.md).  Mutually exclusive with
     ``mesh``/``n_devices``; partition statistics and communication
     accounting land in every result's ``metrics``."""
+    layout, layout_source = select_layout(
+        params, mesh=mesh, n_devices=n_devices, shards=shards,
+        whole_solve=whole_solve)
     if shards is not None and shards > 1:
         if mesh is not None or n_devices:
             raise ValueError(
                 "shards= (partitioned engine) and mesh=/n_devices= "
                 "(replicated sharding) are mutually exclusive")
-        if params.get("layout", "edge") == "lane":
+        if layout == "lane":
             raise ValueError(
                 "layout='lane' is single-device; the partitioned "
                 "engine uses the edge layout")
@@ -228,7 +272,7 @@ def build_engine(dcop: DCOP, params: dict, mesh=None,
             dcop, noise_level=params.get("noise", 0.01),
             aggregation=aggregation,
         )
-        return ShardedMaxSumEngine(
+        engine = ShardedMaxSumEngine(
             graph, meta,
             mesh=partitioned_mesh(shards),
             damping=params.get("damping", 0.5),
@@ -236,6 +280,8 @@ def build_engine(dcop: DCOP, params: dict, mesh=None,
             stability=params.get("stability", STABILITY_COEFF),
             prune=bool(params.get("prune", False)),
         )
+        engine.extra_metrics["layout_source"] = layout_source
+        return engine
     pad_to = 1
     if mesh is not None:
         pad_to = mesh.size
@@ -254,7 +300,7 @@ def build_engine(dcop: DCOP, params: dict, mesh=None,
         agg_info = {"aggregation": "scatter",
                     "aggregation_source": "mesh"}
     if params.get("aggregation") == "auto" and agg_info is None \
-            and params.get("layout", "edge") == "lane":
+            and layout == "lane":
         # The lane layout carries its own scatter aggregation;
         # nothing to tune.
         agg_info = {"aggregation": "scatter",
@@ -293,9 +339,10 @@ def build_engine(dcop: DCOP, params: dict, mesh=None,
         damping_nodes=params.get("damping_nodes", "both"),
         stability=params.get("stability", STABILITY_COEFF),
         mesh=mesh, n_devices=n_devices,
-        layout=params.get("layout", "edge"),
+        layout=layout,
         prune=bool(params.get("prune", False)),
     )
+    engine.extra_metrics["layout_source"] = layout_source
     if agg_info is not None:
         engine.extra_metrics.update(agg_info)
     return engine
@@ -309,8 +356,11 @@ def solve_on_device(dcop: DCOP, algo_def: AlgorithmDef,
                     warmup: bool = False, **_) -> DeviceRunResult:
     """Batched BSP MaxSum on TPU/CPU devices."""
     params = algo_def.params
+    # The plain path: everything below runs whole-solve programs,
+    # except decimation, which select_layout reads from the params.
     engine = build_engine(dcop, params, mesh=mesh,
-                          n_devices=n_devices, shards=shards)
+                          n_devices=n_devices, shards=shards,
+                          whole_solve=True)
     plan = decimation_plan_from_params(params)
     if plan is not None:
         # Decimation is the SEGMENTED mode: clamping happens at the

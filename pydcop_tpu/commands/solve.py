@@ -345,6 +345,11 @@ def run_cmd(args) -> int:
             "backend": "device",
             **_platform_keys(),
         }
+        # A MaxSum engine says which message layout ran and whether
+        # the code chose it ("selected") or a parameter did.
+        for key in ("layout", "layout_source"):
+            if key in res["metrics"]:
+                result[key] = res["metrics"][key]
         # Device-mode cycle metrics: the whole solve is one XLA
         # program, so per-cycle rows come from a cost-trace run
         # (MaxSumEngine.run_trace) written post-hoc with the same CSV
@@ -359,9 +364,13 @@ def run_cmd(args) -> int:
             from pydcop_tpu.algorithms.maxsum import build_engine
             from pydcop_tpu.commands.metrics_io import add_csvline
 
+            # The layout the solve reports it ran, named: the
+            # reconstruction then follows the solve's own trajectory
+            # whichever path selected it.
             trace_res = build_engine(
-                dcop, algo_def.params, n_devices=args.n_devices,
-                shards=args.shards,
+                dcop,
+                dict(algo_def.params, layout=res["metrics"]["layout"]),
+                n_devices=args.n_devices, shards=args.shards,
             ).run_trace(max_cycles=max(res["cycles"], 1))
             for i, cost in enumerate(
                     trace_res.metrics["cost_trace"]):

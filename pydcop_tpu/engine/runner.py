@@ -112,7 +112,8 @@ _WARM_DISPATCH = {"first": False, "xla_compiles": 0, "cache_loads": 0,
 
 
 def timed_jit_call(warm: set, key, fn, *args,
-                   report: Optional[Dict[str, Any]] = None):
+                   report: Optional[Dict[str, Any]] = None,
+                   span_args: Optional[Dict[str, Any]] = None):
     """Execute a cached-jit function, splitting compile from run time.
 
     Plain jit dispatch, NOT ``fn.lower(...).compile()``: the AOT
@@ -141,6 +142,8 @@ def timed_jit_call(warm: set, key, fn, *args,
     ``report``, when given, is filled with the same and with
     ``compile_s``, the seconds XLA compiled or loaded (0 when neither
     happened): the whole-solve path reports that as its compile time.
+    ``span_args`` are further args of the span (a MaxSum engine's
+    ``layout``).
 
     Completion is forced with engine.timing.sync (a host fetch of
     the smallest output — see the timing module docstring).
@@ -172,7 +175,8 @@ def timed_jit_call(warm: set, key, fn, *args,
     # the redundant per-segment event would eat the ring.
     span = NOOP_SPAN
     if tracer.enabled or (first and tracer.active):
-        span = tracer.span("engine_call", "engine", key=str(key))
+        span = tracer.span("engine_call", "engine", key=str(key),
+                           **(span_args or {}))
     with span:
         out = sync(fn(*args))
         elapsed = time.perf_counter() - t0
@@ -660,8 +664,12 @@ class MaxSumEngine:
         # re-run from one (the A/B tests do).
         self.donate = donate
         # Per-engine annotations (e.g. the aggregation autotuner's
-        # decision) merged into every DeviceRunResult.metrics.
-        self.extra_metrics: Dict[str, Any] = {}
+        # decision) merged into every DeviceRunResult.metrics: the
+        # message layout that ran, and whether a caller named it
+        # ("param") or algorithms/maxsum.select_layout chose it
+        # ("selected": build_engine overwrites the source).
+        self.extra_metrics: Dict[str, Any] = {
+            "layout": self.layout, "layout_source": "param"}
         # Extra args stamped onto every engine_segment span (the
         # partitioned engine tags its shard count here so trace
         # tooling can tell sharded segments apart).
@@ -695,7 +703,8 @@ class MaxSumEngine:
         key.  The fold happens once per engine and key (a program may
         be warm in the process when this engine first runs it); later
         dispatches skip the profiler lock."""
-        out = timed_jit_call(self._warm, key, fn, *args, report=report)
+        out = timed_jit_call(self._warm, key, fn, *args, report=report,
+                             span_args={"layout": self.layout})
         if profiler.enabled:
             skey = key_str(key)
             if skey not in self.extra_metrics.get("xla_cost", ()):

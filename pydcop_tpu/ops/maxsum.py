@@ -168,6 +168,12 @@ def _use_pallas() -> bool:
     return True
 
 
+def pallas_requested() -> bool:
+    """Whether PYDCOP_PALLAS_MAXSUM=1 was set at import: the engine
+    wiring keeps such a solve edge-major, the kernel's layout."""
+    return _PALLAS_FLAG
+
+
 def refuse_pallas(where: str) -> None:
     """Called by an engine whose buckets cannot feed the kernel: a
     bucket sharded over a mesh would have to be gathered whole per

@@ -1,14 +1,19 @@
 """Lane-major MaxSum superstep: factors on the TPU lane axis.
 
-The default kernels (ops/maxsum.py) keep messages as ``[F, arity, D]``
+The edge-major kernels (ops/maxsum.py) keep messages as ``[F, arity, D]``
 — domain values on the minor axis.  DCOP domains are tiny (D=3..8) so
 that layout leaves 120+ of the 128 TPU lanes idle in every vector op,
 and past the size that fits fast memory (~100k vars) the
 scatter/gather traffic is issued in D-element slivers.  This module
-is the full-superstep version of the transposed layout (not yet
-measured against edge-major on the chip: ROADMAP.md Queue 3
-"Two layouts"), selectable with the maxsum ``layout="lane"`` algo
-param (engine/runner.MaxSumEngine).
+is the full-superstep version of the transposed layout.  It is what a
+plain single-device solve runs (``algorithms/maxsum.select_layout``;
+the ``layout`` algo param names either layout outright).  On the chip
+(TPU v5e, 10k variables / 15k binary factors, PERF.md section 6
+"PR 36") a superstep takes 403 us against the edge-major 1 203: the
+edge-major ``[edges, 3]`` buffers are padded 43x to the 128-lane
+minor axis and relaid out by three copies a superstep; here the
+factor and variable updates are 2 + 6 us and what is left is the
+scatter-add (224 us) and the gathers of ``var_to_factor`` (169 us).
 
 Layout (one bucket of arity ``a``, F factors, padded domain D):
 

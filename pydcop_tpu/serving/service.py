@@ -214,9 +214,13 @@ class SolveService:
     request pruning — an edge-major-only kernel) route through
     lane packing instead (engine/batch.run_lane_packed): a disjoint
     union with no per-member shape padding at all.  Results stay
-    bit-identical to solo ``api.solve`` either way (mask-padded lanes
-    and union members compute exactly the solo messages — battery- and
-    smoke-asserted); ``envelope_packing=False`` restores the old
+    bit-identical to solo ``api.solve`` of the same message layout
+    either way (``layout: edge`` for stacked and envelope dispatches,
+    ``lane`` for lane-packed ones: mask-padded lanes and union
+    members compute exactly the solo messages — battery- and
+    smoke-asserted; an unset ``layout`` lets a solo solve run
+    lane-major, which sums each variable's messages in another
+    order); ``envelope_packing=False`` restores the old
     solo-singleton behavior.
 
     ``journal_dir`` enables the durable request journal

@@ -88,9 +88,18 @@ def _small_dcop(n_vars=8, n_cons=12, d=3, seed=2):
                            seed=seed)
 
 
+# The MaxSum family's mesh and partitioned engines are edge-major:
+# their single-device side names that layout (unset, a plain
+# single-device solve runs lane-major, another order of one sum).
+MAXSUM_FAMILY = ("maxsum", "amaxsum", "maxsum_dynamic")
+EDGE = {"layout": "edge"}
+
+
 def _pair(dcop, algo, max_cycles=30, algo_params=None):
+    single_params = (dict(algo_params or {}, **EDGE)
+                     if algo in MAXSUM_FAMILY else algo_params)
     single = solve(dcop, algo, backend="device", max_cycles=max_cycles,
-                   algo_params=algo_params)
+                   algo_params=single_params)
     sharded = solve(dcop, algo, backend="device",
                     max_cycles=max_cycles, n_devices=N_DEVICES,
                     algo_params=algo_params)
@@ -167,7 +176,8 @@ def test_partitioned_assignment_parity(topo, shards):
     cuts) and trees (quiescent fixpoint)."""
     dcop = {"grid": _grid_dcop, "loopy": _loopy_int_dcop,
             "tree": _tree_dcop}[topo]()
-    single = solve(dcop, "maxsum", backend="device", max_cycles=60)
+    single = solve(dcop, "maxsum", backend="device", max_cycles=60,
+                   algo_params=EDGE)
     sharded = solve(dcop, "maxsum", backend="device", max_cycles=60,
                     shards=shards)
     assert sharded.assignment == single.assignment, (
@@ -213,7 +223,8 @@ def test_device_ladder_parity(algo, params, n):
     identical assignments and costs."""
     dcop = _grid_dcop()
     single = solve(dcop, algo, backend="device", max_cycles=30,
-                   algo_params=params)
+                   algo_params=dict(params, **EDGE)
+                   if algo == "maxsum" else params)
     kwargs = ({"shards": n} if algo == "maxsum"
               else {"n_devices": n})
     sharded = solve(dcop, algo, backend="device", max_cycles=30,

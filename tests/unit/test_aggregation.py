@@ -65,7 +65,9 @@ def test_full_solve_same_assignment(strategy):
     from pydcop_tpu.api import solve
 
     dcop = _coloring(n_vars=150, seed=9)
-    base = solve(dcop, "maxsum", max_cycles=60)
+    # The strategies are edge-major arrays: their scatter twin too.
+    base = solve(dcop, "maxsum", max_cycles=60,
+                 algo_params={"layout": "edge"})
     alt = solve(dcop, "maxsum", max_cycles=60,
                 algo_params={"aggregation": strategy})
     assert alt["cost"] == base["cost"]

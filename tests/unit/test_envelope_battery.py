@@ -547,8 +547,13 @@ class TestFlushPlanning:
         assert stats["envelope_dispatches"] >= 1
         assert stats["envelope_packed_requests"] >= 2
         for dcop, res in zip(dcops, results):
+            # The solo solve of the layout the dispatch ran: a
+            # lane-packed union is lane-major, the rest edge-major.
+            layout = ("lane" if res["batch"]["packing"] == "lane"
+                      else "edge")
             solo = api.solve(dcop, "maxsum", backend="device",
-                             max_cycles=MAX_CYCLES)
+                             max_cycles=MAX_CYCLES,
+                             algo_params={"layout": layout})
             assert res["assignment"] == solo["assignment"]
             assert res["cost"] == solo["cost"]
             assert res["batch"]["packing"] in ("envelope", "lane",

@@ -455,7 +455,8 @@ class TestFleetEndToEnd:
             # api.solve — clients cannot tell the fleet exists.
             for dcop, (_, res) in zip(dcops, results):
                 solo = api.solve(dcop, "maxsum", backend="device",
-                                 max_cycles=60)
+                                 max_cycles=60,
+                                 algo_params={"layout": "edge"})
                 assert res["assignment"] == solo["assignment"]
                 assert res["cost"] == solo["cost"]
 

@@ -208,7 +208,8 @@ class TestJournalRecovery:
                 assert result["status"] == "FINISHED"
                 solo = api.solve(dcops[rid], "maxsum",
                                  backend="device",
-                                 max_cycles=MAX_CYCLES)
+                                 max_cycles=MAX_CYCLES,
+                                 algo_params={"layout": "edge"})
                 assert result["assignment"] == solo["assignment"]
             # The pre-crash completion must NOT resurrect.
             with pytest.raises(KeyError):

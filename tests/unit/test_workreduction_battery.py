@@ -473,7 +473,8 @@ class TestServingConsumption:
                 assert service.stats()["portfolio_resolved"] == 1
             finally:
                 service.stop(drain=False)
-            solo = solve(dcop, "maxsum", max_cycles=60)
+            solo = solve(dcop, "maxsum", max_cycles=60,
+                         algo_params={"layout": "edge"})
             assert res["assignment"] == solo["assignment"]
 
     def test_prune_param_rides_the_bin_key(self):

@@ -195,8 +195,7 @@ def leg_coalescing():
 
         # Every response must match the equivalent solo api.solve.
         for dcop, (_, res) in zip(dcops, results):
-            solo = api.solve(dcop, "maxsum", backend="device",
-                             max_cycles=MAX_CYCLES)
+            solo = api_solve_cached(dcop, res)
             if res["assignment"] != solo["assignment"]:
                 check(False,
                       f"served assignment for {dcop.name} differs "
@@ -272,8 +271,7 @@ def leg_mixed_envelope():
         # THE acceptance bar: every envelope-packed response equals
         # the solo api.solve answer bit for bit.
         for dcop, (_, res) in zip(dcops, results):
-            solo = api.solve(dcop, "maxsum", backend="device",
-                             max_cycles=MAX_CYCLES)
+            solo = api_solve_cached(dcop, res)
             if res["assignment"] != solo["assignment"]:
                 check(False,
                       f"mixed-burst assignment for {dcop.name} "
@@ -617,7 +615,7 @@ def _check_pipelined_speculation(compile_share, pairs_off, stats_off,
     # speculated, packed or not) equals the solo api.solve
     # answer bit for bit.
     for dcop, res in pairs_on + pairs_off:
-        solo = api_solve_cached(dcop)
+        solo = api_solve_cached(dcop, res)
         if res["assignment"] != solo["assignment"]:
             check(False,
                   f"served assignment for {dcop.name} differs "
@@ -636,13 +634,21 @@ def _check_pipelined_speculation(compile_share, pairs_off, stats_off,
 _SOLO_CACHE = {}
 
 
-def api_solve_cached(dcop):
+def api_solve_cached(dcop, res=None):
+    """The solo ``api.solve`` a served answer ``res`` is held to: of
+    the message layout its dispatch ran (a lane-packed union is
+    lane-major, everything else the serve plane runs edge-major; an
+    unset ``layout`` would let the solo solve pick lane-major, which
+    sums each variable's messages in another order)."""
     from pydcop_tpu import api
 
-    if dcop.name not in _SOLO_CACHE:
-        _SOLO_CACHE[dcop.name] = api.solve(
-            dcop, "maxsum", backend="device", max_cycles=MAX_CYCLES)
-    return _SOLO_CACHE[dcop.name]
+    packing = (res or {}).get("batch", {}).get("packing")
+    layout = "lane" if packing == "lane" else "edge"
+    if (dcop.name, layout) not in _SOLO_CACHE:
+        _SOLO_CACHE[dcop.name, layout] = api.solve(
+            dcop, "maxsum", backend="device", max_cycles=MAX_CYCLES,
+            algo_params={"layout": layout})
+    return _SOLO_CACHE[dcop.name, layout]
 
 
 def leg_overload():
@@ -754,8 +760,7 @@ def leg_fleet_burst():
                   and r[1]["status"] == "FINISHED" for r in results),
               f"all {len(dcops)} fleet-burst responses finished")
         for dcop, (_, res) in zip(dcops, results):
-            solo = api.solve(dcop, "maxsum", backend="device",
-                             max_cycles=MAX_CYCLES)
+            solo = api_solve_cached(dcop, res)
             if res["assignment"] != solo["assignment"] \
                     or res["cost"] != solo["cost"]:
                 check(False,
@@ -1068,14 +1073,11 @@ def leg_kill9_replay():
                   and result["status"] == "FINISHED",
                   f"replayed request {rid} completed after kill -9")
         # Parity: a replayed request's answer equals the solo solve.
-        from pydcop_tpu import api
-
         probe = sorted(pending)[0] if pending else None
         if probe is not None:
-            solo = api.solve(dcops[probe], "maxsum",
-                             backend="device", max_cycles=MAX_CYCLES)
-            check(svc.result(probe)["assignment"]
-                  == solo["assignment"],
+            replayed = svc.result(probe)
+            solo = api_solve_cached(dcops[probe], replayed)
+            check(replayed["assignment"] == solo["assignment"],
                   "replayed result identical to solo api.solve")
     finally:
         svc.stop(drain=False)
