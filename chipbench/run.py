@@ -8,9 +8,13 @@ One process per run: loads, warms up (set-up), measures for
 JSON object (``correct``, ``attempted``, ``failed``, ``metrics``,
 ``device``, traced ``breakdown``, and last ``compared``: each number
 that ``correct`` rests on, of the run's worst answer, beside its
-limit; the same as the last lines of standard error).  ``--trace 0``
-reports the cell's end-to-end metrics, ``--trace 1`` its per-layer
-metrics.
+limit; the same as the last lines of standard error).  The line
+parses under a strict JSON parser: a number compared that is not
+finite is printed as a string, and the run is not ``correct``.
+``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
+per-layer metrics, and notes before the last line the names
+BENCHMARK.json lists for the cell that no reader found a value for
+(``listed_and_read_nothing``).
 
 The cell is looked up by name in BENCHMARK.json; its configuration
 file names a ``kind``, and ``runners/<kind>.py`` runs it.  In a traced
@@ -27,6 +31,7 @@ T0 = time.perf_counter()
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
@@ -156,6 +161,16 @@ def per_layer_metrics(data_dir, kind, capture):
     return out
 
 
+def strict(compared):
+    """``compared`` with every number that is not finite as a string
+    (``"inf"``, ``"nan"``), which a strict JSON parser takes, and
+    whether all of them were finite."""
+    out = {name: [x if math.isfinite(x) else str(x) for x in pair]
+           for name, pair in compared.items()}
+    return out, all(math.isfinite(x) for pair in compared.values()
+                    for x in pair)
+
+
 def breakdown(capture):
     """The device operations with the largest total time, and where
     the host's time went in the traced block: the self time of the
@@ -201,7 +216,8 @@ def run(args, started):
         result = runner.run(cell)
         if cell.window_start is None:
             raise BenchFailure("the runner never started its window")
-        line = {"correct": bool(result["correct"]),
+        compared, finite = strict(result["compared"])
+        line = {"correct": bool(result["correct"]) and finite,
                 "attempted": int(result["attempted"]),
                 "failed": int(result["failed"]),
                 "metrics": None, "device": device}
@@ -209,9 +225,14 @@ def run(args, started):
             capture = result["capture"]
             capture["device_kind"] = device["kind"]
             found = per_layer_metrics(data_dir, config["kind"], capture)
-            metrics = {m["name"]: found[m["name"]]
-                       for m in listed(bench, "per_layer", entry["name"])
-                       if m["name"] in found}
+            names = [m["name"]
+                     for m in listed(bench, "per_layer", entry["name"])]
+            metrics = {name: found[name] for name in names
+                       if name in found}
+            # Said in the run itself, not first by the driver: a
+            # listed metric whose reader found nothing to read.
+            note(listed_and_read_nothing=[
+                name for name in names if name not in found])
             if capture.get("device_trace"):
                 device["busy_s"] = capture["device_trace"]["busy_s"]
                 device["window_s"] = capture["traced_wall_s"]
@@ -230,7 +251,7 @@ def run(args, started):
     device["memory_peak_bytes"] = memory_peak_bytes()
     # Last in the line: each number `correct` rests on, of the run's
     # worst answer, beside its limit.
-    line["compared"] = result["compared"]
+    line["compared"] = compared
     return line
 
 
@@ -248,6 +269,7 @@ def main(argv=None, started=None):
     args = parser.parse_args(argv)
     try:
         line = run(args, started)
+        last = json.dumps(line, allow_nan=False)
     except Exception as exc:  # noqa: BLE001 - reported, exit non-zero
         traceback.print_exc()
         print(f"chipbench: FAILED: {type(exc).__name__}: {exc}",
@@ -256,7 +278,7 @@ def main(argv=None, started=None):
     for name, (value, limit) in line["compared"].items():
         print(f"chipbench: compared {name}: {value!r} (limit {limit!r})",
               file=sys.stderr)
-    print(json.dumps(line), flush=True)
+    print(last, flush=True)
     return 0
 
 

@@ -1,4 +1,5 @@
-"""What a rehearsal may not leave behind in its worker's process.
+"""What a rehearsal may not leave behind in its worker's process, and
+the benchmarks the checks of the lists are held against.
 
 ``test_rehearsal.py``'s ``harness`` puts ``aotcache._state`` back as
 it found it.  Where the run under test was the process's first to
@@ -12,7 +13,10 @@ flag is made true, and the listeners are on the bus exactly once,
 before ``harness`` saves the state.
 """
 
+import os
+
 import pytest
+import rehearsal_benchmarks
 
 
 @pytest.fixture(autouse=True)
@@ -27,3 +31,15 @@ def the_programs_listeners_are_on_the_bus_once():
     aotcache.install_listeners()
     yield
     assert monitoring.get_event_listeners().count(aotcache._on_event) == 1
+
+
+@pytest.fixture(scope="session")
+def benchmarks(tmp_path_factory):
+    """``{which: (path of its BENCHMARK.json, its data directory)}``:
+    the repository's own benchmark, and the copy with a fourth cell
+    (``rehearsal_benchmarks.py``), which no test writes to."""
+    return {
+        "real": (os.path.join(rehearsal_benchmarks.REPO, "BENCHMARK.json"),
+                 rehearsal_benchmarks.CHIPBENCH),
+        "fourth_cell": rehearsal_benchmarks.build(
+            str(tmp_path_factory.mktemp("fourth_cell")))}

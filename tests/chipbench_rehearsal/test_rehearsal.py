@@ -18,10 +18,13 @@ import statistics
 
 import numpy as np
 import pytest
-
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-CHIPBENCH = os.path.join(REPO, "chipbench")
+from rehearsal_benchmarks import (
+    CHIPBENCH,
+    WHICH,
+    benchmark,
+    per_benchmark,
+    per_layer_names,
+)
 
 LAST_LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device",
                   "compared"}
@@ -30,6 +33,14 @@ DEVICE_DERIVED = {"kernel.superstep_us", "maxsum_superstep_roofline",
 END_TO_END = {
     "solve": {"setup_s", "cli_solve_s", "solve_p50_s"},
     "serve": {"setup_s", "serve_problems_per_s", "serve_p95_ms"},
+}
+# The per-layer metrics a traced CPU run of each tiny cell prints.
+CPU_PER_LAYER = {
+    "solve": {"yaml.load_s", "hostcompile.ms", "engine.ms",
+              "entry.self_ms", "xla.compiles.solve", "solve.cost_ratio"},
+    "serve": {"serve.frontend_ms", "serve.queue_ms", "serve.prep_ms",
+              "serve.execute_ms", "serve.batch_mean",
+              "xla.compiles.serve", "serve.cost_ratio"},
 }
 TINY_CONFIGS = {
     "tiny_solve": {
@@ -67,16 +78,17 @@ def write_json(path, obj):
 
 @pytest.fixture
 def bench(tmp_path):
-    """A BENCHMARK.json of two tiny cells; returns its path."""
+    """A BENCHMARK.json of two tiny cells; returns its path.  It lists
+    the per-layer metrics the assertions of this file name, whatever
+    else the repository's own file lists by now: a metric a later PR
+    adds is rehearsed in a file of its own."""
     data = tmp_path / "data"
     shutil.copytree(os.path.join(CHIPBENCH, "metrics"), data / "metrics")
     for name, config in TINY_CONFIGS.items():
         write_json(str(data / "configs" / f"{name}.json"), config)
     for name, mix in TINY_TRAFFIC.items():
         write_json(str(data / "traffic" / f"{name}.json"), mix)
-    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
-        real = json.load(f)
-    units = {m["name"]: m["unit"] for m in real["end_to_end"]}
+    units = {m["name"]: m["unit"] for m in benchmark("real")["end_to_end"]}
     bench_path = tmp_path / "BENCHMARK.json"
     write_json(str(bench_path), {
         "configs": [{"name": n, "file": f"data/configs/{n}.json"}
@@ -91,7 +103,8 @@ def bench(tmp_path):
              "workloads": [TINY_CELLS[kind] for kind in END_TO_END
                            if name in END_TO_END[kind]]}
             for name in sorted(set().union(*END_TO_END.values()))],
-        "per_layer": [{"name": m["name"]} for m in real["per_layer"]],
+        "per_layer": [{"name": name} for name in sorted(
+            DEVICE_DERIVED.union(*CPU_PER_LAYER.values()))],
     })
     return str(bench_path)
 
@@ -159,6 +172,13 @@ def compared_is_last_and_sound(line, err, kind):
     assert [x.split()[2].rstrip(":") for x in said] == list(compared)
 
 
+def unread(notes):
+    """The listed per-layer metrics a traced run says it could not
+    read."""
+    return next(n["listed_and_read_nothing"] for n in notes
+                if "listed_and_read_nothing" in n)
+
+
 def run_cell(run, bench, kind, trace, seed=3000000001):
     return run.main(["--workload", TINY_CELLS[kind], "--seed", str(seed),
                      "--seconds", "1", "--trace", str(trace),
@@ -186,22 +206,20 @@ def test_untraced_run_prints_the_end_to_end_metrics(
                                    "memory_peak_bytes"}
 
 
-@pytest.mark.parametrize("kind,expected", [
-    ("solve", {"yaml.load_s", "hostcompile.ms", "engine.ms",
-               "entry.self_ms", "xla.compiles.solve", "solve.cost_ratio"}),
-    ("serve", {"serve.frontend_ms", "serve.queue_ms", "serve.prep_ms",
-               "serve.execute_ms", "serve.batch_mean",
-               "xla.compiles.serve", "serve.cost_ratio"}),
-])
+@pytest.mark.parametrize("kind", ["solve", "serve"])
 def test_traced_run_prints_the_per_layer_metrics_a_cpu_can_give(
-        harness, bench, capsys, kind, expected):
+        harness, bench, capsys, kind):
     assert run_cell(harness, bench, kind, 1) == 0
-    line, _ = last_line(capsys, compared_of=kind)
+    line, notes = last_line(capsys, compared_of=kind)
     assert set(line) == LAST_LINE_KEYS | {"breakdown"}
     assert line["correct"] is True
     # No number from a CPU run under a device metric's name.
-    assert set(line["metrics"]) == expected
+    assert set(line["metrics"]) == CPU_PER_LAYER[kind]
     assert not set(line["metrics"]) & DEVICE_DERIVED
+    # The run says itself which listed metrics read nothing: here the
+    # device's and the other kind's.
+    assert set(unread(notes)) == DEVICE_DERIVED.union(
+        *CPU_PER_LAYER.values()) - CPU_PER_LAYER[kind]
     assert "busy_s" not in line["device"]
     assert "device_ops" not in line["breakdown"]
     assert line["breakdown"]["idle_gaps"]
@@ -238,6 +256,37 @@ def test_a_wrong_cost_makes_correct_false(harness, bench, capsys,
     # answer, beside their limits.
     assert line["compared"]["cost_minus_host"] == [1.0, 0]
     assert line["compared"]["violations_minus_host"] == [0, 0]
+
+
+def test_an_infinite_cost_is_printed_as_a_string_and_is_not_correct(
+        harness, bench, capsys, monkeypatch):
+    """The last line has to parse under a strict JSON parser, which
+    refuses ``Infinity`` and ``NaN``."""
+    from pydcop_tpu import api
+
+    honest = api.solve
+
+    def infinite(*args, **kwargs):
+        return dict(honest(*args, **kwargs), cost=float("inf"))
+
+    def refuse(constant):
+        raise AssertionError(f"{constant} in the last line")
+
+    monkeypatch.setattr(api, "solve", infinite)
+    assert run_cell(harness, bench, "solve", 0) == 0
+    captured = capsys.readouterr()
+    line = json.loads(captured.out.strip().splitlines()[-1],
+                      parse_constant=refuse)
+    assert line["correct"] is False and line["failed"] >= 1
+    assert line["compared"]["cost"][0] == "inf"
+    assert line["compared"]["cost_minus_host"] == ["inf", 0]
+    assert "chipbench: compared cost: 'inf'" in captured.err
+    # A limit that is not finite fails the run too, whatever the cost.
+    assert harness.strict({"cost": [3.0, float("inf")],
+                           "unassigned": [0, 0]}) == (
+        {"cost": [3.0, "inf"], "unassigned": [0, 0]}, False)
+    assert harness.strict({"cost": [3.0, 4.0]}) == (
+        {"cost": [3.0, 4.0]}, True)
 
 
 def test_no_chip_no_result(bench, capsys):
@@ -557,23 +606,21 @@ def test_the_worst_answer_is_one_at_fault_then_the_costliest():
 
 
 # --------------------------------------------------------------------- #
-# BENCHMARK.json and the data files agree
+# BENCHMARK.json and the data files agree: in the repository's own
+# benchmark, and in the copy to which a fourth cell was added
 
 
-def _benchmark():
-    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
-        return json.load(f)
-
-
-def test_every_cell_resolves_to_its_files_and_a_runner():
+@pytest.mark.parametrize("which", WHICH)
+def test_every_cell_resolves_to_its_files_and_a_runner(benchmarks, which):
     from chipbench import run
 
-    bench = _benchmark()
+    path, data = benchmarks[which]
+    bench = benchmark(which)
     assert bench["paths"] == ["chipbench", "tests/chipbench_rehearsal"]
     for cell in bench["workloads"]:
         _, entry, config, traffic, data_dir = run.resolve(
-            os.path.join(REPO, "BENCHMARK.json"), cell["name"])
-        assert data_dir == CHIPBENCH
+            path, cell["name"])
+        assert data_dir == data
         assert config["name"] == entry["config"]
         assert traffic["name"] == entry["traffic"]
         assert os.path.isfile(os.path.join(
@@ -584,19 +631,31 @@ def test_every_cell_resolves_to_its_files_and_a_runner():
         assert run.listed(bench, "per_layer", cell["name"])
 
 
-def test_every_per_layer_metric_is_a_file_that_agrees_with_its_entry():
-    bench = _benchmark()
-    end_to_end = {m["name"]: m for m in bench["end_to_end"]}
+@pytest.mark.parametrize("which,name", per_benchmark(per_layer_names))
+def test_a_per_layer_metric_is_a_file_that_agrees_with_its_entry(
+        benchmarks, which, name):
+    bench = benchmark(which)
+    entry = next(m for m in bench["per_layer"] if m["name"] == name)
     cells = {w["name"] for w in bench["workloads"]}
-    for entry in bench["per_layer"]:
-        path = os.path.join(CHIPBENCH, "metrics", f"{entry['name']}.json")
-        with open(path, encoding="utf-8") as f:
-            spec = json.load(f)
-        for key in ("name", "unit", "better", "source", "layer", "moves"):
-            assert spec[key] == entry[key], (entry["name"], key)
-        assert os.path.isfile(os.path.join(
-            CHIPBENCH, "readers", f"{spec['reader']}.py"))
-        # The metric it moves is reported in every cell it is in.
-        moved = end_to_end[entry["moves"]]
-        assert set(entry.get("workloads", cells)) <= set(
-            moved.get("workloads", cells))
+    path = os.path.join(benchmarks[which][1], "metrics", f"{name}.json")
+    with open(path, encoding="utf-8") as f:
+        spec = json.load(f)
+    for key in ("name", "unit", "better", "source", "layer", "moves"):
+        assert spec[key] == entry[key], key
+    assert os.path.isfile(os.path.join(
+        CHIPBENCH, "readers", f"{spec['reader']}.py"))
+    # The metric it moves is reported in every cell it is in.
+    moved = next(m for m in bench["end_to_end"]
+                 if m["name"] == entry["moves"])
+    assert set(entry.get("workloads", cells)) <= set(
+        moved.get("workloads", cells))
+
+
+@pytest.mark.parametrize("which", WHICH)
+def test_every_metric_file_is_listed_in_the_benchmark(benchmarks, which):
+    """A file in ``metrics/`` that ``BENCHMARK.json`` does not list is
+    evaluated in every traced run and printed in none."""
+    files = {name[:-len(".json")]
+             for name in os.listdir(os.path.join(benchmarks[which][1],
+                                                 "metrics"))}
+    assert files == set(per_layer_names(which))

@@ -11,21 +11,30 @@ every file in ``chipbench/configs/`` to what the README says they
 are.  No test here names a real cell, a real
 configuration or a count of either: each expectation is read from the
 data, so a later PR's cell, metric, configuration or family is held to
-the same rules without an edit here.
+the same rules without an edit here.  That is tried: every such check
+runs on the repository's benchmark and on a copy of it to which a
+fourth cell was added as entries and new files only
+(``rehearsal_benchmarks.py``).
 """
 
-import glob
 import json
 import os
 import shutil
 
 import pytest
+from rehearsal_benchmarks import (
+    WHICH,
+    benchmark,
+    config_files,
+    per_benchmark,
+    per_layer_names,
+    solve_config_files,
+)
 from test_rehearsal import (  # noqa: F401 - harness is a fixture
     CHIPBENCH,
     LAST_LINE_KEYS,
     TINY_CONFIGS,
     TINY_TRAFFIC,
-    _benchmark,
     harness,
     last_line,
     write_json,
@@ -37,9 +46,6 @@ NOCLI = dict(
     generator={"family": "graph_coloring", "variables": 60, "colors": 3,
                "graph": "random", "p_edge": 0.05, "constraints": 90},
     cli_solve=False)
-CONFIG_FILES = sorted(
-    os.path.basename(p)
-    for p in glob.glob(os.path.join(CHIPBENCH, "configs", "*.json")))
 # What chipbench/README.md's "a configuration" row names.
 README_KEYS = {"name", "kind", "source", "reduced", "assumed",
                "guarantees", "cost_tolerance", "why"}
@@ -50,25 +56,25 @@ RUNNER_KEYS = {
 }
 
 
-def _config(filename):
-    with open(os.path.join(CHIPBENCH, "configs", filename),
+def _config(filename, data=CHIPBENCH):
+    with open(os.path.join(data, "configs", filename),
               encoding="utf-8") as f:
         return json.load(f)
 
 
-def _config_of(bench):
+def _config_of(bench, data=CHIPBENCH):
     """Each cell's configuration file, by the cell's name."""
     files = {c["name"]: os.path.basename(c["file"])
              for c in bench["configs"]}
-    return {w["name"]: _config(files[w["config"]])
+    return {w["name"]: _config(files[w["config"]], data)
             for w in bench["workloads"]}
 
 
-def cells_the_rule_gives(bench, moves, kinds):
+def cells_the_rule_gives(bench, moves, kinds, data=CHIPBENCH):
     """chipbench/README.md, "a cell": the cells whose configuration is
     of one of ``kinds`` and which report the end-to-end metric
     ``moves`` (every cell, where that metric lists none)."""
-    config_of = _config_of(bench)
+    config_of = _config_of(bench, data)
     moved = next(m for m in bench["end_to_end"] if m["name"] == moves)
     return [w["name"] for w in bench["workloads"]
             if config_of[w["name"]]["kind"] in kinds
@@ -89,7 +95,7 @@ def _write_bench(tmp_path, cli_solve_cells, configs=None):
         write_json(str(data / "configs" / f"{name}.json"), config)
     write_json(str(data / "traffic" / "resolve.json"),
                TINY_TRAFFIC["resolve"])
-    real = _benchmark()
+    real = benchmark("real")
     both = [f"{n}.resolve" for n in configs]
     bench_path = tmp_path / "BENCHMARK.json"
     write_json(str(bench_path), {
@@ -254,9 +260,11 @@ def test_a_superstep_that_leaves_its_state_unchanged_makes_correct_false(
 # BENCHMARK.json's lists follow from the data
 
 
-def test_the_cells_of_cli_solve_s_are_those_whose_configuration_says_so():
-    bench = _benchmark()
-    config_of = _config_of(bench)
+@pytest.mark.parametrize("which", WHICH)
+def test_the_cells_of_cli_solve_s_are_those_whose_configuration_says_so(
+        benchmarks, which):
+    bench = benchmark(which)
+    config_of = _config_of(bench, benchmarks[which][1])
     cli = next(m for m in bench["end_to_end"]
                if m["name"] == "cli_solve_s")
     assert set(cli["workloads"]) == {
@@ -270,28 +278,30 @@ def test_the_cells_of_cli_solve_s_are_those_whose_configuration_says_so():
         if config["kind"] == "solve"}
 
 
-@pytest.mark.parametrize(
-    "name", [m["name"] for m in _benchmark()["per_layer"]])
-def test_a_per_layer_metric_lists_only_cells_the_rule_allows(name):
+@pytest.mark.parametrize("which,name", per_benchmark(per_layer_names))
+def test_a_per_layer_metric_lists_only_cells_the_rule_allows(
+        benchmarks, which, name):
     """chipbench/README.md, "a cell": a per-layer metric lists only
     cells of its kind that report the end-to-end metric it moves.  It
     may list fewer (a metric that finds something to read in some of
-    them only), never another."""
-    bench = _benchmark()
+    them only), never another.  No test asks for all of them."""
+    bench, data = benchmark(which), benchmarks[which][1]
     entry = next(m for m in bench["per_layer"] if m["name"] == name)
-    with open(os.path.join(CHIPBENCH, "metrics", f"{name}.json"),
+    with open(os.path.join(data, "metrics", f"{name}.json"),
               encoding="utf-8") as f:
         kinds = json.load(f)["kinds"]
-    allowed = cells_the_rule_gives(bench, entry["moves"], kinds)
+    allowed = cells_the_rule_gives(bench, entry["moves"], kinds, data)
     assert entry["workloads"], name
     assert set(entry["workloads"]) <= set(allowed)
     assert len(set(entry["workloads"])) == len(entry["workloads"])
 
 
-def test_every_cell_is_in_some_per_layer_metric_of_each_metric_it_reports():
+@pytest.mark.parametrize("which", WHICH)
+def test_every_cell_is_in_some_per_layer_metric_of_each_metric_it_reports(
+        which):
     """A cell that no per-layer metric follows for an end-to-end metric
     (``setup_s`` apart) would move unexplained."""
-    bench = _benchmark()
+    bench = benchmark(which)
     for cell in bench["workloads"]:
         for metric in bench["end_to_end"]:
             if metric["name"] == "setup_s" or cell["name"] not in \
@@ -307,15 +317,20 @@ def test_every_cell_is_in_some_per_layer_metric_of_each_metric_it_reports():
 # every configuration file is what the README says one is
 
 
-def test_there_is_a_file_for_each_configuration_and_no_other():
+@pytest.mark.parametrize("which", WHICH)
+def test_there_is_a_file_for_each_configuration_and_no_other(
+        benchmarks, which):
     listed = {os.path.basename(c["file"])
-              for c in _benchmark()["configs"]}
-    assert listed == set(CONFIG_FILES)
+              for c in benchmark(which)["configs"]}
+    assert listed == set(os.listdir(os.path.join(benchmarks[which][1],
+                                                 "configs")))
+    assert listed == set(config_files(which))
 
 
-@pytest.mark.parametrize("filename", CONFIG_FILES)
-def test_a_configuration_file_has_the_keys_the_readme_names(filename):
-    config = _config(filename)
+@pytest.mark.parametrize("which,filename", per_benchmark(config_files))
+def test_a_configuration_file_has_the_keys_the_readme_names(
+        benchmarks, which, filename):
+    config = _config(filename, benchmarks[which][1])
     assert README_KEYS <= set(config), README_KEYS - set(config)
     assert config["kind"] in RUNNER_KEYS
     missing = RUNNER_KEYS[config["kind"]] - set(config)
@@ -328,11 +343,12 @@ def test_a_configuration_file_has_the_keys_the_readme_names(filename):
     assert config["why"].strip()
 
 
-@pytest.mark.parametrize("filename", CONFIG_FILES)
-def test_a_configuration_file_agrees_with_its_benchmark_entry(filename):
-    config = _config(filename)
+@pytest.mark.parametrize("which,filename", per_benchmark(config_files))
+def test_a_configuration_file_agrees_with_its_benchmark_entry(
+        benchmarks, which, filename):
+    config = _config(filename, benchmarks[which][1])
     assert config["name"] + ".json" == filename
-    entry = next(c for c in _benchmark()["configs"]
+    entry = next(c for c in benchmark(which)["configs"]
                  if c["name"] == config["name"])
     assert entry["file"] == f"chipbench/configs/{filename}"
     assert config["source"] == entry["source"]
@@ -341,21 +357,26 @@ def test_a_configuration_file_agrees_with_its_benchmark_entry(filename):
     assert config["reduced"] == entry["reduced"]
 
 
-def test_no_two_configurations_share_a_source():
-    sources = [_config(f)["source"] for f in CONFIG_FILES]
+@pytest.mark.parametrize("which", WHICH)
+def test_no_two_configurations_share_a_source(benchmarks, which):
+    sources = [_config(f, benchmarks[which][1])["source"]
+               for f in config_files(which)]
     assert len(set(sources)) == len(sources)
 
 
-@pytest.mark.parametrize("filename", [
-    f for f in CONFIG_FILES if _config(f)["kind"] == "solve"])
-def test_a_solve_configuration_fixes_the_count_its_density_gives(filename):
+@pytest.mark.parametrize("which,filename",
+                         per_benchmark(solve_config_files))
+def test_a_solve_configuration_fixes_the_count_its_density_gives(
+        benchmarks, which, filename):
     """The counts the file fixes follow from the parameters its
     ``source`` names, by its family's own rule, and every seed's
     instance has the shapes the family states (tried on the same
     family at a size a test can hold)."""
     from chipbench import lib
 
-    generator = _config(filename)["generator"]
+    config = _config(filename, benchmarks[which][1])
+    assert config["kind"] == "solve"
+    generator = config["generator"]
     family = lib.family_of(generator)
     family.check(generator)
     small = family.small(generator)

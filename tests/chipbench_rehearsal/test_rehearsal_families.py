@@ -7,8 +7,13 @@ import glob
 import os
 
 import pytest
+from rehearsal_benchmarks import (
+    config_files,
+    per_benchmark,
+    solve_config_files,
+)
 from test_rehearsal import CHIPBENCH, FAMILY_SPECS
-from test_rehearsal_cells import CONFIG_FILES, _config
+from test_rehearsal_cells import _config
 
 
 @pytest.mark.parametrize("name", sorted(FAMILY_SPECS))
@@ -33,18 +38,20 @@ def test_every_seed_gives_the_shapes_its_family_states(name):
                           for c in again.constraints.values()]
 
 
-@pytest.mark.parametrize("filename", CONFIG_FILES)
-def test_a_configuration_names_a_family_that_is_a_file(filename):
-    family = _config(filename)["generator"]["family"]
+@pytest.mark.parametrize("which,filename", per_benchmark(config_files))
+def test_a_configuration_names_a_family_that_is_a_file(
+        benchmarks, which, filename):
+    family = _config(filename,
+                     benchmarks[which][1])["generator"]["family"]
     assert os.path.isfile(os.path.join(CHIPBENCH, "families",
                                        f"{family}.py"))
 
 
-@pytest.mark.parametrize("filename", [
-    f for f in CONFIG_FILES if _config(f)["kind"] == "solve"])
+@pytest.mark.parametrize("which,filename",
+                         per_benchmark(solve_config_files))
 def test_a_solve_configuration_states_its_parameters_and_how_it_ends(
-        filename):
-    config = _config(filename)
+        benchmarks, which, filename):
+    config = _config(filename, benchmarks[which][1])
     assert isinstance(config["algo_params"], dict)
     if config["ends"] is not None:
         assert set(config["ends"]) == {"status", "cycles"}

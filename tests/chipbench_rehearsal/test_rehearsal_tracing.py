@@ -1,11 +1,11 @@
 """The readers and metrics PR 26 adds, rehearsed on the CPU.
 
 Every metric of PR 26 is reported only from a run that has the
-device's trace: PR 25's rehearsal (``test_rehearsal.py``, which may
-not be edited) asserts the exact set of metrics a CPU run prints.  So
-the end-to-end rehearsal here runs the same tiny cells with a
-benchmark that lists the new metrics alone and hands the readers a
-stand-in for the device's trace; what needs a real one
+device's trace (PR 25's rehearsal, ``test_rehearsal.py``, listed every
+metric of the repository's file until PR 37 and asserts the exact set
+a CPU run prints).  So the end-to-end rehearsal here runs the same
+tiny cells with a benchmark that lists PR 26's metrics alone and hands
+the readers a stand-in for the device's trace; what needs a real one
 (``xplane_scopes``, ``idle_under``) is checked as pure functions on
 hand-made events, and the protobuf reading on a profile of the CPU.
 """
@@ -16,9 +16,9 @@ import os
 import shutil
 
 import pytest
+from rehearsal_benchmarks import REPO, WHICH
 from test_rehearsal import (  # noqa: F401 - harness is a fixture
     CHIPBENCH,
-    REPO,
     TINY_CELLS,
     TINY_CONFIGS,
     TINY_TRAFFIC,
@@ -27,12 +27,10 @@ from test_rehearsal import (  # noqa: F401 - harness is a fixture
     run_cell,
     write_json,
 )
-from test_rehearsal_cells import cells_the_rule_gives
 
 NEW = {
     "solve": {
-        "cpu": {"yaml.parse_s", "yaml.build_s", "engine.retrace_ms",
-                "engine.cache_load_ms"},
+        "cpu": {"yaml.parse_s", "yaml.build_s"},
         "chip": {"engine.dispatch_ms", "kernel.f2v_us",
                  "kernel.aggregate_us", "kernel.v2f_us",
                  "kernel.update_us", "kernel.unscoped_us",
@@ -45,11 +43,6 @@ NEW = {
 }
 ALL_NEW = sorted(set().union(*(v for kind in NEW.values()
                                for v in kind.values())))
-
-
-def _real_benchmark():
-    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
-        return json.load(f)
 
 
 @pytest.fixture
@@ -127,20 +120,17 @@ def test_without_a_device_trace_no_new_metric_is_reported(
     assert line["metrics"] == {}
 
 
+@pytest.mark.parametrize("which", WHICH)
 @pytest.mark.parametrize("name", ALL_NEW)
-def test_a_new_metric_lists_its_cells_and_reads_nothing_from_nothing(
-        name):
+def test_a_new_metric_is_of_one_kind_and_reads_nothing_from_nothing(
+        benchmarks, which, name):
+    """Which cells it lists is test_rehearsal_cells.py's one rule: only
+    cells of its kind that report what it moves, possibly fewer."""
     from chipbench import run
 
-    bench = _real_benchmark()
-    entry = next(m for m in bench["per_layer"] if m["name"] == name)
     kind = next(k for k in NEW if name in NEW[k]["cpu"] | NEW[k]["chip"])
-    # Its cells: those of its kind that report the end-to-end metric
-    # it moves (all cells, where that metric lists none).
-    cells = cells_the_rule_gives(bench, entry["moves"], [kind])
-    assert set(entry["workloads"]) == set(cells)
-    with open(os.path.join(CHIPBENCH, "metrics", f"{name}.json"),
-              encoding="utf-8") as f:
+    with open(os.path.join(benchmarks[which][1], "metrics",
+                           f"{name}.json"), encoding="utf-8") as f:
         spec = json.load(f)
     assert spec["kinds"] == [kind]
     reader = run.module_by_name("readers", spec["reader"], name)
