@@ -14,8 +14,10 @@ the same data, several times slower.  ``YAML_LOADER`` says which
 (``"c"`` or ``"python"``).  Dumping stays on ``SafeDumper``.
 """
 
+import gc
 import os
 import re
+import threading
 from typing import Any, Dict, Iterable, List, Optional, Union
 
 import yaml
@@ -65,6 +67,47 @@ def _yaml_dump(data, **kw) -> str:
 
 class DcopInvalidFormatError(Exception):
     pass
+
+
+class _CollectorPause:
+    """The cyclic garbage collector, off while any load is in flight.
+
+    A load builds a tree of dicts, lists and strings that holds no
+    reference cycle, yet every 700 container allocations the collector
+    walks it again, and each full collection walks the whole heap:
+    about half of a 10 000-variable problem's load.  ``gc.disable()``
+    is the process's and ``pydcop serve`` loads in several handler
+    threads at once, so the loads are counted: the first in records
+    whether the collector was enabled and disables it, the last out
+    enables it if, and only if, it was.  Entering returns whether this
+    pause is what holds the collector off (false when the caller runs
+    with it off already)."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.in_flight = 0
+        self._was_enabled = False
+
+    def __enter__(self) -> bool:
+        with self._lock:
+            if self.in_flight == 0:
+                self._was_enabled = gc.isenabled()
+                gc.disable()
+            self.in_flight += 1
+            return self._was_enabled
+
+    def __exit__(self, *exc):
+        with self._lock:
+            self.in_flight -= 1
+            if self.in_flight == 0 and self._was_enabled:
+                gc.enable()
+
+
+_collector_pause = _CollectorPause()
+
+
+def _full_collections() -> int:
+    return gc.get_stats()[2]["collections"]
 
 
 # --------------------------------------------------------------------- #
@@ -131,23 +174,33 @@ def _parse_domain_values(raw_values) -> List:
 
 
 def load_dcop(yaml_str: str, main_dir: str = ".") -> DCOP:
-    """Parse the YAML text, then build the DCOP's objects from it.
+    """Parse the YAML text, then build the DCOP's objects from it,
+    both with the cyclic collector paused (``_CollectorPause``).
     The two halves are spans on ``tracer.active``, so they reach the
     flight ring with tracing off: the load is the largest host cost
     of ``pydcop solve`` and of a served request, and a slow request's
     parse time is what a postmortem wants."""
-    if not tracer.active:
-        return _build_dcop(_yaml_load(yaml_str), main_dir)
-    # ``bytes`` counts characters: the same number for ASCII YAML,
-    # without encoding the text a second time.
-    with tracer.span("yaml_parse", "dcop", bytes=len(yaml_str),
-                     loader=YAML_LOADER):
-        data = _yaml_load(yaml_str)
-    with tracer.span("yaml_build", "dcop") as span:
-        dcop = _build_dcop(data, main_dir)
-        span.args["n_variables"] = len(dcop.variables)
-        span.args["n_constraints"] = len(dcop.constraints)
-    return dcop
+    with _collector_pause as paused:
+        if not tracer.active:
+            return _build_dcop(_yaml_load(yaml_str), main_dir)
+        full_before = _full_collections()
+        # ``bytes`` counts characters: the same number for ASCII YAML,
+        # without encoding the text a second time.
+        parse = tracer.span("yaml_parse", "dcop", bytes=len(yaml_str),
+                            loader=YAML_LOADER, gc_paused=paused)
+        try:
+            with parse:
+                data = _yaml_load(yaml_str)
+            with tracer.span("yaml_build", "dcop") as span:
+                dcop = _build_dcop(data, main_dir)
+                span.args["n_variables"] = len(dcop.variables)
+                span.args["n_constraints"] = len(dcop.constraints)
+            return dcop
+        finally:
+            # Over the whole load, so written when it ends (the
+            # recorded event holds this dict): 0 when the pause held.
+            parse.args["gc_full_collections"] = (
+                _full_collections() - full_before)
 
 
 def _build_dcop(data, main_dir: str) -> DCOP:

@@ -561,6 +561,46 @@ def test_post_solve_answers_400_for_a_malformed_document():
         svc.stop(drain=False)
 
 
+@pytest.mark.parametrize("body, status", [
+    ("name: [unclosed\n  - {", 400),
+    (BASE + "constraints:\n  c: {type: intention, function: v1 + v2}\n",
+     200),
+], ids=["not_yaml", "well_formed"])
+def test_a_served_load_leaves_the_server_its_collector(body, status):
+    """The handler thread's load pauses the process's collector: a
+    bad body must not leave a long-lived server without it."""
+    import gc
+
+    from pydcop_tpu.serving.http import ServeFrontEnd
+    from pydcop_tpu.serving.service import SolveService
+
+    assert gc.isenabled()
+    svc = SolveService(batch_window_s=0.01, max_batch=8)
+    svc.start()
+    front = ServeFrontEnd(svc, port=0).start()
+    try:
+        req = urllib.request.Request(
+            front.url + "/solve",
+            data=json.dumps({"dcop": body, "wait": True,
+                             "params": {"max_cycles": 10}}).encode(),
+            headers={"Content-Type": "application/json"})
+        try:
+            with urllib.request.urlopen(req, timeout=60) as reply:
+                code, answer = reply.status, json.loads(reply.read())
+        except urllib.error.HTTPError as err:
+            code, answer = err.code, json.loads(err.read())
+        assert code == status, answer
+        if status == 400:
+            assert "bad problem" in answer["error"]
+        else:
+            assert answer["status"] == "FINISHED"
+        assert gc.isenabled()
+        assert yamldcop._collector_pause.in_flight == 0
+    finally:
+        front.stop()
+        svc.stop(drain=False)
+
+
 def _module_copy(name: str):
     """``dcop/yamldcop.py`` executed again under another name: what
     ``importlib.reload`` would choose, without replacing the classes

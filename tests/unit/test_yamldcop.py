@@ -6,7 +6,11 @@ them and produce the same problems.  Those tests skip cleanly when the
 reference checkout is absent, keeping the suite self-contained.
 """
 
+import contextlib
+import gc
 import os
+import sys
+import threading
 
 import pytest
 
@@ -18,8 +22,10 @@ from fixtures_paths import (
     requires_reference,
 )
 
+from pydcop_tpu.dcop import yamldcop
 from pydcop_tpu.dcop.objects import VariableNoisyCostFunc, VariableWithCostFunc
 from pydcop_tpu.dcop.yamldcop import (
+    DcopInvalidFormatError,
     dcop_yaml,
     load_dcop,
     load_dcop_from_file,
@@ -351,3 +357,256 @@ distribution:
     assert d.agent_for("v1") == "a1"
     d2 = load_dist(yaml_dist(d))
     assert d2 == d
+
+
+# ------------------------------------------------------------------ #
+# the cyclic collector is paused while a load is in flight
+# (counts and states only: a time comes from the chip)
+
+CHAIN = """
+name: chain
+objective: min
+domains:
+  d: {values: [0, 1]}
+variables:
+  v1: {domain: d}
+  v2: {domain: d}
+constraints:
+  c1: {type: intention, function: v1 + v2}
+"""
+
+NOT_YAML = "name: [unclosed\n  - {"
+NO_NAME = "objective: min\ndomains:\n  d: {values: [0, 1]}\n"
+
+
+def _set_collector(enabled):
+    (gc.enable if enabled else gc.disable)()
+
+
+@pytest.fixture
+def collector():
+    """The test finds the collector as pytest runs it, and leaves it so."""
+    was = gc.isenabled()
+    assert yamldcop._collector_pause.in_flight == 0
+    yield
+    _set_collector(was)
+
+
+@contextlib.contextmanager
+def _flight_ring(recorder):
+    """``recorder`` (or none: ``tracer.active`` false) as the flight
+    ring for the block."""
+    from pydcop_tpu.observability.trace import tracer
+
+    previous = tracer.flight
+    tracer.set_flight(recorder)
+    try:
+        yield recorder
+    finally:
+        tracer.set_flight(previous)
+        tracer.clear()
+
+
+@pytest.fixture(params=["off", "ring"])
+def session(request):
+    """Both paths of ``load_dcop``: ``tracer.active`` false, and the
+    path with spans (a flight ring attached)."""
+    from pydcop_tpu.observability.flight import FlightRecorder
+
+    ring = FlightRecorder(events=64) if request.param == "ring" else None
+    with _flight_ring(ring):
+        yield
+
+
+def _grid_colouring(side, seed=7):
+    from pydcop_tpu.generators.graphcoloring import generate_graph_coloring
+
+    return generate_graph_coloring(
+        side * side, 3, "grid", allow_subgraph=True, noagents=True,
+        seed=seed)
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_load_leaves_the_collector_as_it_found_it(
+        enabled, collector, session):
+    _set_collector(enabled)
+    dcop = load_dcop(CHAIN)
+    assert gc.isenabled() is enabled
+    assert yamldcop._collector_pause.in_flight == 0
+    assert set(dcop.variables) == {"v1", "v2"}
+
+
+def test_collector_is_off_inside_both_halves_of_a_load(
+        collector, session, monkeypatch):
+    seen = []
+
+    def spy(name, callee):
+        def wrapped(*args):
+            seen.append((name, gc.isenabled(),
+                         yamldcop._collector_pause.in_flight))
+            return callee(*args)
+        monkeypatch.setattr(yamldcop, name, wrapped)
+
+    spy("_yaml_load", yamldcop._yaml_load)
+    spy("_build_dcop", yamldcop._build_dcop)
+    gc.enable()
+    load_dcop(CHAIN)
+    assert seen == [("_yaml_load", False, 1), ("_build_dcop", False, 1)]
+    assert gc.isenabled()
+
+
+@pytest.mark.parametrize("text, error", [
+    (NOT_YAML, yamldcop.yaml.YAMLError),
+    (NO_NAME, DcopInvalidFormatError),
+], ids=["not_yaml", "no_name"])
+def test_a_load_that_raises_restores_the_collector(
+        text, error, collector, session):
+    gc.enable()
+    with pytest.raises(error):
+        load_dcop(text)
+    assert gc.isenabled()
+    assert yamldcop._collector_pause.in_flight == 0
+
+
+def test_a_build_that_raises_restores_the_collector(
+        collector, session, monkeypatch):
+    def broken(data, main_dir):
+        assert not gc.isenabled()
+        raise RuntimeError("in the build")
+
+    monkeypatch.setattr(yamldcop, "_build_dcop", broken)
+    gc.enable()
+    with pytest.raises(RuntimeError, match="in the build"):
+        load_dcop(CHAIN)
+    assert gc.isenabled()
+    assert yamldcop._collector_pause.in_flight == 0
+
+
+def test_eight_threads_share_one_pause(collector, monkeypatch):
+    """Off while any load is in flight, on after the last, count zero:
+    every thread is held inside its parse until all eight are in, and
+    half of them then fail."""
+    n = 8
+    inside = threading.Barrier(n, timeout=30)
+    seen, failures = [], []
+    real = yamldcop._yaml_load
+
+    def held(text):
+        inside.wait()
+        seen.append((gc.isenabled(), yamldcop._collector_pause.in_flight))
+        inside.wait()
+        return real(text)
+
+    def caller(k):
+        try:
+            load_dcop(NO_NAME if k % 2 else CHAIN)
+        except DcopInvalidFormatError:
+            failures.append(k)
+        # Another load may still be in flight: then it is still off.
+        with yamldcop._collector_pause._lock:
+            seen.append((gc.isenabled(),
+                         yamldcop._collector_pause.in_flight > 0))
+
+    monkeypatch.setattr(yamldcop, "_yaml_load", held)
+    gc.enable()
+    threads = [threading.Thread(target=caller, args=(k,)) for k in range(n)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads)
+    assert seen[:n] == [(False, n)] * n
+    assert all(enabled != in_flight for enabled, in_flight in seen[n:])
+    assert sorted(failures) == [1, 3, 5, 7]
+    assert gc.isenabled()
+    assert yamldcop._collector_pause.in_flight == 0
+
+
+def test_overlapping_loads_stress_leaves_count_zero(collector):
+    """More threads than cores, a short switch interval, good and bad
+    bodies: a lost update of the count would leave it off zero or the
+    collector off."""
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    errors = []
+
+    def caller(k):
+        for i in range(40):
+            try:
+                load_dcop(NO_NAME if (k + i) % 3 == 0 else CHAIN)
+            except DcopInvalidFormatError:
+                pass
+            except Exception as exc:  # noqa: BLE001 - reported below
+                errors.append(exc)
+
+    gc.enable()
+    try:
+        threads = [threading.Thread(target=caller, args=(k,))
+                   for k in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert errors == []
+    assert gc.isenabled()
+    assert yamldcop._collector_pause.in_flight == 0
+
+
+@contextlib.contextmanager
+def _eager_collector():
+    """A full collection every few thousand allocations, whatever the
+    test process's heap: CPython skips one until a quarter of the old
+    generation is new, so the heap is frozen out of that generation."""
+    thresholds = gc.get_threshold()
+    gc.collect()
+    gc.freeze()
+    gc.set_threshold(200, 3, 3)
+    try:
+        yield
+    finally:
+        gc.set_threshold(*thresholds)
+        gc.unfreeze()
+
+
+def _full_collections_during(load, text):
+    before = gc.get_stats()[2]["collections"]
+    dcop = load(text)
+    return gc.get_stats()[2]["collections"] - before, dcop
+
+
+def test_a_large_load_runs_no_full_collection(collector, monkeypatch):
+    text = dcop_yaml(_grid_colouring(34))
+    gc.enable()
+    with _eager_collector():
+        paused, dcop = _full_collections_during(load_dcop, text)
+        monkeypatch.setattr(yamldcop, "_collector_pause",
+                            contextlib.nullcontext(False))
+        unpaused, again = _full_collections_during(load_dcop, text)
+    assert len(dcop.constraints) == len(again.constraints) >= 2000
+    assert (paused, unpaused > 0) == (0, True), (paused, unpaused)
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_yaml_parse_span_says_whether_the_pause_engaged(
+        enabled, collector):
+    from pydcop_tpu.observability.flight import FlightRecorder
+
+    _set_collector(enabled)
+    with _flight_ring(FlightRecorder(events=16)) as recorder:
+        load_dcop(CHAIN)
+        with pytest.raises(DcopInvalidFormatError):
+            load_dcop(NO_NAME)
+    spans = [e for e in recorder.snapshot() if e["name"] == "yaml_parse"]
+    assert len(spans) == 2
+    for span in spans:
+        assert span["args"]["gc_paused"] is enabled
+        assert span["args"]["gc_full_collections"] == 0
+        assert span["args"]["loader"] == yamldcop.YAML_LOADER
+
+
+def test_generated_instance_round_trips_bytes(collector):
+    text = dcop_yaml(_grid_colouring(6))
+    assert dcop_yaml(load_dcop(text)) == text
