@@ -38,6 +38,7 @@ from pydcop_tpu.computations_graph import factor_graph as fg
 from pydcop_tpu.dcop.dcop import DCOP
 from pydcop_tpu.engine.compile import compile_dcop, validated_aggregation
 from pydcop_tpu.engine.runner import DeviceRunResult, MaxSumEngine
+from pydcop_tpu.observability.trace import NOOP_SPAN, tracer
 from pydcop_tpu.ops import maxsum as maxsum_ops
 
 GRAPH_TYPE = "factor_graph"
@@ -220,6 +221,24 @@ def build_engine(dcop: DCOP, params: dict, mesh=None,
                  n_devices: Optional[int] = None,
                  shards: Optional[int] = None,
                  whole_solve: bool = False) -> MaxSumEngine:
+    """:func:`_build_engine`, under a ``build_engine`` span while a
+    file session traces (parent of ``compile_graph`` and
+    ``engine_place``; args ``layout``, ``layout_source``: what ran)."""
+    with (tracer.span("build_engine", "engine")
+          if tracer.enabled else NOOP_SPAN) as span:
+        engine = _build_engine(dcop, params, mesh=mesh,
+                               n_devices=n_devices, shards=shards,
+                               whole_solve=whole_solve)
+        span.args["layout"] = engine.extra_metrics["layout"]
+        span.args["layout_source"] = engine.extra_metrics[
+            "layout_source"]
+        return engine
+
+
+def _build_engine(dcop: DCOP, params: dict, mesh=None,
+                  n_devices: Optional[int] = None,
+                  shards: Optional[int] = None,
+                  whole_solve: bool = False) -> MaxSumEngine:
     """Compile + construct the engine from validated algo params — the
     single place the parameter->engine wiring lives (solve_on_device
     and the CLI's device-mode trace reconstruction both use it).

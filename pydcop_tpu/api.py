@@ -953,7 +953,15 @@ def _solve(dcop, algo_def, module, *, distribution, backend, timeout,
                 dcop, algo_def, max_cycles=max_cycles, mesh=mesh,
                 n_devices=n_devices, warmup=warmup, **extra,
             )
-        cost, violations = dcop.solution_cost(res.assignment)
+        from pydcop_tpu.observability.trace import NOOP_SPAN, tracer
+
+        # The answer's cost on the host, one Python call per
+        # constraint: its own span under a file session.
+        with (tracer.span("result_cost", "api",
+                          n_constraints=len(dcop.constraints),
+                          n_variables=len(dcop.variables))
+              if tracer.enabled else NOOP_SPAN):
+            cost, violations = dcop.solution_cost(res.assignment)
         return SolveResult(
             status="FINISHED" if res.converged else "TIMEOUT",
             assignment=res.assignment,

@@ -87,7 +87,7 @@ from pydcop_tpu.engine.runner import (
 )
 from pydcop_tpu.observability import efficiency
 from pydcop_tpu.observability.profiler import profiler
-from pydcop_tpu.observability.trace import tracer
+from pydcop_tpu.observability.trace import NOOP_SPAN, tracer
 from pydcop_tpu.ops import maxsum as maxsum_ops
 
 # Batch-size ladder used when a caller asks for bin padding without
@@ -302,6 +302,17 @@ def _batched_maxsum_solve(stacked, *, max_cycles, damping, damp_vars,
 # The rollup's per-structure cell label (ONE definition, shared with
 # the dynamic engine — observability/efficiency.py).
 _structure_label = efficiency.structure_label
+
+
+def _assembly_span(n_real: int, packing: str):
+    """``batch_assemble``, under a file session: a synchronous batch's
+    assembly on the host, before ``timed_jit_call`` has the work (its
+    ``engine_call`` holds the launch).  A pipelined dispatch assembles
+    under ``SolveService.launch_dispatch``'s ``serve_launch``."""
+    if not tracer.enabled:
+        return NOOP_SPAN
+    return tracer.span("batch_assemble", "engine", n_real=n_real,
+                       packing=packing)
 
 
 class _StackedPrep(NamedTuple):
@@ -533,9 +544,12 @@ def run_stacked(
     (mean padded-cell fraction over real lanes) and
     ``envelope_waste_lanes`` (per lane, dispatch order).
     """
-    prep = _prepare_stacked(graphs, max_cycles, damping,
-                            damping_nodes, stability, pad_to_bins,
-                            prune, envelope)
+    with _assembly_span(
+            len(graphs),
+            "structure" if envelope is None else "envelope"):
+        prep = _prepare_stacked(graphs, max_cycles, damping,
+                                damping_nodes, stability, pad_to_bins,
+                                prune, envelope)
     t0 = time.perf_counter()
     # A batched dispatch IS one engine segment (the whole solve in
     # one program): the span name matches the segmented loop's so
@@ -609,8 +623,9 @@ def run_lane_packed(
     counts).  ``converged_lanes`` holds honest per-member verdicts
     recovered from the suppression counters
     (ops/maxsum_lane.converged_per_graph)."""
-    prep = _prepare_lane(graphs, max_cycles, damping, damping_nodes,
-                         stability, d_env, ladder)
+    with _assembly_span(len(graphs), "lane"):
+        prep = _prepare_lane(graphs, max_cycles, damping,
+                             damping_nodes, stability, d_env, ladder)
     t0 = time.perf_counter()
     span = (tracer.span("engine_segment", "engine",
                         batch_size=len(graphs), n_real=len(graphs),

@@ -630,12 +630,25 @@ class MaxSumEngine:
                     "layout='lane' uses its own scatter aggregation; "
                     "compile with aggregation='scatter'")
             maxsum_ops.refuse_pallas("layout='lane'")
-            self.graph = jax.device_put(lane_ops.to_lane_graph(graph))
-            self.mesh = None
-        else:
-            self.graph, self.mesh = _place_graph(graph, mesh, n_devices)
-            if self.mesh is not None and self.mesh.size > 1:
-                maxsum_ops.refuse_pallas("n_devices= mesh runs")
+        # The host's share of putting the graph where it runs (the
+        # lane conversion is numpy): its own span under a file
+        # session, beside ``compile_graph`` under ``build_engine``.
+        traced = tracer.enabled
+        with (tracer.span("engine_place", "engine", layout=layout)
+              if traced else NOOP_SPAN) as span:
+            if layout == "lane":
+                self.graph = jax.device_put(
+                    lane_ops.to_lane_graph(graph))
+                self.mesh = None
+            else:
+                self.graph, self.mesh = _place_graph(
+                    graph, mesh, n_devices)
+            if traced:
+                span.args["bytes"] = sum(
+                    int(leaf.nbytes)
+                    for leaf in jax.tree_util.tree_leaves(self.graph))
+        if self.mesh is not None and self.mesh.size > 1:
+            maxsum_ops.refuse_pallas("n_devices= mesh runs")
         self._ops = lane_ops if layout == "lane" else maxsum_ops
         self._init_solver_state(damping, damping_nodes, stability,
                                 donate, prune)
@@ -1313,6 +1326,15 @@ class MaxSumEngine:
         did: Dict[str, Any] = {}
         (state, values), _, run_s = self._call(
             key, fn, self.graph, report=did)
+        with (tracer.span("result_decode", "engine")
+              if tracer.enabled else NOOP_SPAN):
+            return self._decode(state, values, run_s, did)
+
+    def _decode(self, state, values, run_s: float,
+                did: Dict[str, Any]) -> DeviceRunResult:
+        """The host's tail of :meth:`run`, after the dispatch has
+        returned: the outputs fetched, the assignment by name, the
+        metrics."""
         # One host transfer for all three outputs.
         values, cycle, stable = jax.device_get(
             (values, state.cycle, state.stable)

@@ -172,12 +172,9 @@ class XlaCostProfiler:
 
     def _export_metrics(self, skey: str, entry: Dict[str, Any]):
         from pydcop_tpu.observability.metrics import registry
-        from pydcop_tpu.observability.trace import tracer
 
-        if tracer.enabled:
-            tracer.instant("xla_cost", "engine", key=skey, **{
-                k: v for k, v in entry.items() if k != "capture_s"
-            })
+        # (The trace holds the same numbers as the ``xla_cost`` arg
+        # of the first dispatch's span: engine/runner.timed_jit_call.)
         # Key-labeled series are unbounded across engines, so — like
         # the runner's per-key jit accounting — they are opt-in
         # detail: only recorded while metrics were actually requested

@@ -48,8 +48,16 @@ postmortem guard on ``tracer.active`` (true when either the session
 tracer or the flight ring wants events); per-message hot paths keep
 guarding on ``tracer.enabled`` so the ring holds signal, not message
 spam.
+
+The collector's timer (``gc_timer``, at the end of the tracer's own
+code): one ``gc.callbacks`` hook that counts the cyclic collector's
+pauses while a file session or a running service owns it and leaves a
+``gc_collect`` span per collection under a session;
+:func:`process_stats` is the ``process`` object of ``GET /stats``,
+and a session's exports carry it as read at the session's two ends.
 """
 
+import gc
 import itertools
 import json
 import math
@@ -153,10 +161,14 @@ class _Span:
     """An open span; records a complete (``ph:"X"``) event on exit.
     ``name`` and ``args`` may be set until then (``timed_jit_call``
     names its span by what happened inside it); the profiler
-    annotation keeps the name the span was opened with."""
+    annotation keeps the name the span was opened with.  Under a file
+    session the event also carries ``tdur``, the thread's CPU time
+    inside the span (``time.thread_time_ns``); the flight ring alone
+    reads no second clock."""
 
     __slots__ = ("_tracer", "name", "cat", "args", "span_id",
-                 "parent_id", "_t0", "_in_session", "_annotation")
+                 "parent_id", "_t0", "_c0", "_in_session",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Dict[str, Any]):
@@ -167,6 +179,7 @@ class _Span:
         self.span_id = next(tracer._ids)
         self.parent_id = 0
         self._t0 = 0.0
+        self._c0 = 0
         self._in_session = False
         self._annotation = None
 
@@ -180,9 +193,15 @@ class _Span:
         if self._in_session:
             self._annotation = _open_annotation(self.name, self.span_id)
         self._t0 = time.perf_counter()
+        if self._in_session:
+            # The thread's CPU clock, read inside the wall interval
+            # (``tdur <= dur`` wherever that clock is as fine as the
+            # wall's; some hosts tick it in 10 ms steps).
+            self._c0 = time.thread_time_ns()
         return self
 
     def __exit__(self, *exc):
+        c1 = time.thread_time_ns() if self._in_session else 0
         t1 = time.perf_counter()
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
@@ -196,7 +215,7 @@ class _Span:
             # a session only) and must not land on the flight ring
             # instead.
             return False
-        self._tracer._record({
+        event = {
             "name": self.name,
             "cat": self.cat,
             "ph": "X",
@@ -205,7 +224,14 @@ class _Span:
             "id": self.span_id,
             "parent": self.parent_id,
             "args": self.args,
-        })
+        }
+        if self._in_session:
+            # Chrome's thread-clock duration (us): the time this
+            # thread was on a CPU inside the span.  ``dur - tdur`` is
+            # the time it was not: blocked, or waiting for the
+            # interpreter lock.
+            event["tdur"] = (c1 - self._c0) / 1e3
+        self._tracer._record(event)
         return False
 
 
@@ -258,7 +284,10 @@ class Tracer:
         # attribute check, not two.
         self.flight = None
         self.active = False
-        self._lock = threading.Lock()
+        # Re-entrant: a collection can start on the thread that holds
+        # it (an allocation under ``events()``), and the collector's
+        # ``gc_collect`` span registers that thread's buffer.
+        self._lock = threading.RLock()
         self._local = threading.local()
         # (tid, thread name, buffer) per registered thread.
         self._buffers: List[tuple] = []
@@ -266,6 +295,9 @@ class Tracer:
         # buffer, so enable() drops stale events without touching
         # other threads' locals.
         self._generation = 0
+        # ``process_stats()`` at the last file session's ``enable``
+        # (``start``) and ``disable`` (``end``).
+        self._session_process: Dict[str, Any] = {}
         # Monotone lane ids, independent of _buffers length: flight-
         # only threads get a tid without a registration.
         self._tid_counter = 0
@@ -395,8 +427,8 @@ class Tracer:
         it (``tracer.current_span_id()`` where the interval lay
         inside the calling thread's open span, so that span's self
         time subtracts it); 0 leaves it a root.  A retroactive span
-        has no profiler annotation: an annotation cannot be
-        back-dated."""
+        has no profiler annotation and no ``tdur``: neither an
+        annotation nor a thread's CPU clock can be back-dated."""
         if not self.active:
             return
         self._record({
@@ -432,11 +464,20 @@ class Tracer:
             self._buffers = []
             self.enabled = True
             self.active = True
+        # A file session owns the process's timer of the garbage
+        # collector while it runs, and brackets itself with two reads
+        # of the process's clocks and counters (the exports' header
+        # carries them as ``session_process``).
+        gc_timer.acquire(self)
+        self._session_process = {"start": process_stats()}
 
     def disable(self):
         """Stop recording; buffered events stay readable for export."""
+        if self.enabled:
+            self._session_process["end"] = process_stats()
         self.enabled = False
         self.active = self.flight is not None
+        gc_timer.release(self)
 
     def clear(self):
         """Drop all events; recording state unchanged.  Lane ids keep
@@ -459,6 +500,16 @@ class Tracer:
     def thread_names(self) -> Dict[int, str]:
         with self._lock:
             return {tid: name for tid, name, _ in self._buffers}
+
+    def _header(self) -> Dict[str, Any]:
+        """:func:`trace_header`, and ``session_process`` for a file
+        session that has ended: :func:`process_stats` as its
+        ``enable`` and its ``disable`` read it, so that their
+        difference is the session's own and nothing's around it."""
+        header = trace_header()
+        if len(self._session_process) == 2:
+            header["session_process"] = dict(self._session_process)
+        return header
 
     def export_chrome(self, path: str):
         """Write Chrome ``trace_event`` JSON (open in chrome://tracing
@@ -483,6 +534,8 @@ class Tracer:
             }
             if ev["ph"] == "X":
                 out["dur"] = ev["dur"]
+                if "tdur" in ev:
+                    out["tdur"] = ev["tdur"]
             else:
                 out["s"] = "t"  # thread-scoped instant
             # Correlation ids ride in args: the Chrome schema has no
@@ -498,7 +551,7 @@ class Tracer:
                     "displayTimeUnit": "ms",
                     # Viewers ignore unknown top-level keys; trace
                     # merge reads the identity + clock anchor here.
-                    HEADER_KEY: trace_header(),
+                    HEADER_KEY: self._header(),
                 },
                 f, default=str,
             )
@@ -510,7 +563,7 @@ class Tracer:
         names = self.thread_names()
         tmp = f"{path}.tmp"
         with open(tmp, "w", encoding="utf-8") as f:
-            f.write(json.dumps({HEADER_KEY: trace_header()}) + "\n")
+            f.write(json.dumps({HEADER_KEY: self._header()}) + "\n")
             for ev in self.events():
                 row = dict(ev)
                 row["thread"] = names.get(ev["tid"], str(ev["tid"]))
@@ -529,6 +582,142 @@ class Tracer:
 
 
 tracer = Tracer()
+
+
+# --------------------------------------------------------------------- #
+# The collector's timer, and the ``process`` object of ``GET /stats``
+# --------------------------------------------------------------------- #
+#
+# A collection runs on whichever thread's allocation crossed a
+# threshold and holds the interpreter lock from start to end, against
+# every other thread.  ``gc.callbacks`` is the interpreter's own hook
+# around each one: ``gc_timer`` notes the clock on ``start`` and on
+# ``stop`` adds to its counters (collections and pause seconds by
+# generation, the longest pause, the longest full pause) and, under a
+# file session, leaves a live ``gc_collect`` span on the thread the
+# collection ran on, so that it has its profiler annotation and its
+# parent is whatever span that thread had open.
+#
+# The hook is installed for as long as somebody owns the timer, never
+# at import: a file session (``Tracer.enable`` / ``disable``) and a
+# running ``SolveService`` (``start`` / ``stop``) each ``acquire`` and
+# ``release`` it.  With tracing off it costs two Python calls per
+# collection (one per phase).  The counters only move while the hook
+# is installed; they are never reset, so two reads bracket an interval.
+
+_GENERATIONS = ("gen0", "gen1", "gen2")
+
+
+class GcTimer:
+    """Counters of the collector's pauses, fed by one ``gc.callbacks``
+    hook that is installed while the timer has an owner."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._owners: list = []
+        self.collections = [0, 0, 0]
+        self.pause_s = [0.0, 0.0, 0.0]
+        self.max_pause_s = 0.0
+        self.max_full_pause_s = 0.0
+        # One collection runs at a time in a process, so the open one
+        # needs no per-thread state.
+        self._t0 = None
+        self._span = None
+
+    # -- ownership ----------------------------------------------------- #
+
+    def acquire(self, owner) -> None:
+        """Install the hook for ``owner`` (idempotent per owner)."""
+        with self._lock:
+            if any(o is owner for o in self._owners):
+                return
+            self._owners.append(owner)
+            if len(self._owners) == 1:
+                gc.callbacks.append(self._on_gc)
+
+    def release(self, owner) -> None:
+        """Drop ``owner``; the hook goes with the last one.  A
+        release by somebody who never acquired does nothing."""
+        with self._lock:
+            kept = [o for o in self._owners if o is not owner]
+            if len(kept) == len(self._owners):
+                return
+            self._owners = kept
+            if not kept and self._on_gc in gc.callbacks:
+                gc.callbacks.remove(self._on_gc)
+                # A collection caught open has lost its ``stop``.
+                self._t0 = self._span = None
+
+    @property
+    def installed(self) -> bool:
+        return self._on_gc in gc.callbacks
+
+    # -- the hook ------------------------------------------------------ #
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            if tracer.enabled:
+                span = tracer.span("gc_collect", "gc",
+                                   generation=info["generation"])
+                span.__enter__()
+                self._span = span
+            self._t0 = time.perf_counter()
+            return
+        t0, self._t0 = self._t0, None
+        if t0 is None:
+            # Installed while this collection was running.
+            return
+        pause = time.perf_counter() - t0
+        span, self._span = self._span, None
+        if span is not None:
+            span.args["collected"] = info["collected"]
+            span.args["uncollectable"] = info["uncollectable"]
+            span.__exit__(None, None, None)
+        generation = info["generation"]
+        self.collections[generation] += 1
+        self.pause_s[generation] += pause
+        if pause > self.max_pause_s:
+            self.max_pause_s = pause
+        if generation == 2 and pause > self.max_full_pause_s:
+            self.max_full_pause_s = pause
+
+    # -- readback ------------------------------------------------------ #
+
+    def counters(self) -> Dict[str, Any]:
+        return {
+            "collections": dict(zip(_GENERATIONS, self.collections)),
+            "pause_s": dict(zip(_GENERATIONS, self.pause_s)),
+            "max_pause_s": self.max_pause_s,
+            "max_full_pause_s": self.max_full_pause_s,
+        }
+
+
+gc_timer = GcTimer()
+
+
+def rss_bytes() -> int:
+    """The process's resident set: ``/proc/self/statm`` where there
+    is one, else the peak from ``resource`` (kilobytes on Linux)."""
+    try:
+        with open("/proc/self/statm", encoding="ascii") as f:
+            return int(f.read().split()[1]) * os.sysconf("SC_PAGE_SIZE")
+    except (OSError, ValueError, IndexError):
+        import resource
+
+        return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+
+
+def process_stats() -> Dict[str, Any]:
+    """The ``process`` object of ``GET /stats``: the collector's
+    counters, resident memory, the CPU seconds of every thread of the
+    process (``time.process_time``) and a monotonic wall clock to
+    divide their differences by."""
+    return {
+        "gc": gc_timer.counters(),
+        "rss_bytes": rss_bytes(),
+        "cpu_s": time.process_time(),
+        "wall_s": time.monotonic(),
+    }
 
 
 def get_tracer() -> Tracer:
@@ -820,6 +1009,8 @@ def merge_traces(paths: Sequence[str], out_path: str
         }
         if ev.get("ph") == "X":
             out["dur"] = ev.get("dur", 0.0)
+            if "tdur" in ev:
+                out["tdur"] = ev["tdur"]
         else:
             out["s"] = "t"
         if ev.get("id"):

@@ -77,7 +77,10 @@ class BinScheduler:
         # between flushes: ``sched_idle`` (nothing to dispatch: from
         # the end of a flush to the next request's arrival, ONE span
         # however many 0.1 s polls it takes), ``sched_collect`` (the
-        # linger window) and ``sched_flush`` (plan, launch, collect).
+        # linger window) and ``sched_flush``, itself tiled by
+        # ``sched_plan``, ``serve_launch`` (the host's half before
+        # the device has the work), ``serve_dispatch`` (the wait for
+        # the device) and ``serve_decode`` (serving/service.py).
         # ``sched_idle`` is a live span, not a retroactive one: only
         # an open span has a profiler annotation, and the device's
         # idle time is attributed on the profiler's clock.
@@ -182,16 +185,19 @@ class BinScheduler:
         # crashes degrade INSIDE plan_flush (once-per-flush log +
         # one-plan-per-bin fallback) — this guard is only the last
         # line of defense against the wrapper itself breaking.
-        try:
-            plans = self.service.plan_flush(bins)
-        except Exception:  # noqa: BLE001 — last line of defense
-            logger.exception("flush planning crashed; dispatching "
-                             "per bin")
-            from pydcop_tpu.serving.service import DispatchPlan
+        with (tracer.span("sched_plan", "serving")
+              if tracer.enabled else NOOP_SPAN) as span:
+            try:
+                plans = self.service.plan_flush(bins)
+            except Exception:  # noqa: BLE001 — last line of defense
+                logger.exception("flush planning crashed; "
+                                 "dispatching per bin")
+                from pydcop_tpu.serving.service import DispatchPlan
 
-            plans = [DispatchPlan(list(bins[k]))
-                     for k in sorted(bins,
-                                     key=lambda k: -len(bins[k]))]
+                plans = [DispatchPlan(list(bins[k]))
+                         for k in sorted(bins,
+                                         key=lambda k: -len(bins[k]))]
+            span.args["n_plans"] = len(plans)
         chunks: List = []
         for plan in plans:
             reqs: List = plan.reqs

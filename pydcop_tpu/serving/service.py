@@ -70,7 +70,12 @@ from pydcop_tpu.observability import efficiency, flight
 from pydcop_tpu.observability.metrics import CycleSnapshotter
 from pydcop_tpu.observability.metrics import registry as metrics_registry
 from pydcop_tpu.observability.profiler import profiler
-from pydcop_tpu.observability.trace import tracer
+from pydcop_tpu.observability.trace import (
+    NOOP_SPAN,
+    gc_timer,
+    process_stats,
+    tracer,
+)
 from pydcop_tpu.ops.dpop import UtilTooLargeError
 from pydcop_tpu.serving import binning, journal as journal_mod
 from pydcop_tpu.serving.admission import (
@@ -90,6 +95,13 @@ FINISHED = "FINISHED"
 ERROR = "ERROR"
 EXPIRED = "EXPIRED"
 REPLAYABLE = "REPLAYABLE"
+
+
+def _packing(envelope, lane_d) -> str:
+    """How a dispatch's members share it (a span arg)."""
+    if lane_d is not None:
+        return "lane"
+    return "envelope" if envelope is not None else "structure"
 
 
 class _DuplicateDelivery(Exception):
@@ -431,6 +443,10 @@ class SolveService:
         self._scheduler.start()
         self._scheduler_ident = self._scheduler.thread_ident()
         self._started = True
+        # The collector's pauses hold the interpreter lock against
+        # every request in flight: /stats counts them while the
+        # service runs (observability/trace.py).
+        gc_timer.acquire(self)
         if self._journal is not None:
             # Journal backlog feeds the operator surfaces while the
             # service runs: /healthz (replay debt before a restart)
@@ -493,6 +509,7 @@ class SolveService:
         self._started = False
         metrics_registry.active = self._was_active
         profiler.enabled = getattr(self, "_was_profiling", False)
+        gc_timer.release(self)
         # Anything still queued (drain=False, drain timeout, or a
         # submit that raced the shutdown): journaled services leave it
         # REPLAYABLE — the accepted record survives, a --recover
@@ -1237,6 +1254,18 @@ class SolveService:
                 or type(self).dispatch is not SolveService.dispatch
                 or "dispatch" in self.__dict__):
             return None
+        # The host's half of a dispatch, before the device has the
+        # work: batch assembly, the launch, the requests' bookkeeping.
+        with (tracer.span("serve_launch", "serving", n_real=len(reqs),
+                          packing=_packing(envelope, lane_d),
+                          pipelined=True)
+              if tracer.enabled else NOOP_SPAN) as span:
+            pb = self._launch(reqs, params, envelope, lane_d)
+            span.args["launched"] = pb is not None
+            return pb
+
+    def _launch(self, reqs: List[SolveRequest], params,
+                envelope, lane_d) -> Optional[PendingBatch]:
         graphs = [r.graph for r in reqs]
         t_dequeue = time.perf_counter()
         try:
@@ -1298,9 +1327,7 @@ class SolveService:
                 "serve_dispatch", "serving",
                 bin=binning.bin_label(reqs[0].bin),
                 n_real=len(reqs),
-                packing=("lane" if pb.lane_d is not None else
-                         "envelope" if pb.envelope is not None else
-                         "structure"),
+                packing=_packing(pb.envelope, pb.lane_d),
                 retry_depth=0, pipelined=True)
                 if tracer.active else None)
             try:
@@ -1364,9 +1391,7 @@ class SolveService:
             "serve_dispatch", "serving",
             bin=binning.bin_label(reqs[0].bin),
             n_real=len(reqs),
-            packing=("lane" if lane_d is not None else
-                     "envelope" if envelope is not None else
-                     "structure"),
+            packing=_packing(envelope, lane_d),
             retry_depth=retry_depth) if tracer.active else None)
         t_dev0 = time.perf_counter()
         try:
@@ -1441,7 +1466,20 @@ class SolveService:
         per-request decode with its own failure isolation, honest
         ledgers, journal/lifecycle terminals — plus the closed-loop
         feedback taps (pack-model fit samples, speculation hit
-        accounting)."""
+        accounting).  Under a file session the whole of it is one
+        ``serve_decode`` span (``cost_ms``: the part of it inside the
+        requests' ``dcop.solution_cost``)."""
+        traced = tracer.enabled
+        with (tracer.span("serve_decode", "serving", n_real=len(reqs))
+              if traced else NOOP_SPAN) as span:
+            self._decode_batch(reqs, batch_result, values, cycles,
+                               t_dev0, t_dev1,
+                               span=span if traced else None)
+
+    def _decode_batch(self, reqs: List[SolveRequest], batch_result,
+                      values, cycles, t_dev0: float,
+                      t_dev1: Optional[float], span=None) -> None:
+        cost_s = 0.0
         self.admission.record_dispatch(ok=True)
         metrics = batch_result.metrics
         self.dispatches += 1
@@ -1478,7 +1516,14 @@ class SolveService:
             try:
                 assignment = req.meta.assignment_from_indices(
                     values[i])
-                cost, violations = req.dcop.solution_cost(assignment)
+                if span is None:
+                    cost, violations = req.dcop.solution_cost(
+                        assignment)
+                else:
+                    t_cost = time.perf_counter()
+                    cost, violations = req.dcop.solution_cost(
+                        assignment)
+                    cost_s += time.perf_counter() - t_cost
             except Exception as exc:  # noqa: BLE001
                 logger.warning("result decode failed for %s: %s",
                                req.id, exc)
@@ -1543,6 +1588,8 @@ class SolveService:
             self._journal_done(req)
             req.done.set()
             self._publish_lifecycle("finished", req)
+        if span is not None:
+            span.args["cost_ms"] = 1e3 * cost_s
 
     def _feed_closed_loop(self, reqs: List[SolveRequest],
                           batch_result) -> None:
@@ -1962,6 +2009,10 @@ class SolveService:
                         if self._journal is not None else None),
             "sessions": self.sessions.stats(),
             "tracked_requests": tracked,
+            # What the process itself costs beside them: the
+            # collector's pauses by generation, resident memory, CPU
+            # seconds of all threads over a monotonic wall clock.
+            "process": process_stats(),
             "max_batch": self.max_batch,
             "batch_window_s": self.batch_window_s,
             "bin_sizes": list(self.bin_sizes),

@@ -13,6 +13,7 @@ import json
 import os
 import re
 import threading
+import time
 
 import pytest
 
@@ -236,6 +237,53 @@ class TestZeroOverheadWhenOff:
         messaging.register_computation("c")
         for _ in range(10):
             messaging.post_msg("x", "c", Message("algo", 1))
+        assert tracer.events() == []
+
+    @pytest.mark.parametrize("path", ["solve", "serve"])
+    def test_the_solve_and_flush_sites_make_no_span(self, path,
+                                                    monkeypatch):
+        """ISSUE 41's sites (``build_engine``, ``engine_place``,
+        ``result_decode``, ``result_cost``; ``sched_plan``,
+        ``serve_launch``, ``serve_decode``; the collector's hook) with
+        the tracer off: a solve and a served request make no ``_Span``,
+        read no thread clock and record nothing."""
+        from pydcop_tpu import api
+        from pydcop_tpu.observability import trace as trace_mod
+
+        made, reads = [], []
+        real = trace_mod._Span.__init__
+
+        def counting(self, *args, **kwargs):
+            made.append(args[1])
+            real(self, *args, **kwargs)
+
+        class Clock:
+            perf_counter = staticmethod(time.perf_counter)
+            time = staticmethod(time.time)
+
+            @staticmethod
+            def thread_time_ns():
+                reads.append(1)
+                return time.thread_time_ns()
+
+        monkeypatch.setattr(trace_mod._Span, "__init__", counting)
+        monkeypatch.setattr(trace_mod, "time", Clock)
+        assert not tracer.active
+        dcop = _coloring_dcop()
+        if path == "solve":
+            result = api.solve(dcop, "maxsum", max_cycles=10)
+            assert result["status"] in ("FINISHED", "TIMEOUT")
+        else:
+            with api.serve(port=0, batch_window_s=0.005, max_batch=2,
+                           max_queue=8) as handle:
+                # Cold, then warm: the synchronous and the pipelined
+                # flush.
+                for _ in range(2):
+                    rid = handle.service.submit(
+                        dcop, params={"max_cycles": 10})
+                    answer = handle.service.result(rid, wait=120)
+                    assert answer["status"] == "FINISHED"
+        assert made == [] and reads == []
         assert tracer.events() == []
 
     def test_noop_span_reused_across_many_calls(self):

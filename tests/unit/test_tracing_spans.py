@@ -260,9 +260,16 @@ def served_requests():
     previous = tracer.flight
     try:
         # Warm the program up first, so the traced requests take the
-        # steady path (pipelined launch and collect).
+        # steady path (pipelined launch and collect).  Its handler
+        # thread closes its ``http_request`` span after the caller has
+        # the reply: wait for it, or a busy machine lets it close
+        # inside the session, a fourth request there.
+        warm_up = FlightRecorder(events=256)
+        tracer.set_flight(warm_up)
         _post(handle.url, {"dcop": payloads[0], "wait": True,
                            "params": {"max_cycles": MAX_CYCLES}})
+        assert _until(lambda: any(
+            e["name"] == "http_request" for e in warm_up.snapshot()))
         tracer.enable()
         try:
             replies = [
@@ -271,7 +278,8 @@ def served_requests():
                 for text in payloads[:3]]
             assert _until(lambda: sum(
                 e["name"] == "http_request"
-                for e in tracer.events()) == 3)
+                for e in tracer.events()) == 3), sorted(
+                    (e["name"], e["tid"]) for e in tracer.events())
         finally:
             tracer.disable()
         events = tracer.events()
@@ -324,7 +332,10 @@ def test_a_request_leaves_three_more_spans_on_the_ring(
     beside prep, submit, queue, dispatch and the engine's segment —
     and 3 lifecycle instants; none of the session-only spans."""
     *_, ring = served_requests
-    spans = sorted(e["name"] for e in ring if e["ph"] == "X")
+    # (The background compiler's own span may close meanwhile, on a
+    # busy machine: it is no part of the request.)
+    spans = sorted(e["name"] for e in ring if e["ph"] == "X"
+                   and e["name"] != "speculative_compile")
     assert spans == sorted([
         "http_request", "yaml_parse", "yaml_build",
         "compile_graph", "serve_submit", "serve_queued",
@@ -364,6 +375,165 @@ def test_three_spans_tile_the_scheduler_thread_between_flushes(
                         key=lambda e: e["ts"])[:2]
     assert len(dispatches) == 2 and all(
         e["parent"] in {f["id"] for f in flushes} for e in dispatches)
+
+
+FLUSH_STAGES = ("sched_plan", "serve_launch", "serve_dispatch",
+                "serve_decode")
+
+
+@pytest.mark.parametrize("name", FLUSH_STAGES)
+def test_four_stages_tile_a_flush(served_requests, name):
+    """ISSUE 41: plan, the host's half of the launch, the wait for
+    the device, the decode; in that order, one of each for a flush of
+    one chunk, none overlapping, each with its thread's CPU time."""
+    events, thread_names, *_ = served_requests
+    tid = next(t for t, label in thread_names.items()
+               if label == "pydcop-serve-scheduler")
+    flushes = sorted((e for e in events if e["name"] == "sched_flush"),
+                     key=lambda e: e["ts"])
+    assert len(flushes) >= 2
+    for flush in flushes[:2]:
+        stages = sorted(
+            (e for e in events if e["parent"] == flush["id"]
+             and e["name"] != "gc_collect"),
+            key=lambda e: e["ts"])
+        assert [e["name"] for e in stages] == list(FLUSH_STAGES)
+        assert all(e["tid"] == tid for e in stages)
+        for before, after in zip(stages, stages[1:]):
+            assert before["ts"] + before["dur"] <= after["ts"]
+        assert flush["ts"] <= stages[0]["ts"]
+        assert (stages[-1]["ts"] + stages[-1]["dur"]
+                <= flush["ts"] + flush["dur"])
+        stage = next(e for e in stages if e["name"] == name)
+        assert 0 <= stage["tdur"] <= stage["dur"]
+        if name == "sched_plan":
+            assert stage["args"]["n_plans"] == 1
+        elif name == "serve_launch":
+            # The warm path: launched, not handed back to the
+            # synchronous dispatch.
+            assert stage["args"]["n_real"] == 1
+            assert stage["args"]["packing"] == "structure"
+            assert stage["args"]["pipelined"] is True
+            assert stage["args"]["launched"] is True
+        elif name == "serve_decode":
+            assert stage["args"]["n_real"] == 1
+            assert 0 < stage["args"]["cost_ms"] <= stage["dur"] / 1e3
+
+
+def test_a_cold_flush_assembles_inside_its_dispatch(file_session):
+    """The synchronous path (a program not compiled yet): the launch
+    is handed back, and the assembly before ``timed_jit_call`` is the
+    engine's ``batch_assemble`` under ``serve_dispatch``: one
+    ``serve_launch`` a dispatch, whichever path it took."""
+    from pydcop_tpu import api
+    from pydcop_tpu.observability.trace import check_well_nested
+
+    patch = pytest.MonkeyPatch()
+    patch.setenv("PYDCOP_PACK_FIT", "0")
+    try:
+        with api.serve(port=0, batch_window_s=0.005, max_batch=4,
+                       max_queue=16) as handle:
+            rid = handle.service.submit(
+                _ring(11, 7), params={"max_cycles": MAX_CYCLES})
+            assert handle.service.result(
+                rid, wait=120)["status"] == "FINISHED"
+            assert _until(lambda: any(
+                e["name"] == "sched_flush" for e in tracer.events()))
+    finally:
+        patch.undo()
+    events = tracer.events()
+    # (`serve_queued` is back-dated from another thread's clock read:
+    # it lies across the scheduler's own spans by design.)
+    check_well_nested(e for e in events if e["name"] != "serve_queued")
+    (launch,) = [e for e in events if e["name"] == "serve_launch"]
+    assert launch["args"]["pipelined"] is True
+    assert launch["args"]["launched"] is False
+    (dispatch,) = [e for e in events if e["name"] == "serve_dispatch"]
+    (assembly,) = [e for e in events if e["name"] == "batch_assemble"]
+    assert assembly["cat"] == "engine"
+    assert assembly["parent"] == dispatch["id"]
+    assert assembly["args"]["n_real"] == 1
+    assert assembly["args"]["packing"] == "structure"
+    assert launch["ts"] + launch["dur"] <= assembly["ts"]
+    (flush,) = [e for e in events if e["name"] == "sched_flush"]
+    under = {e["name"] for e in events if e["parent"] == flush["id"]}
+    assert set(FLUSH_STAGES) <= under
+
+
+def test_a_batch_solve_outside_the_service_names_no_serving_span(
+        file_session):
+    """``engine.batch.solve_maxsum_batch`` runs the same assembly with no
+    service above it: its span is the engine's."""
+    from pydcop_tpu.engine.batch import solve_maxsum_batch
+
+    solve_maxsum_batch([_ring(9, 3), _ring(9, 4)],
+                       max_cycles=MAX_CYCLES)
+    events = tracer.events()
+    assert [e["args"]["n_real"] for e in events
+            if e["name"] == "batch_assemble"] == [2]
+    assert not [e for e in events if e["cat"] == "serving"]
+
+
+# ------------------------------------------------------------------ #
+# (g) where solve's own time goes
+
+
+SOLVE_CHILDREN = ("build_engine", "compile_graph", "engine_place",
+                  "result_decode", "result_cost")
+
+
+@pytest.fixture(scope="module")
+def traced_solves():
+    """Two ``api.solve`` of one problem under a file session (the
+    second warm); returns the events."""
+    from pydcop_tpu import api
+
+    dcop = _ring(9, 4)
+    tracer.enable()
+    try:
+        for _ in range(2):
+            api.solve(dcop, "maxsum", max_cycles=MAX_CYCLES)
+    finally:
+        tracer.disable()
+    events = tracer.events()
+    tracer.clear()
+    return events
+
+
+@pytest.mark.parametrize("name", SOLVE_CHILDREN)
+def test_the_children_of_a_solve_are_well_nested(traced_solves, name):
+    from pydcop_tpu.observability.trace import check_well_nested
+
+    check_well_nested(traced_solves)
+    solves = [e for e in traced_solves if e["name"] == "solve"]
+    assert len(solves) == 2
+    by_id = {e["id"]: e for e in traced_solves}
+    for solve in solves:
+        under = _descendants(traced_solves, solve)
+        (span,) = [e for e in under if e["name"] == name]
+        parent = by_id[span["parent"]]
+        expected = ("build_engine" if name in ("compile_graph",
+                                               "engine_place")
+                    else "solve")
+        assert parent["name"] == expected
+        assert parent["ts"] <= span["ts"]
+        assert (span["ts"] + span["dur"]
+                <= parent["ts"] + parent["dur"])
+        assert 0 <= span["tdur"] <= span["dur"]
+        if name == "build_engine":
+            assert span["args"] == {"layout": "lane",
+                                    "layout_source": "selected"}
+        elif name == "engine_place":
+            assert span["args"]["layout"] == "lane"
+            assert span["args"]["bytes"] > 0
+        elif name == "result_cost":
+            assert span["args"] == {"n_constraints": 9,
+                                    "n_variables": 9}
+    # In the order the solve runs them.
+    first = _descendants(traced_solves, solves[0])
+    order = [e["name"] for e in sorted(first, key=lambda e: e["ts"])
+             if e["name"] in SOLVE_CHILDREN]
+    assert order == list(SOLVE_CHILDREN)
 
 
 # ------------------------------------------------------------------ #
