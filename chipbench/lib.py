@@ -1,16 +1,18 @@
 """What both runners share: the checks of an answer, the in-process
 ``pydcop`` CLI, the HTTP helpers (all lifted from ``chip_smoke.py``,
 which proved them on the chip in PR 22), the lookup of a module by
-name (runner, reader, family), the instance and its shapes, the
-percentile and the traced block."""
+name (runner, reader, family, reference), the instance and its shapes,
+the percentile and the traced block."""
 
 import contextlib
 import glob
 import importlib
+import importlib.util
 import io
 import json
 import math
 import os
+import statistics
 import time
 import urllib.request
 
@@ -28,29 +30,61 @@ def note(**fields):
     print(json.dumps(fields), flush=True)
 
 
-def module_by_name(package, name, what):
-    """``chipbench/<package>/<name>.py``, imported; a name that
-    resolves to no file names the file looked for."""
-    path = os.path.join(HERE, package, f"{name}.py")
-    if not os.path.isfile(path):
-        raise BenchFailure(f"{what} {name!r}: no file {path}")
+def module_by_name(package, name, what, data_dir=HERE):
+    """``<package>/<name>.py`` of the benchmark's data directory,
+    imported (a family or a reference may be a file of a copy's own);
+    where that directory is not ``chipbench/`` and has no such file (a
+    rehearsal's copy holds only the files it adds), or none is given,
+    ``chipbench/<package>/<name>.py``.  A name that resolves to no
+    file names the files looked for."""
+    own = os.path.join(HERE, package, f"{name}.py")
+    path = os.path.join(os.path.abspath(data_dir), package, f"{name}.py")
+    if path != own and os.path.isfile(path):
+        spec = importlib.util.spec_from_file_location(
+            f"chipbench_data.{package}.{name}", path)
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        return module
+    if not os.path.isfile(own):
+        looked = own if path == own else f"{path} nor {own}"
+        raise BenchFailure(f"{what} {name!r}: no file {looked}")
     return importlib.import_module(f"chipbench.{package}.{name}")
 
 
-def family_of(spec):
-    """The module of ``chipbench/families/`` that a configuration's
+def family_of(spec, data_dir=HERE):
+    """The module of ``families/`` that a configuration's
     ``generator`` names.  There is no default family."""
     if "family" not in spec:
         raise BenchFailure(
             "the generator states no `family` (a file of "
             f"{os.path.join(HERE, 'families')})")
-    return module_by_name("families", spec["family"], "family")
+    return module_by_name("families", spec["family"], "family", data_dir)
 
 
-def generate(spec, seed):
+def reference_of(config, data_dir=HERE):
+    """The module of ``references/`` that a configuration's
+    ``reference`` names: ``solve(dcop, cycles, seed) -> (assignment,
+    cost)``, the cost in the problem's own sign.  There is no default
+    reference."""
+    if "reference" not in config:
+        raise BenchFailure(
+            "the configuration states no `reference` (a file of "
+            f"{os.path.join(HERE, 'references')})")
+    return module_by_name("references", config["reference"], "reference",
+                          data_dir)
+
+
+def placement(config):
+    """Where a solve configuration's solves run: its ``shards`` or
+    ``n_devices``, as keywords of ``api.solve`` (none: one device)."""
+    return {key: config[key] for key in ("shards", "n_devices")
+            if key in config}
+
+
+def generate(spec, seed, data_dir=HERE):
     """The instance of the spec's family for this seed, as a DCOP
     object, from the program's own generator."""
-    return family_of(spec).generate(spec, seed)
+    return family_of(spec, data_dir).generate(spec, seed)
 
 
 def shapes(dcop):
@@ -84,13 +118,17 @@ def check_answer(dcop, answer, reference_cost, tolerance, ends=None):
     covers every variable, its reported cost and violations equal
     ``dcop.solution_cost`` on the host exactly, the cost is no worse
     than the reference's by more than ``tolerance`` (relative,
-    one-sided), and the solve ended as the configuration's ``ends``
-    states.  ``compared`` holds each number that was compared beside
-    its limit, ``{name: [value, limit]}``."""
+    one-sided: on a problem whose ``objective`` is ``max`` the cost is
+    a utility, and worse is lower), and the solve ended as the
+    configuration's ``ends`` states.  ``compared`` holds each number
+    that was compared beside its limit, ``{name: [value, limit]}``,
+    each sound where value <= limit: on a max problem ``cost`` holds
+    the negated utility and the negated floor."""
     assignment, cost = answer["assignment"], answer["cost"]
-    limit = reference_cost + tolerance * abs(reference_cost)
+    sign = -1 if dcop.objective == "max" else 1
+    limit = sign * reference_cost + tolerance * abs(reference_cost)
     compared = {
-        "cost": [cost, limit],
+        "cost": [sign * cost, limit],
         "unassigned": [len(set(assignment) ^ set(dcop.variables)), 0]}
     if ends is not None:
         compared["cycles"] = [answer["cycles"], ends["cycles"]]
@@ -109,8 +147,11 @@ def check_answer(dcop, answer, reference_cost, tolerance, ends=None):
     elif compared["violations_minus_host"][0]:
         fault = (f"reported violations {answer['violations']} != host "
                  f"{host_violations}")
-    elif host_cost > limit:
-        fault = (f"cost {host_cost} is more than {tolerance:.0%} worse "
+    elif sign * host_cost > limit:
+        fault = (f"utility {host_cost} is worse than the reference's "
+                 f"{reference_cost} by more than {tolerance:g} of it"
+                 if sign < 0 else
+                 f"cost {host_cost} is more than {tolerance:.0%} worse "
                  f"than the reference's {reference_cost}")
     elif ends is not None and (answer["status"], answer["cycles"]) != (
             ends["status"], ends["cycles"]):
@@ -123,9 +164,30 @@ def check_answer(dcop, answer, reference_cost, tolerance, ends=None):
 def worst(checked):
     """Of ``(fault, compared)`` pairs, the ``compared`` of the worst
     answer: one at fault before a sound one, then the cost that lies
-    highest against its limit."""
+    highest against its limit (``check_answer`` signs it, so on a max
+    problem the utility that lies lowest)."""
     return max(checked, key=lambda c: (
         c[0] is not None, c[1]["cost"][0] - c[1]["cost"][1]))[1]
+
+
+def cost_ratio(dcop, cost, reference_cost):
+    """An answer against the reference, 1 where they are equal and
+    above 1 where the answer is worse: its cost over the reference's,
+    and on a max problem one plus the utility it falls short by, as a
+    share of the reference's (so for a utility of either sign).  None
+    where the reference's value is 0, which gives no ratio."""
+    if reference_cost == 0:
+        return None
+    if dcop.objective == "max":
+        return 1 + (reference_cost - cost) / abs(reference_cost)
+    return cost / reference_cost
+
+
+def mean_cost_ratio(answers):
+    """The mean ``cost_ratio`` of ``(dcop, cost, reference_cost)``
+    triples; None where one of them gives no ratio."""
+    ratios = [cost_ratio(*answer) for answer in answers]
+    return None if None in ratios else statistics.fmean(ratios)
 
 
 def post_solve(url, body):

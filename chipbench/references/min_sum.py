@@ -1,5 +1,6 @@
-"""The plain reference: synchronous min-sum on factors of any arity,
-numpy.
+"""The plain reference ``min_sum``: synchronous min-sum on factors of
+any arity, numpy; on a problem whose ``objective`` is ``max``, max-sum
+(min-sum on the negated tables), and the answer's utility.
 
 Independent of ``pydcop_tpu``: it reads the problem only by calling
 each constraint on every tuple of values and each variable's
@@ -100,9 +101,14 @@ def min_sum(unary, factors, cycles, seed=0):
 
 def solve(dcop, cycles, seed=0):
     """``(assignment, cost)``: the reference's answer and what it
-    costs, summed from the same tables."""
+    costs (on a max problem, its utility), summed from the same
+    tables."""
     names, values, unary, factors = tables(dcop)
-    choice = min_sum(unary, factors, cycles, seed)
+    if dcop.objective == "max":
+        choice = min_sum(-unary, {k: (index, -costs) for k, (index, costs)
+                                  in factors.items()}, cycles, seed)
+    else:
+        choice = min_sum(unary, factors, cycles, seed)
     cost = float(unary[np.arange(len(names)), choice].sum())
     for k, (index, costs) in factors.items():
         chosen = tuple(choice[index[:, p]] for p in range(k))

@@ -19,9 +19,13 @@ BENCHMARK.json lists for the cell that no reader found a value for
 The cell is looked up by name in BENCHMARK.json; its configuration
 file names a ``kind``, and ``runners/<kind>.py`` runs it.  In a traced
 run every file in ``metrics/`` whose ``kinds`` holds that kind is
-evaluated by ``readers/<reader>.py``.  Any failure (no TPU, a name
-that resolves to no file, an answer that cannot be had) exits
-non-zero and prints no last line.
+evaluated by ``readers/<reader>.py``.  A configuration that states
+``shards`` or ``n_devices`` (the two are exclusive) may name no more
+devices than its cell has chips, and a cell of more than one chip has
+a configuration that states one of them, equal to its chips.  Any
+failure (no TPU, a name that resolves to no file, a configuration that
+does not fit its cell, an answer that cannot be had) exits non-zero
+and prints no last line.
 """
 
 import time
@@ -42,7 +46,12 @@ ROOT = os.path.dirname(HERE)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from chipbench.lib import BenchFailure, module_by_name, note  # noqa: E402
+from chipbench.lib import (  # noqa: E402
+    BenchFailure,
+    module_by_name,
+    note,
+    placement,
+)
 
 # What every run must have run on.  (The CPU rehearsal,
 # tests/chipbench_rehearsal, patches it from the test.)
@@ -63,6 +72,7 @@ class Cell:
     device: dict
     device_capture: bool
     workdir: str
+    data_dir: str
     window_start: float = None
 
     def start_window(self):
@@ -108,6 +118,34 @@ def resolve(bench_path, workload):
             f"{traffic['kind']!r}, configuration {entry['config']!r} of "
             f"kind {config['kind']!r}")
     return bench, entry, config, traffic, data_dir
+
+
+def check_placement(entry, config):
+    """Where the cell's solves run, against the chips it asks for:
+    ``shards`` or ``n_devices`` (exclusive) names no more devices than
+    the cell has chips, and a cell of more than one chip names one of
+    them, equal to its chips."""
+    stated = placement(config)
+    if len(stated) > 1:
+        raise BenchFailure(
+            f"configuration {entry['config']!r} states {stated}: shards and "
+            "n_devices are exclusive")
+    for key, devices in stated.items():
+        if devices > entry["chips"]:
+            raise BenchFailure(
+                f"configuration {entry['config']!r} states {key} "
+                f"{devices}; cell {entry['name']!r} has {entry['chips']} "
+                "chip(s)")
+        if entry["chips"] > 1 and devices < entry["chips"]:
+            raise BenchFailure(
+                f"configuration {entry['config']!r} states {key} "
+                f"{devices}; cell {entry['name']!r} takes {entry['chips']} "
+                f"chips, so {entry['chips'] - devices} would stay idle")
+    if entry["chips"] > 1 and not stated:
+        raise BenchFailure(
+            f"cell {entry['name']!r} takes {entry['chips']} chips; "
+            f"configuration {entry['config']!r} states neither shards nor "
+            "n_devices, so its solves would run on one")
 
 
 def listed(bench, section, workload):
@@ -195,6 +233,7 @@ def breakdown(capture):
 def run(args, started):
     bench, entry, config, traffic, data_dir = resolve(
         os.path.abspath(args.bench), args.workload)
+    check_placement(entry, config)
     runner = module_by_name("runners", config["kind"],
                             f"runner of kind {config['kind']!r}")
     from pydcop_tpu.engine import aotcache
@@ -212,7 +251,7 @@ def run(args, started):
             name=entry["name"], config=config, traffic=traffic,
             seed=args.seed, seconds=args.seconds, trace=bool(args.trace),
             device=device, device_capture=device["platform"] == "tpu",
-            workdir=workdir)
+            workdir=workdir, data_dir=data_dir)
         result = runner.run(cell)
         if cell.window_start is None:
             raise BenchFailure("the runner never started its window")

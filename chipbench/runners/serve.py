@@ -4,9 +4,10 @@ process), closed-loop callers posting a pool of small problems to
 
 Configuration keys: ``generator``, ``pool`` (problems of one
 structure, seeds ``1000 * seed + i``), ``params`` (sent with every
-request), ``service`` (the arguments of ``api.serve``),
-``cost_tolerance``.  Traffic keys: ``callers``, ``warmup_bursts``,
-``warmup_rounds``, ``traced_seconds``.
+request), ``service`` (the arguments of ``api.serve``), ``reference``
+(a module of ``chipbench/references/``), ``cost_tolerance``.  Traffic
+keys: ``callers``, ``warmup_bursts``, ``warmup_rounds``,
+``traced_seconds``.
 """
 
 import itertools
@@ -15,7 +16,7 @@ import statistics
 import threading
 import time
 
-from chipbench import lib, reference
+from chipbench import lib
 
 REQUEST_TIMEOUT_S = 600
 JOIN_TIMEOUT_S = 900
@@ -98,6 +99,13 @@ def settle(url, counters):
     return time.perf_counter() - start
 
 
+def collector(url):
+    """The service's counters of the cyclic collector (``/stats``
+    ``process.gc``): two reads bracket the window, so that a tail can
+    be set beside the full collections that fell in it."""
+    return lib.get_json(url + "/stats")["process"]["gc"]
+
+
 def faults_of(records, dcops, reference_costs, tolerance):
     """``(faults, compared)``: why answers are wrong (not FINISHED,
     wrong on the host, worse than the reference allows, or a repeated
@@ -137,9 +145,11 @@ def run(cell):
 
     # ---- set-up ------------------------------------------------------
     t0 = time.perf_counter()
-    dcops = [lib.generate(config["generator"], 1000 * cell.seed + i)
+    dcops = [lib.generate(config["generator"], 1000 * cell.seed + i,
+                          cell.data_dir)
              for i in range(config["pool"])]
     payloads = [dcop_yaml(d) for d in dcops]
+    reference = lib.reference_of(config, cell.data_dir)
     reference_costs = [
         reference.solve(d, config["params"]["max_cycles"], cell.seed)[1]
         for d in dcops]
@@ -159,10 +169,12 @@ def run(cell):
                         "payload_bytes": len(payloads[0])})
 
         # ---- the window ----------------------------------------------
+        gc_before = collector(handle.url)
         counters_before = aotcache.counters()
         start = cell.start_window()
         records = callers.run(n_callers, until=start + cell.seconds)
         elapsed = max(r["done"] for r in records) - start
+        gc_after = collector(handle.url)
         latencies = [r["latency_s"] for r in records]
         finished = sum(r["answer"].get("status") == "FINISHED"
                        for r in records)
@@ -177,10 +189,16 @@ def run(cell):
             "cache_misses": counters["misses"] - counters_before["misses"],
             "elapsed_s": elapsed,
             "p50_ms": 1e3 * statistics.median(latencies),
+            "p90_ms": 1e3 * lib.percentile(latencies, 0.90),
             "p95_ms": end_to_end["serve_p95_ms"],
+            "p99_ms": 1e3 * lib.percentile(latencies, 0.99),
             "max_ms": 1e3 * max(latencies),
             "samples_beyond_p95": len(latencies) - math.ceil(
-                0.95 * len(latencies))})
+                0.95 * len(latencies)),
+            "full_collections": (gc_after["collections"]["gen2"]
+                                 - gc_before["collections"]["gen2"]),
+            "full_collections_s": (gc_after["pause_s"]["gen2"]
+                                   - gc_before["pause_s"]["gen2"])})
 
         # ---- the traced block ----------------------------------------
         capture = None
@@ -220,8 +238,9 @@ def run(cell):
         "completed", "failed", "expired", "deduped", "dispatches",
         "batched_dispatches")})
     if capture is not None:
-        capture["values"]["cost_ratio"] = statistics.fmean(
-            r["answer"]["cost"] / reference_costs[r["index"]]
+        capture["values"]["cost_ratio"] = lib.mean_cost_ratio(
+            (dcops[r["index"]], r["answer"]["cost"],
+             reference_costs[r["index"]])
             for r in records if r["answer"].get("status") == "FINISHED")
     return {"correct": not faults, "attempted": len(warm) + len(records),
             "failed": len(faults), "end_to_end": end_to_end,

@@ -8,8 +8,11 @@ Configuration keys: ``generator`` (its ``family`` names a module of
 ``-p name:value`` each to ``pydcop solve``), ``max_cycles``, ``ends``
 (``{"status", "cycles"}``: how every solve has to end, or null),
 ``cli_solve`` (whether set-up makes the timed CLI solve),
-``cost_tolerance``.  Traffic keys: ``warmup_solves``,
-``traced_solves``.
+``reference`` (a module of ``chipbench/references/``),
+``cost_tolerance``, and optionally one of ``shards`` and ``n_devices``
+(where the solves run: given to ``api.solve`` and as ``--shards N`` /
+``--n_devices N`` to ``pydcop solve``; ``run.py`` holds them to the
+cell's chips).  Traffic keys: ``warmup_solves``, ``traced_solves``.
 """
 
 import json
@@ -17,7 +20,7 @@ import os
 import statistics
 import time
 
-from chipbench import lib, reference
+from chipbench import lib
 
 
 def cli_solve(cell, path):
@@ -28,6 +31,8 @@ def cli_solve(cell, path):
     t0 = time.perf_counter()
     params = [arg for name, value in cell.config["algo_params"].items()
               for arg in ("-p", f"{name}:{value}")]
+    params += [arg for key, value in lib.placement(cell.config).items()
+               for arg in (f"--{key}", str(value))]
     lib.pydcop("--output", result_path, "solve", "-a",
                cell.config["algo"], *params, "-c",
                str(cell.config["max_cycles"]), path)
@@ -50,18 +55,21 @@ def run(cell):
 
     config, traffic = cell.config, cell.traffic
     algo, cycles = config["algo"], config["max_cycles"]
+    reference = lib.reference_of(config, cell.data_dir)
 
     def solve():
         t0 = time.perf_counter()
         res = api.solve(dcop, algo, max_cycles=cycles,
-                        algo_params=config["algo_params"])
+                        algo_params=config["algo_params"],
+                        **lib.placement(config))
         return time.perf_counter() - t0, res
 
     # ---- set-up ------------------------------------------------------
     t0 = time.perf_counter()
-    dcop = lib.generate(config["generator"], cell.seed)
+    dcop = lib.generate(config["generator"], cell.seed, cell.data_dir)
     generate_s = time.perf_counter() - t0
-    stated = lib.family_of(config["generator"]).shapes(config["generator"])
+    stated = lib.family_of(config["generator"], cell.data_dir).shapes(
+        config["generator"])
     if lib.shapes(dcop) != stated:
         raise lib.BenchFailure(
             f"seed {cell.seed} gave the shapes {lib.shapes(dcop)}; the "
@@ -103,7 +111,8 @@ def run(cell):
                                       - counters_before["misses"]),
                      "p50_s": end_to_end["solve_p50_s"],
                      "max_s": max(walls), "cycles": res["cycles"],
-                     "status": res["status"], "cost": res["cost"]})
+                     "status": res["status"], "cost": res["cost"],
+                     "n_shards": res.get("metrics", {}).get("n_shards")})
 
     # ---- the traced block --------------------------------------------
     capture = None
@@ -130,8 +139,8 @@ def run(cell):
     for fault in faults[:5]:
         lib.note(fault=fault)
     if capture is not None:
-        capture["values"]["cost_ratio"] = statistics.fmean(
-            a["cost"] / reference_cost for _, a in answers)
+        capture["values"]["cost_ratio"] = lib.mean_cost_ratio(
+            (dcop, a["cost"], reference_cost) for _, a in answers)
     return {"correct": not faults, "attempted": len(answers),
             "failed": len(faults), "end_to_end": end_to_end,
             "capture": capture, "compared": lib.worst(checked)}

@@ -36,10 +36,12 @@ def the_programs_listeners_are_on_the_bus_once():
 @pytest.fixture(scope="session")
 def benchmarks(tmp_path_factory):
     """``{which: (path of its BENCHMARK.json, its data directory)}``:
-    the repository's own benchmark, and the copy with a fourth cell
+    the repository's own benchmark, and each copy with a cell added
     (``rehearsal_benchmarks.py``), which no test writes to."""
-    return {
-        "real": (os.path.join(rehearsal_benchmarks.REPO, "BENCHMARK.json"),
-                 rehearsal_benchmarks.CHIPBENCH),
-        "fourth_cell": rehearsal_benchmarks.build(
-            str(tmp_path_factory.mktemp("fourth_cell")))}
+    built = {
+        which: rehearsal_benchmarks.build(
+            str(tmp_path_factory.mktemp(which)), which)
+        for which in rehearsal_benchmarks.ADDITIONS}
+    return dict(built, real=(
+        os.path.join(rehearsal_benchmarks.REPO, "BENCHMARK.json"),
+        rehearsal_benchmarks.CHIPBENCH))

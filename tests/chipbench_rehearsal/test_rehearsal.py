@@ -50,7 +50,7 @@ TINY_CONFIGS = {
                       "constraints": 130},
         "algo": "maxsum", "algo_params": {}, "max_cycles": 30,
         "ends": {"status": "TIMEOUT", "cycles": 30}, "cli_solve": True,
-        "cost_tolerance": 3.0},
+        "reference": "min_sum", "cost_tolerance": 3.0},
     "tiny_serve": {
         "name": "tiny_serve", "kind": "serve",
         "generator": {"family": "graph_coloring", "variables": 16,
@@ -58,7 +58,7 @@ TINY_CONFIGS = {
         "pool": 3, "params": {"max_cycles": 30},
         "service": {"batch_window_s": 0.005, "max_batch": 4,
                     "max_queue": 64},
-        "cost_tolerance": 0.5},
+        "reference": "min_sum", "cost_tolerance": 0.5},
 }
 TINY_TRAFFIC = {
     "resolve": {"name": "resolve", "kind": "solve", "warmup_solves": 1,
@@ -516,7 +516,7 @@ def _brute_force(dcop):
 
 
 def test_reference_finds_the_optimum_of_a_tree():
-    from chipbench import reference
+    from chipbench.references import min_sum as reference
 
     dcop = _random_problem(np.random.default_rng(5), [
         (0, 1), (0, 2), (1, 3), (1, 4), (2, 5)], 3)

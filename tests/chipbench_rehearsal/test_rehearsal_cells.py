@@ -51,8 +51,8 @@ README_KEYS = {"name", "kind", "source", "reduced", "assumed",
                "guarantees", "cost_tolerance", "why"}
 RUNNER_KEYS = {
     "solve": {"generator", "algo", "algo_params", "max_cycles", "ends",
-              "cli_solve"},
-    "serve": {"generator", "pool", "params", "service"},
+              "cli_solve", "reference"},
+    "serve": {"generator", "pool", "params", "service", "reference"},
 }
 
 
@@ -374,13 +374,15 @@ def test_a_solve_configuration_fixes_the_count_its_density_gives(
     family at a size a test can hold)."""
     from chipbench import lib
 
-    config = _config(filename, benchmarks[which][1])
+    data = benchmarks[which][1]
+    config = _config(filename, data)
     assert config["kind"] == "solve"
     generator = config["generator"]
-    family = lib.family_of(generator)
+    family = lib.family_of(generator, data)
     family.check(generator)
     small = family.small(generator)
     assert sum(family.shapes(small)["factors_by_arity"].values()) <= 1500
     assert small["family"] == generator["family"]
     family.check(small)
-    assert lib.shapes(lib.generate(small, 4100000007)) == family.shapes(small)
+    assert lib.shapes(lib.generate(small, 4100000007, data)) == (
+        family.shapes(small))

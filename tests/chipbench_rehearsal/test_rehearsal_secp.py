@@ -27,7 +27,7 @@ SECP = {
                   "factors_by_arity": {"1": 62, "2": 7, "3": 15, "4": 6}},
     "algo": "maxsum", "algo_params": {"stability": 0}, "max_cycles": 200,
     "ends": {"status": "TIMEOUT", "cycles": 200}, "cli_solve": True,
-    "cost_tolerance": 0.5}
+    "reference": "min_sum", "cost_tolerance": 0.5}
 SECP_CELL = "tiny_secp.resolve"
 
 
